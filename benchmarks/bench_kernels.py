@@ -1,8 +1,9 @@
-"""Pallas kernel benches: interpret-mode correctness + timing vs jnp oracle.
+"""Pallas kernel benches: correctness + timing vs the jnp oracles.
 
-On this CPU container the numbers measure the *interpreted* kernel (Python
-loop over grid steps), so wall time is diagnostic only; the `rel_err` and
-tiling metadata are the deliverable.  On TPU, set interpret=False.
+The kernels compile on TPU and are interpreted on CPU
+(``kernels/platform.py``); on CPU the numbers measure the *interpreted*
+kernel (a Python loop over grid steps), so wall time there is diagnostic
+only and the `rel_err` and tiling metadata are the deliverable.
 """
 from __future__ import annotations
 

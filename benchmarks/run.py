@@ -28,7 +28,7 @@ CI perf-regression smoke job.  Benches match the paper artifacts:
   stream    streaming tick pipeline: double-buffered ticks vs the sync
             loop, fused vs chunked newborn relax, bounded re-relaxation
             (all asserted bit-exact), + 1e6/1e7-user scale rows
-  kernels   Pallas kernel vs reference oracle timings (interpret mode)
+  kernels   Pallas kernel vs reference oracle timings (interpreted on CPU)
   roofline  dry-run derived roofline terms per (arch x shape)
 """
 from __future__ import annotations
@@ -69,6 +69,8 @@ def main() -> None:
                     help="reduced sizes/repeats (sets REPRO_BENCH_SMOKE=1) — "
                          "the CI perf smoke mode")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.smoke:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
 

@@ -369,7 +369,9 @@ class Population:
 
     ``backend``: ``minplus``/``banded`` (float64 numpy, bit-exact vs
     ``Plan.solve()``), ``jnp``/``pallas`` (float32 engines), ``mesh``
-    (float32, sharded over the user axis of a jax device mesh).
+    (float32, sharded over the user axis of a jax device mesh — every
+    visible device unless ``mesh`` names one, see
+    ``sharding.population.population_mesh``).
     """
 
     def __init__(self, network: Network, profile: DNNProfile,
@@ -381,7 +383,7 @@ class Population:
                  max_states: int = 65536, vector_postpass: bool = True,
                  bounded_rerelax: bool = True, timing: bool = False,
                  telemetry: Optional[TelemetryPolicy] = None,
-                 fused_ingest: str = "numpy"):
+                 fused_ingest: str = "numpy", mesh=None):
         if n_users <= 0:
             raise ValueError(f"n_users must be positive, got {n_users}")
         if backend != "mesh" and DP_BACKENDS.get(backend) is None:
@@ -395,6 +397,8 @@ class Population:
         if gamma >= np.iinfo(np.int16).max:
             raise ValueError(f"gamma {gamma} overflows the int16 state "
                              f"encoding")
+        if mesh is not None and backend != "mesh":
+            raise ValueError("mesh= only applies with backend='mesh'")
         if fused_ingest not in ("numpy", "jnp"):
             raise ValueError(f"unknown fused_ingest backend "
                              f"{fused_ingest!r} (expected numpy or jnp)")
@@ -491,6 +495,7 @@ class Population:
         #: (exit, placement) -> (energy, e_comp, e_comm); cleared with the
         #: state table on compute-slice churn
         self._cfg_energy: Dict[Tuple, Tuple[float, float, float]] = {}
+        self._mesh_arg = mesh
         self._mesh_relaxer = None
         self._fallback_plan: Optional[Plan] = None
         #: vectorized frontier post-pass (core/frontier.py): all (candidate,
@@ -559,6 +564,12 @@ class Population:
         (``_best_feasible`` only ever returns exactly-feasible configs, so
         found == feasible, mirroring ``Solution.feasible``)."""
         return self._inc_exit >= 0
+
+    def incumbents(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Copies of every user's incumbent: (U, L) placement, (U,) exit
+        and (U,) energy (exit -1 and energy inf where none is feasible)."""
+        return (self._inc_place.copy(), self._inc_exit.copy(),
+                self._inc_energy.copy())
 
     def solution(self, u: int) -> Optional[Solution]:
         return self._solutions[u]
@@ -1203,7 +1214,7 @@ class Population:
     def _mesh(self):
         if self._mesh_relaxer is None:
             from repro.sharding.population import MeshRelaxer
-            self._mesh_relaxer = MeshRelaxer()
+            self._mesh_relaxer = MeshRelaxer(self._mesh_arg)
         return self._mesh_relaxer
 
     # ------------------------------------------------------------- post-pass

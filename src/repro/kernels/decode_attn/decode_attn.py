@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..platform import interpret_mode
+
 NEG = -3.0e38         # python float: kernels must not capture traced constants
 
 
@@ -71,7 +73,7 @@ def _decode_attn_kernel(window, q_ref, k_ref, v_ref, cpos_ref, pos_ref,
                    static_argnames=("window", "bt", "interpret"))
 def decode_attn_pallas(q, k_cache, v_cache, cache_pos, pos, *,
                        window: int = 0, bt: int = 512,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret=None) -> jnp.ndarray:
     """q: [B, H, D]; k/v: [B, T, KV, D]; cache_pos: [T] i32; pos scalar i32.
     Returns [B, H, D] (same dtype as q)."""
     B, H, D = q.shape
@@ -102,6 +104,6 @@ def decode_attn_pallas(q, k_cache, v_cache, cache_pos, pos, *,
         scratch_shapes=[pltpu.VMEM((G,), jnp.float32),
                         pltpu.VMEM((G,), jnp.float32),
                         pltpu.VMEM((G, D), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qg, k_cache, v_cache, cache_pos, pos_arr)
     return out.reshape(B, H, D)

@@ -11,7 +11,10 @@ reduction instead of softmax + max + argmax passes over HBM.
 
 Tiling: grid (B/bb, V/bv); V minor.  Scratch carries the running max, the
 running sum of exponentials (rescaled flash-style on max updates), and the
-running argmax, all [bb] in SMEM-like VMEM scratch.
+running argmax, all [bb, 1] VMEM columns; the outputs are [B, 1] columns
+too, so every block is (8k, 1)-shaped and any batch size lowers (a rank-1
+block must span its array or be a multiple of 128 lanes).  Compiled on TPU,
+interpreted on CPU (``kernels/platform.py``).
 """
 from __future__ import annotations
 
@@ -21,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..platform import interpret_mode
 
 NEG = -3.0e38         # python float: kernels must not capture traced constants
 
@@ -39,14 +44,14 @@ def _ee_gate_kernel(logits_ref, conf_ref, arg_ref, m_ref, s_ref, a_ref):
     x = jnp.maximum(x, NEG)                            # -inf padding safe
     bv = x.shape[1]
     base = j * bv
-    local_max = x.max(axis=1)
-    local_arg = base + jnp.argmax(x, axis=1).astype(jnp.int32)
+    local_max = x.max(axis=1, keepdims=True)           # [bb, 1]
+    local_arg = base + jnp.argmax(x, axis=1, keepdims=True).astype(jnp.int32)
 
     m_old = m_ref[...]
     m_new = jnp.maximum(m_old, local_max)
     # rescale old sum, add this block's mass
     s_ref[...] = (s_ref[...] * jnp.exp(m_old - m_new)
-                  + jnp.exp(x - m_new[:, None]).sum(axis=1))
+                  + jnp.exp(x - m_new).sum(axis=1, keepdims=True))
     a_ref[...] = jnp.where(local_max > m_old, local_arg, a_ref[...])
     m_ref[...] = m_new
 
@@ -58,7 +63,7 @@ def _ee_gate_kernel(logits_ref, conf_ref, arg_ref, m_ref, s_ref, a_ref):
 
 @functools.partial(jax.jit, static_argnames=("bb", "bv", "interpret"))
 def ee_gate_pallas(logits: jnp.ndarray, *, bb: int = 8, bv: int = 2048,
-                   interpret: bool = True):
+                   interpret=None):
     """logits: [B, V] (any float; -inf padding ok).
     Returns (conf [B] f32, argmax [B] i32)."""
     B, V = logits.shape
@@ -73,13 +78,13 @@ def ee_gate_pallas(logits: jnp.ndarray, *, bb: int = 8, bv: int = 2048,
         _ee_gate_kernel,
         grid=(Bp // bb, Vp // bv),
         in_specs=[pl.BlockSpec((bb, bv), lambda i, j: (i, j))],
-        out_specs=[pl.BlockSpec((bb,), lambda i, j: (i,)),
-                   pl.BlockSpec((bb,), lambda i, j: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((Bp,), jnp.float32),
-                   jax.ShapeDtypeStruct((Bp,), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((bb,), jnp.float32),
-                        pltpu.VMEM((bb,), jnp.float32),
-                        pltpu.VMEM((bb,), jnp.int32)],
-        interpret=interpret,
+        out_specs=[pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
+                   pl.BlockSpec((bb, 1), lambda i, j: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((Bp, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((Bp, 1), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((bb, 1), jnp.float32),
+                        pltpu.VMEM((bb, 1), jnp.float32),
+                        pltpu.VMEM((bb, 1), jnp.int32)],
+        interpret=interpret_mode(interpret),
     )(x)
-    return conf[:B], arg[:B]
+    return conf[:B, 0], arg[:B, 0]

@@ -1,4 +1,4 @@
-"""Jitted wrapper for the ee_gate Pallas kernel (interpret=True on CPU)."""
+"""Jitted wrapper for the ee_gate Pallas kernel (interpreted on CPU)."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -6,6 +6,6 @@ import jax.numpy as jnp
 from .ee_gate import ee_gate_pallas
 
 
-def ee_gate(logits: jnp.ndarray, *, interpret: bool = True):
+def ee_gate(logits: jnp.ndarray):
     """logits: [B, V] -> (confidence [B], greedy token [B])."""
-    return ee_gate_pallas(logits, interpret=interpret)
+    return ee_gate_pallas(logits)

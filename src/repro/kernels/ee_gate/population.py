@@ -18,7 +18,7 @@ Two backends, selected per call:
             ``_requant_users`` + ``_enc_int16`` composition (same
             ``_quant_raw`` formulas, same copyto semantics), one int16
             output and no float64 pack materialization.
-``jnp``     one jitted XLA launch under a *scoped* ``enable_x64`` context
+``jnp``     one jitted XLA launch under a *scoped* ``jax.enable_x64`` context
             (the repo never enables x64 globally — the f32 relaxation
             engines must keep their dtypes).  float64 on CPU XLA follows
             the same IEEE arithmetic as numpy, and ``jnp.round`` matches
@@ -136,9 +136,9 @@ def _jnp_program(modes: Tuple[str, ...], gamma: int):
 def quant_signature_jnp(vec: np.ndarray, c: QuantConsts) -> np.ndarray:
     """One fused XLA launch under a scoped x64 context — bit-exact vs the
     numpy oracle (asserted by tests and the bench's ``agree`` column)."""
-    from jax.experimental import enable_x64
+    import jax
     fn = _jnp_program(c.modes, int(c.gamma))
-    with enable_x64():
+    with jax.enable_x64(True):
         out = fn(np.asarray(vec, dtype=np.float64), c.bits_pack, c.C_pack,
                  c.mask_pack, c.load_pack, np.float64(c.delta))
         return np.asarray(out)

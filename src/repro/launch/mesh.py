@@ -18,11 +18,7 @@ import numpy as np
 
 import jax
 
-try:
-    from jax.sharding import AxisType, Mesh
-except ImportError:          # jax < 0.5: no explicit-sharding axis types
-    from jax.sharding import Mesh
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -44,8 +40,6 @@ def _mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, have {len(devs)} — launch via "
             f"dryrun.py (sets --xla_force_host_platform_device_count)")
-    if AxisType is None:     # older jax: meshes are implicitly Auto-typed
-        return jax.make_mesh(shape, axes, devices=devs[:n])
     return jax.make_mesh(shape, axes, devices=devs[:n],
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -1,9 +1,12 @@
 """Serving launcher:  PYTHONPATH=src python -m repro.launch.serve \
-    --arch qwen3-4b --requests 16 --max-new 8 [--threshold 0.7]
+    --arch qwen3-4b --requests 16 --max-new 8 [--cache-len 512] \
+    [--threshold 0.7] [--reduced]
 
-Runs the split-serving engine (exit-aware continuous batching) on the
-reduced config with a FIN placement over the paper's mobile-edge-cloud
-system, and reports throughput / exit usage / placement-model energy.
+Runs the split-serving engine (exit-aware continuous batching) with a FIN
+placement over the paper's mobile-edge-cloud system, and reports
+throughput / exit usage / placement-model energy.  The model runs at its
+published widths with random weights from ``--seed``; ``--reduced`` swaps
+in the tiny same-family config for CPU smoke runs.
 """
 from __future__ import annotations
 
@@ -14,6 +17,7 @@ import jax
 from repro.configs import ARCH_NAMES, get
 from repro.core import AppRequirements, paper_profile
 from repro.core.scenarios import paper_scenario
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.runtime.serve_engine import SplitServeEngine
 
@@ -24,15 +28,21 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--batch", type=int, default=4)
+    ap.add_argument("--cache-len", type=int, default=256)
     ap.add_argument("--threshold", type=float, default=0.7)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--reduced", action="store_true",
+                    help="the tiny same-family config (CPU smoke runs)")
     args = ap.parse_args()
 
-    cfg = get(args.arch, reduced=True)
+    use_compile_cache()
+    cfg = get(args.arch, reduced=args.reduced)
     if not cfg.has_decoder:
         raise SystemExit(f"{args.arch} is encoder-only; no serve path")
-    params = T.init_model(jax.random.PRNGKey(0), cfg)
+    params = jax.jit(T.init_model, static_argnums=1)(
+        jax.random.PRNGKey(args.seed), cfg)
     eng = SplitServeEngine(
-        cfg, params, batch_size=args.batch, cache_len=256,
+        cfg, params, batch_size=args.batch, cache_len=args.cache_len,
         thresholds=[args.threshold] * (len(cfg.exit_layer_list)),
         network=paper_scenario(), profile=paper_profile("h2"),
         req=AppRequirements(alpha=0.55, delta=8e-3))

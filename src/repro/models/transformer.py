@@ -70,8 +70,10 @@ def init_model(key, cfg: ArchConfig) -> dict:
     n = cfg.n_periods
     k_embed, k_head, k_layers, k_exits = jax.random.split(key, 4)
     period_keys = jax.random.split(k_layers, n)
-    periods = [_period_init(period_keys[i], cfg, dtype) for i in range(n)]
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *periods)
+    # vmapped over the period keys: the stack is built in place, never as
+    # n separate period pytrees plus their stacked copy (2x the layer
+    # weights at peak — what keeps a full-width init off a 16 GB chip)
+    stacked = jax.vmap(lambda k: _period_init(k, cfg, dtype))(period_keys)
     params = {
         "embed": embed_init(k_embed, cfg.padded_vocab, cfg.d_model, dtype),
         "layers": stacked,
@@ -358,24 +360,20 @@ def decode_step(params, cfg: ArchConfig, tokens, caches: dict, pos
     assert cfg.has_decoder, f"{cfg.name} is encoder-only"
     h = embed_apply(params["embed"], tokens)
     head = _lm_head_params(params, cfg)
-    exits: Dict[str, jnp.ndarray] = {}
-    new_segments = []
-    for (a, b) in _segments(cfg):
-        seg_cache = _slice_periods(caches, a, b)
 
-        def body(carry, xs):
-            pp, cache = xs
-            hh, new_cache = _period_decode(cfg, pp, carry, cache, pos)
-            return hh, new_cache
+    def body(carry, xs):
+        pp, cache = xs
+        hh, new_cache = _period_decode(cfg, pp, carry, cache, pos)
+        return hh, (new_cache, hh)
 
-        h, seg_new = jax.lax.scan(
-            body, h, (_slice_periods(params["layers"], a, b), seg_cache))
-        new_segments.append(seg_new)
-        if b < cfg.n_periods:
-            exits[f"exit_{b}"] = exit_head_apply(
-                params["exits"][f"exit_{b}"], cfg, h, head)[:, 0]
-    new_caches = jax.tree.map(
-        lambda *xs: jnp.concatenate(xs, axis=0), *new_segments)
+    # one scan over the whole stack: the exits read the per-period hidden
+    # states it emits, so no segment slices of the weights or caches (each
+    # a full copy inside the step) are ever materialized
+    h, (new_caches, hs) = jax.lax.scan(body, h, (params["layers"], caches))
+    exits: Dict[str, jnp.ndarray] = {
+        f"exit_{b}": exit_head_apply(params["exits"][f"exit_{b}"], cfg,
+                                     hs[b - 1], head)[:, 0]
+        for b in cfg.exit_layer_list}
     hn = rmsnorm(params["final_norm"], h, cfg.norm_eps)
     logits = lm_head_apply(head, hn, cfg.vocab_size)[:, 0]
     return logits, new_caches, exits

@@ -127,8 +127,12 @@ class SplitServeEngine:
         self.thresholds = list(thresholds) if thresholds is not None else \
             [0.9] * (self.n_exits - 1)
         self.caches = T.init_caches(cfg, batch_size, cache_len)
-        self._decode = jax.jit(
-            lambda p, c, t, pos: T.decode_step(p, cfg, t, c, pos))
+        #: the jitted step ``(params, caches, tokens [B, 1], pos) ->
+        #: (logits, caches, exits)``; it donates the caches, so the step
+        #: updates them in place instead of keeping a second copy live
+        self.decode_step = jax.jit(
+            lambda p, c, t, pos: T.decode_step(p, cfg, t, c, pos),
+            donate_argnums=(1,))
         self.slots: List[Optional[Request]] = [None] * batch_size
         self.queue: List[Request] = []
         self.stats = EngineStats()
@@ -497,7 +501,7 @@ class SplitServeEngine:
             else:
                 toks[i, 0] = r.tokens[-1] if r.tokens else r.prompt[-1]
 
-        logits, self.caches, exits = self._decode(
+        logits, self.caches, exits = self.decode_step(
             self.params, self.caches, jnp.asarray(toks),
             jnp.int32(self.pos))
         self.pos += 1

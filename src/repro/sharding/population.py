@@ -11,14 +11,17 @@ running one shard per device with the distance grid carried on-device
 across the layer scan (nothing round-trips through the host between
 layers).
 
-On this container the mesh is host-platform devices
+The per-shard engine is the jitted jnp layer scan
+(``bellman_ford._banded_relax_scan_jnp``), not the banded Pallas kernel:
+on CPU the mesh is host-platform devices
 (``XLA_FLAGS=--xla_force_host_platform_device_count=4`` before importing
-jax — see the README scaling quickstart); on TPU the same program lands on
-real chips with the banded Pallas kernel as the per-shard engine
-(``interpret=False`` in ``kernels/minplus``).  Like the ``jnp``/``pallas``
-backends, the mesh engine relaxes in float32 — ``Population`` widens its
-exit-prune guard accordingly (``tolerances.DIST_RTOL_F32``); the float64
-numpy fallback (``backend="minplus"``) remains the bit-exact reference.
+jax — see the README scaling quickstart), on TPU the same XLA program lands
+on the chips.  (The Pallas kernels behind ``backend="pallas"`` compile on
+TPU and are interpreted only on CPU — ``kernels/platform.py``.)  Like the
+``jnp``/``pallas`` backends, the mesh engine relaxes in float32 —
+``Population`` widens its exit-prune guard accordingly
+(``tolerances.DIST_RTOL_F32``); the float64 numpy fallback
+(``backend="minplus"``) remains the bit-exact reference.
 
 Multi-host: when the mesh spans devices of several ``jax.distributed``
 processes (launch each with ``JAX_COORDINATOR_ADDRESS`` /
@@ -95,12 +98,12 @@ class MeshRelaxer:
     hides dispatch overhead.
     """
 
-    #: dispatch failures the retry/demotion ladder absorbs: simulated and
-    #: real collective timeouts, socket-level host losses, and runtime
-    #: errors out of the distributed XLA client (XlaRuntimeError is a
-    #: RuntimeError subclass) — shape/value errors raise before dispatch
-    #: and are never retried
-    RECOVERABLE = (TimeoutError, OSError, RuntimeError)
+    #: dispatch failures the retry/demotion ladder absorbs: collective
+    #: timeouts (the watchdog's and simulated stalls) and socket-level host
+    #: losses.  Compile and programming errors (XLA raises them as
+    #: RuntimeError) are NOT in the set: they propagate instead of being
+    #: demoted to a smaller mesh while the run carries on
+    RECOVERABLE = (TimeoutError, ConnectionError)
 
     def __init__(self, mesh: Optional[Mesh] = None, *,
                  timeout_s: Optional[float] = None, max_retries: int = 2,
@@ -117,6 +120,8 @@ class MeshRelaxer:
         self.fault_hook: Optional[Callable[[int], None]] = None
         self.retries = 0             # dispatch attempts beyond the first
         self.demotions = 0           # mesh-ladder rungs taken
+        #: devices that held the input shards of the last dispatch
+        self.last_shard_devices: Tuple = ()
 
     def _build(self, mesh: Mesh) -> None:
         self.mesh = mesh
@@ -260,6 +265,8 @@ class MeshRelaxer:
         dev = jax.device_put(jnp.asarray(initf), self._sharding)
         Ed = jax.device_put(jnp.asarray(Ef), self._sharding)
         sd = jax.device_put(jnp.asarray(sti), self._sharding)
+        self.last_shard_devices = tuple(s.device
+                                        for s in Ed.addressable_shards)
         h, p = _mesh_relax(dev, Ed, sd, lo)
         hist = np.asarray(h, np.float64)[:D]
         par = np.asarray(p).astype(np.int64)[:D]

@@ -147,3 +147,18 @@ def test_nonrecoverable_errors_are_not_retried():
     with pytest.raises(KeyError):
         mr.relax(init, E, steep, None)
     assert mr.retries == 0 and mr.demotions == 0
+
+
+def test_compile_errors_propagate_without_demotion():
+    """XLA raises compile/programming faults as RuntimeError: those must
+    surface, not shed the mesh while the run carries on."""
+    mr = MeshRelaxer(population_mesh(), max_retries=3, backoff_s=0.0)
+    init, E, steep = _case(2, seed=26)
+
+    def compile_fault(attempt):
+        raise RuntimeError("INTERNAL: Mosaic failed to compile")
+
+    mr.fault_hook = compile_fault
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        mr.relax(init, E, steep, None)
+    assert mr.retries == 0 and mr.demotions == 0

@@ -414,3 +414,15 @@ try:
         _random_population_run(seed, quantize, gamma)
 except ImportError:          # pragma: no cover - hypothesis optional
     pass
+
+
+def test_population_mesh_arg_requires_mesh_backend(network):
+    from repro.sharding.population import population_mesh
+    prof = paper_profile("h1")
+    req = PAPER_MULTIAPP_REQS["h1"]
+    with pytest.raises(ValueError, match="mesh= only applies"):
+        Population(network, prof, req, 2, mesh=population_mesh(1))
+    pop = Population(network, prof, req, 2, backend="mesh",
+                     mesh=population_mesh(1))
+    pop.solve()
+    assert pop._mesh_relaxer.n_devices == 1
