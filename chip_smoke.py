@@ -121,6 +121,28 @@ def _assert_same_decisions(name: str, ref_name: str, t: int,
                                rtol=DIST_RTOL_F32, err_msg=ctx)
 
 
+class CompileCounter:
+    """Counts XLA backend compiles (JAX's compile-duration events) while
+    registered; ``n`` is the running total.  A persistent-cache hit
+    records no such event."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.n = 0
+
+    def __call__(self, event: str, duration_secs: float, **kw) -> None:
+        if event == self.EVENT:
+            self.n += 1
+
+    def __enter__(self) -> "CompileCounter":
+        jax.monitoring.register_event_duration_secs_listener(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        jax.monitoring.unregister_event_duration_listener(self)
+
+
 def population_phase(n_users: int, variants: Mapping[str, Mapping],
                      *, ref: str, ticks: int = 5, seed: int = 0
                      ) -> Dict[str, object]:
@@ -129,8 +151,8 @@ def population_phase(n_users: int, variants: Mapping[str, Mapping],
     ``variants`` maps a name to ``population_cohorts`` keyword arguments
     (backend, mesh, ...); ``ref`` names the one every other variant must
     match tick by tick.  Returns ``{"tick_s": {name: [seconds per tick]},
-    "orchestrators": {name: ChurnOrchestrator}}``; tick 0 includes
-    compilation."""
+    "compiles": {name: [XLA compiles per tick]}, "orchestrators": {name:
+    ChurnOrchestrator}}``; tick 0 includes compilation."""
     draws = ar1_qualities(n_users, ticks, seed=seed)
     orchs = {name: ChurnOrchestrator(
                  population=population_cohorts(n_users, n_extra_edge=2,
@@ -138,12 +160,15 @@ def population_phase(n_users: int, variants: Mapping[str, Mapping],
                  hysteresis=0.05)
              for name, kw in variants.items()}
     tick_s: Dict[str, List[float]] = {name: [] for name in variants}
+    compiles: Dict[str, List[int]] = {name: [] for name in variants}
     for t in range(ticks):
         decisions = {}
         for name, orch in orchs.items():
-            t0 = time.perf_counter()
-            (rep,) = orch.run_arrays(draws[t:t + 1])
-            tick_s[name].append(time.perf_counter() - t0)
+            with CompileCounter() as cc:
+                t0 = time.perf_counter()
+                (rep,) = orch.run_arrays(draws[t:t + 1])
+                tick_s[name].append(time.perf_counter() - t0)
+            compiles[name].append(cc.n)
             assert rep.n_mesh_retries == 0 and rep.n_mesh_demotions == 0, (
                 f"{name} tick {t}: {rep.n_mesh_retries} mesh retries, "
                 f"{rep.n_mesh_demotions} demotions")
@@ -157,7 +182,7 @@ def population_phase(n_users: int, variants: Mapping[str, Mapping],
             launches = sum(p.stats.fused_relaxes + p.stats.chunked_relaxes
                            for p in orchs[name].pops)
             assert launches > 0, f"{name}: no relaxation reached the device"
-    return {"tick_s": tick_s, "orchestrators": orchs}
+    return {"tick_s": tick_s, "compiles": compiles, "orchestrators": orchs}
 
 
 def serving_phase(cfg, *, batch: int = 8, cache_len: int = 512,
@@ -257,6 +282,7 @@ def _report_population(out, n_users: int, kind: str) -> None:
               f"warmup_tick_s={ts[0]:.6f} "
               f"steady_tick_s={[round(x, 6) for x in steady]} "
               f"median_steady_tick_s={float(np.median(steady)):.6f} "
+              f"xla_compiles_per_tick={out['compiles'][name]} "
               f"(host wall clock around run_arrays, one {kind})",
               flush=True)
 

@@ -85,6 +85,41 @@ def relax_chunk_rows(bytes_per_row: int) -> int:
 
 
 # ---------------------------------------------------------------------------
+# device batch buckets (shared by the jnp/pallas engines and the mesh)
+# ---------------------------------------------------------------------------
+
+#: fewest scenario rows a device relaxation pads its batch to
+MIN_RELAX_ROWS = 16
+
+
+def bucket_rows(D: int, n_devices: int = 1) -> int:
+    """Rows per device that a device relaxation of ``D`` scenarios pads to:
+    at least ``MIN_RELAX_ROWS`` in all, rounded up to a power of two.
+
+    A tick's dirty-state count changes from tick to tick, and each new
+    batch shape is a new XLA/Mosaic compile (about a second per compile on
+    a TPU v5e).  Buckets bound a run to a few programs per chain length."""
+    per = max(-(-D // n_devices), -(-MIN_RELAX_ROWS // n_devices), 1)
+    return 1 << (per - 1).bit_length()
+
+
+def pad_relax_rows(init: np.ndarray, E: np.ndarray, st: np.ndarray,
+                   rows: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Pad a device relaxation's float32 init (D, N, G+1), E (D, L, N, N)
+    and int32 steepness to ``rows`` scenarios with empty chains (all-inf
+    init and energies, zero steepness).  Scenarios relax independently,
+    so callers strip the padding from the outputs with ``[:D]``."""
+    pad = rows - init.shape[0]
+    if pad <= 0:
+        return init, E, st
+    return (np.concatenate([init, np.full((pad,) + init.shape[1:], np.inf,
+                                          np.float32)]),
+            np.concatenate([E, np.full((pad,) + E.shape[1:], np.inf,
+                                       np.float32)]),
+            np.concatenate([st, np.zeros((pad,) + st.shape[1:], np.int32)]))
+
+
+# ---------------------------------------------------------------------------
 # numpy reference
 # ---------------------------------------------------------------------------
 
@@ -606,31 +641,33 @@ def batched_banded_relax_argmin(init: np.ndarray, E: np.ndarray,
     Returns (hist (B, L+1, N, G+1) float64, par_n (B, L, N, G+1) int64, -1
     where unreachable).  The parent *depth* is implied: g_src = g -
     steep[par_n, n].  Backends: ``jnp`` (float32 lax.scan) and ``pallas``
-    (the banded minplus kernel, one launch per layer).
+    (the chained banded minplus kernel).  Both pad the batch to
+    ``bucket_rows(B)`` scenarios.
     """
     B, N, Gp1 = init.shape
     L = E.shape[1]
     if L == 0:
         return (np.asarray(init)[:, None].astype(np.float64),
                 np.zeros((B, 0, N, Gp1), dtype=np.int64))
+    if backend not in ("jnp", "pallas"):
+        raise ValueError(f"unknown banded backend {backend!r}")
     finite = np.isfinite(steep)
-    sti = np.where(finite, steep, 0).astype(np.int32)
-    Ef = np.where(finite, E, np.inf).astype(np.float32)
-    initf = np.asarray(init, np.float32)
+    initf, Ef, sti = pad_relax_rows(
+        np.asarray(init, np.float32),
+        np.where(finite, E, np.inf).astype(np.float32),
+        np.where(finite, steep, 0).astype(np.int32), bucket_rows(B))
     if backend == "jnp":
         hist, par = _banded_relax_scan_jnp(jnp.asarray(initf),
                                            jnp.asarray(Ef), jnp.asarray(sti),
                                            lo)
-        return (np.asarray(hist, np.float64),
-                np.asarray(par).astype(np.int64))
-    if backend == "pallas":
-        from repro.kernels.minplus.ops import banded_minplus_chain
-        # one chained launch relaxes the whole (B, L) batch — the distance
-        # grid is carried in VMEM across layers instead of round-tripping
-        # through HBM between per-layer kernel calls
-        h, p = banded_minplus_chain(jnp.asarray(initf), jnp.asarray(Ef),
-                                    jnp.asarray(sti), lo=lo)
-        hist = np.concatenate([np.asarray(init, np.float64)[:, None],
-                               np.asarray(h, np.float64)], axis=1)
-        return hist, np.asarray(p).astype(np.int64)
-    raise ValueError(f"unknown banded backend {backend!r}")
+        return (np.asarray(hist, np.float64)[:B],
+                np.asarray(par).astype(np.int64)[:B])
+    from repro.kernels.minplus.ops import banded_minplus_chain
+    # one chained launch relaxes the whole (B, L) batch — the distance
+    # grid is carried in VMEM across layers instead of round-tripping
+    # through HBM between per-layer kernel calls
+    h, p = banded_minplus_chain(jnp.asarray(initf), jnp.asarray(Ef),
+                                jnp.asarray(sti), lo=lo)
+    hist = np.concatenate([np.asarray(init, np.float64)[:, None],
+                           np.asarray(h, np.float64)[:B]], axis=1)
+    return hist, np.asarray(p).astype(np.int64)[:B]

@@ -5,8 +5,8 @@
 Runs the split-serving engine (exit-aware continuous batching) with a FIN
 placement over the paper's mobile-edge-cloud system, and reports
 throughput / exit usage / placement-model energy.  The model runs at its
-published widths with random weights from ``--seed``; ``--reduced`` swaps
-in the tiny same-family config for CPU smoke runs.
+published widths with random weights; ``--reduced`` swaps in the tiny
+same-family config for CPU smoke runs.
 """
 from __future__ import annotations
 
@@ -30,7 +30,6 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--cache-len", type=int, default=256)
     ap.add_argument("--threshold", type=float, default=0.7)
-    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--reduced", action="store_true",
                     help="the tiny same-family config (CPU smoke runs)")
     args = ap.parse_args()
@@ -40,7 +39,7 @@ def main() -> None:
     if not cfg.has_decoder:
         raise SystemExit(f"{args.arch} is encoder-only; no serve path")
     params = jax.jit(T.init_model, static_argnums=1)(
-        jax.random.PRNGKey(args.seed), cfg)
+        jax.random.PRNGKey(0), cfg)
     eng = SplitServeEngine(
         cfg, params, batch_size=args.batch, cache_len=args.cache_len,
         thresholds=[args.threshold] * (len(cfg.exit_layer_list)),

@@ -49,7 +49,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core.bellman_ford import _banded_relax_scan_jnp
+from repro.core.bellman_ford import (_banded_relax_scan_jnp, bucket_rows,
+                                     pad_relax_rows)
 
 __all__ = ["population_mesh", "MeshRelaxer"]
 
@@ -91,19 +92,33 @@ class MeshRelaxer:
 
     ``relax`` has the ``bellman_ford.batched_banded_relax_argmin``
     contract: init (D, N, G+1), E/steep (D, L, N, N) -> (hist
-    (D, L+1, N, G+1) float64, par (D, L, N, G+1) int64).  D is padded to a
-    device multiple with empty (all-inf) scenarios; each device relaxes
-    its shard independently — there is no cross-shard communication in the
-    banded DP, so scaling is linear until the per-device shard no longer
-    hides dispatch overhead.
+    (D, L+1, N, G+1) float64, par (D, L, N, G+1) int64).  D is padded to
+    ``bucket_rows`` per device with empty (all-inf) scenarios; each device
+    relaxes its shard independently — there is no cross-shard
+    communication in the banded DP, so scaling is linear until the
+    per-device shard no longer hides dispatch overhead.
     """
 
     #: dispatch failures the retry/demotion ladder absorbs: collective
     #: timeouts (the watchdog's and simulated stalls) and socket-level host
-    #: losses.  Compile and programming errors (XLA raises them as
-    #: RuntimeError) are NOT in the set: they propagate instead of being
-    #: demoted to a smaller mesh while the run carries on
+    #: losses
     RECOVERABLE = (TimeoutError, ConnectionError)
+    #: XLA runtime status codes of a lost or stalled peer: a collective
+    #: whose peer died errors out as a ``RuntimeError`` (XLA's
+    #: ``JaxRuntimeError``) whose message starts with one of these.  Every
+    #: other status (INTERNAL, INVALID_ARGUMENT, UNIMPLEMENTED,
+    #: RESOURCE_EXHAUSTED, ...) is a compile or programming fault and
+    #: propagates instead of shedding the mesh
+    RECOVERABLE_STATUS = ("UNAVAILABLE", "DEADLINE_EXCEEDED", "ABORTED",
+                          "CANCELLED")
+
+    @classmethod
+    def recoverable(cls, e: BaseException) -> bool:
+        """Whether the retry/demotion ladder absorbs dispatch error ``e``."""
+        if isinstance(e, cls.RECOVERABLE):
+            return True
+        return (isinstance(e, RuntimeError)
+                and str(e).split(":", 1)[0].strip() in cls.RECOVERABLE_STATUS)
 
     def __init__(self, mesh: Optional[Mesh] = None, *,
                  timeout_s: Optional[float] = None, max_retries: int = 2,
@@ -191,11 +206,11 @@ class MeshRelaxer:
             try:
                 hist, par = self._dispatch(initf, Ef, sti, lo, D)
                 break
-            except self.RECOVERABLE:
+            except Exception as e:
                 # the retry budget at this rung is spent: shed capacity
                 # and try the smaller mesh (bit-exact per-scenario), or
                 # give up at the bottom of the ladder
-                if not self.demote():
+                if not self.recoverable(e) or not self.demote():
                     raise
         # layer-0 history: the exact float64 init (parity with the jnp
         # engine, whose callers read hist[0] as the untouched init grid)
@@ -215,7 +230,9 @@ class MeshRelaxer:
                 if self.fault_hook is not None:
                     self.fault_hook(attempt)
                 return self._relax_once(initf, Ef, sti, lo, D)
-            except self.RECOVERABLE as e:
+            except Exception as e:
+                if not self.recoverable(e):
+                    raise
                 last = e
         raise last
 
@@ -246,22 +263,14 @@ class MeshRelaxer:
     def _relax_run(self, initf: np.ndarray, Ef: np.ndarray,
                    sti: np.ndarray, lo: Optional[int],
                    D: int) -> Tuple[np.ndarray, np.ndarray]:
-        _, N, Gp1 = initf.shape
-        L = Ef.shape[1]
         if self.multihost:
             return self._relax_global(initf, Ef, sti, lo, D)
-        # scenario counts not divisible by the device count pad with
-        # empty (all-inf) chains and strip them from the outputs —
-        # callers never pre-shape
+        # pad to a bucket of rows per device with empty (all-inf) chains
+        # and strip them from the outputs — callers never pre-shape, and
+        # a stream of varying batch sizes compiles only a few programs
         n = self.n_devices
-        pad = (-D) % n
-        if pad:
-            initf = np.concatenate(
-                [initf, np.full((pad, N, Gp1), np.inf, np.float32)])
-            Ef = np.concatenate(
-                [Ef, np.full((pad, L, N, N), np.inf, np.float32)])
-            sti = np.concatenate(
-                [sti, np.zeros((pad, L, N, N), np.int32)])
+        initf, Ef, sti = pad_relax_rows(initf, Ef, sti,
+                                        n * bucket_rows(D, n))
         dev = jax.device_put(jnp.asarray(initf), self._sharding)
         Ed = jax.device_put(jnp.asarray(Ef), self._sharding)
         sd = jax.device_put(jnp.asarray(sti), self._sharding)
@@ -282,19 +291,11 @@ class MeshRelaxer:
         movement, run the SPMD program, and read back only their own
         addressable shards."""
         from jax.experimental import multihost_utils
-        _, N, Gp1 = initf.shape
-        L = Ef.shape[1]
         counts = np.asarray(
             multihost_utils.process_allgather(np.asarray([D])),
             dtype=np.int64).reshape(-1)
-        rows = max(1, int(-(-counts.max() // self._n_local)))
-        pad = rows * self._n_local - D
-        if pad:
-            initf = np.concatenate(
-                [initf, np.full((pad, N, Gp1), np.inf, np.float32)])
-            Ef = np.concatenate(
-                [Ef, np.full((pad, L, N, N), np.inf, np.float32)])
-            sti = np.concatenate([sti, np.zeros((pad, L, N, N), np.int32)])
+        rows = bucket_rows(int(counts.max()), self._n_local)
+        initf, Ef, sti = pad_relax_rows(initf, Ef, sti, rows * self._n_local)
 
         def mk(x):
             return jax.make_array_from_process_local_data(

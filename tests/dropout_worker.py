@@ -43,8 +43,13 @@ init = np.where(rng.random((D, N, Gp1)) < 0.3,
                 rng.random((D, N, Gp1)), np.inf)
 
 # fail every attempt at the multi-host rung (max_retries=1 -> 2 attempts),
-# forcing one rung down the ladder; the local-rung attempt then succeeds
-mr.fault_hook = FaultPlan.stall_hook(2)
+# forcing one rung down the ladder; the local-rung attempt then succeeds.
+# The first attempt stalls into the watchdog's timeout, the second errors
+# out as XLA reports a collective whose peer died
+stall = FaultPlan.stall_hook(1)
+peer_lost = FaultPlan.stall_hook(
+    1, exc=lambda msg: jax.errors.JaxRuntimeError(f"UNAVAILABLE: {msg}"))
+mr.fault_hook = lambda attempt: (stall if attempt == 0 else peer_lost)(attempt)
 hist, par = mr.relax(init, E, steep, None)
 assert mr.demotions == 1, mr.demotions
 assert mr.retries == 1, mr.retries

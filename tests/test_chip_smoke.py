@@ -28,6 +28,8 @@ def test_population_phase_device_backends_match_reference(smoke):
                "mesh": {"backend": "mesh"}}, ref="minplus", ticks=3)
     assert set(out["tick_s"]) == {"minplus", "pallas", "mesh"}
     assert all(len(ts) == 3 for ts in out["tick_s"].values())
+    assert all(len(c) == 3 and min(c) >= 0
+               for c in out["compiles"].values())
     for orch in out["orchestrators"].values():
         assert sum(p.U for p in orch.pops) == 1000
 
@@ -56,6 +58,18 @@ def test_population_phase_rejects_differing_decisions(smoke, monkeypatch):
     with pytest.raises(AssertionError, match="exits differ"):
         smoke.population_phase(300, {"ref": {}, "m": {"backend": "mesh"}},
                                ref="ref", ticks=1)
+
+
+def test_compile_counter_counts_backend_compiles(smoke):
+    import jax
+    f = jax.jit(lambda x: x * 3 + 1)
+    with smoke.CompileCounter() as cc:
+        f(np.zeros((3, 7), np.float32))
+        f(np.ones((3, 7), np.float32))     # same shape: no new compile
+        f(np.zeros((5, 7), np.float32))
+    assert cc.n == 2
+    f(np.zeros((9, 7), np.float32))        # unregistered: not counted
+    assert cc.n == 2
 
 
 def test_serving_phase_reduced_model(smoke):

@@ -405,3 +405,43 @@ def test_relax_chunk_rows(monkeypatch):
     for bad in (0, -8):
         with pytest.raises(ValueError, match="bytes_per_row"):
             relax_chunk_rows(bad)
+
+
+@pytest.mark.parametrize("D,n_devices,rows", [
+    (1, 1, 16), (16, 1, 16), (17, 1, 32), (100, 1, 128),
+    (1, 4, 4), (16, 4, 4), (17, 4, 8), (100, 4, 32), (5, 3, 8)])
+def test_bucket_rows(D, n_devices, rows):
+    """Device relax batches pad to a power of two of rows per device, at
+    least MIN_RELAX_ROWS in all, and always hold every scenario."""
+    from repro.core.bellman_ford import MIN_RELAX_ROWS, bucket_rows
+    got = bucket_rows(D, n_devices)
+    assert got == rows
+    assert got * n_devices >= max(D, MIN_RELAX_ROWS)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_device_relax_buckets_batch_sizes(backend):
+    """Every batch size up to MIN_RELAX_ROWS reuses one compiled program,
+    and the padding never shows in the results."""
+    from repro.core.bellman_ford import (MIN_RELAX_ROWS,
+                                         _banded_relax_scan_jnp,
+                                         batched_banded_relax_argmin)
+    from repro.kernels.minplus.minplus import banded_minplus_chain_pallas
+    jitted = {"jnp": _banded_relax_scan_jnp,
+              "pallas": banded_minplus_chain_pallas}[backend]
+    rng = np.random.default_rng(5)
+    D, L, N, Gp1 = MIN_RELAX_ROWS, 3, 5, 13
+    steep = np.where(rng.random((D, L, N, N)) < 0.6,
+                     rng.integers(0, 4, (D, L, N, N)).astype(float), np.inf)
+    E = rng.random((D, L, N, N))
+    init = np.where(rng.random((D, N, Gp1)) < 0.4,
+                    rng.random((D, N, Gp1)), np.inf)
+    h_all, p_all = batched_banded_relax_argmin(init, E, steep,
+                                               backend=backend)
+    n0 = jitted._cache_size()
+    for b in (1, 3, 7, D):
+        h, p = batched_banded_relax_argmin(init[:b], E[:b], steep[:b],
+                                           backend=backend)
+        assert h.shape == (b, L + 1, N, Gp1) and p.shape == (b, L, N, Gp1)
+        assert np.array_equal(h, h_all[:b]) and np.array_equal(p, p_all[:b])
+    assert jitted._cache_size() == n0

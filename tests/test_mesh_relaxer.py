@@ -149,16 +149,52 @@ def test_nonrecoverable_errors_are_not_retried():
     assert mr.retries == 0 and mr.demotions == 0
 
 
-def test_compile_errors_propagate_without_demotion():
+@pytest.mark.parametrize("exc", [
+    RuntimeError("INTERNAL: Mosaic failed to compile"),
+    jax.errors.JaxRuntimeError("INTERNAL: Mosaic failed to compile"),
+    jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: Mosaic ran out of VMEM"),
+    jax.errors.JaxRuntimeError("INVALID_ARGUMENT: Mosaic shape mismatch"),
+], ids=["runtime", "internal", "resource_exhausted", "invalid_argument"])
+def test_compile_errors_propagate_without_demotion(exc):
     """XLA raises compile/programming faults as RuntimeError: those must
     surface, not shed the mesh while the run carries on."""
     mr = MeshRelaxer(population_mesh(), max_retries=3, backoff_s=0.0)
     init, E, steep = _case(2, seed=26)
 
     def compile_fault(attempt):
-        raise RuntimeError("INTERNAL: Mosaic failed to compile")
+        raise exc
 
     mr.fault_hook = compile_fault
     with pytest.raises(RuntimeError, match="Mosaic"):
         mr.relax(init, E, steep, None)
     assert mr.retries == 0 and mr.demotions == 0
+
+
+@pytest.mark.parametrize("error", [RuntimeError, jax.errors.JaxRuntimeError],
+                         ids=["runtime", "jax_runtime"])
+@pytest.mark.parametrize("status", MeshRelaxer.RECOVERABLE_STATUS)
+def test_lost_peer_runtime_errors_retry_then_demote(status, error):
+    """A collective whose peer died errors out as an XLA RuntimeError with
+    a lost-peer status: the ladder retries it, then sheds the mesh
+    (bit-exact per scenario) or re-raises at the bottom."""
+    mr = MeshRelaxer(population_mesh(), max_retries=1, backoff_s=0.0)
+    n0 = mr.n_devices
+    init, E, steep = _case(3, seed=27)
+    hc, pc = MeshRelaxer(population_mesh()).relax(init, E, steep, None)
+    budget = [mr.max_retries + 1]
+
+    def lost_peer(attempt):
+        if budget[0]:
+            budget[0] -= 1
+            raise error(f"{status}: connection reset")
+
+    mr.fault_hook = lost_peer
+    if n0 > 1:
+        h, p = mr.relax(init, E, steep, None)
+        assert mr.demotions == 1 and mr.n_devices == 1
+        assert np.array_equal(h, hc) and np.array_equal(p, pc)
+    else:
+        with pytest.raises(error, match=status):
+            mr.relax(init, E, steep, None)
+        assert mr.demotions == 0
+    assert mr.retries == 1

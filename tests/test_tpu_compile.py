@@ -16,10 +16,12 @@ import pytest
 from repro.kernels.ee_gate.ee_gate import ee_gate_pallas
 from repro.kernels.minplus.minplus import (banded_minplus_chain_kbest_pallas,
                                            banded_minplus_chain_pallas,
+                                           banded_minplus_pallas,
                                            minplus_argmin_pallas)
 
 # the population's shapes: paper_scenario(n_extra_edge=2) has 5 nodes,
-# gamma=10 gives 11 depth cells, a 5-block profile has 4 relax layers
+# gamma=10 gives 11 depth cells, and the deepest paper profiles (h1-h4,
+# 5 blocks) have 4 relax layers
 B, N, GP1, L = 64, 5, 11, 4
 #: qwen3-4b's padded vocab (151,936 rounded up to a multiple of 2,048)
 QWEN3_VOCAB_PAD = 153_600
@@ -45,8 +47,16 @@ def _compile(fn, one_chip, *shapes):
     return compiled
 
 
-BANDED = ((B, N, GP1), jnp.float32), ((B, L, N, N), jnp.float32), \
-    ((B, L, N, N), jnp.int32)
+def _banded(N):
+    return (((B, N, GP1), jnp.float32), ((B, L, N, N), jnp.float32),
+            ((B, L, N, N), jnp.int32))
+
+
+BANDED = _banded(N)
+#: the largest network the repo relaxes: paper_scenario(n_extra_edge=12)
+#: has 15 nodes; E/st enter the kernels as [..., N, N, 1] columns, whose
+#: VMEM blocks grow with N squared
+N_LARGEST = 15
 
 
 @pytest.mark.parametrize("lo", [None, 4])
@@ -59,6 +69,21 @@ def test_banded_chain_compiles(one_chip, lo):
 def test_banded_chain_kbest_compiles(one_chip, K):
     _compile(lambda d, e, s: banded_minplus_chain_kbest_pallas(
         d, e, s, K, interpret=False), one_chip, *BANDED)
+
+
+def test_banded_chains_compile_on_largest_network(one_chip):
+    _compile(lambda d, e, s: banded_minplus_chain_pallas(
+        d, e, s, interpret=False), one_chip, *_banded(N_LARGEST))
+    _compile(lambda d, e, s: banded_minplus_chain_kbest_pallas(
+        d, e, s, 3, interpret=False), one_chip, *_banded(N_LARGEST))
+
+
+def test_banded_layer_compiles_at_bench_size(one_chip):
+    # the largest single-layer case of benchmarks/bench_kernels.py
+    n, g = 64, 48
+    _compile(lambda d, e, s: banded_minplus_pallas(d, e, s, interpret=False),
+             one_chip, ((n, g + 1), jnp.float32), ((n, n), jnp.float32),
+             ((n, n), jnp.int32))
 
 
 def test_minplus_argmin_compiles(one_chip):
