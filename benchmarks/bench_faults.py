@@ -45,13 +45,11 @@ from typing import Iterable
 import numpy as np
 
 from repro.core.faults import FaultPlan, corrupt_specs
-from repro.core.online import ChurnOrchestrator, population_cohorts
+from repro.core.online import (TIMING_FIELDS, ChurnOrchestrator,
+                               population_cohorts)
 from repro.core.population import TelemetryPolicy
 
 from .common import Row, kv, smoke
-
-#: wall-clock fields excluded from the bit-identity assertion
-_TIMING = ("t_ingest_ms", "t_relax_ms", "t_post_ms", "t_reprice_ms")
 
 
 def _reports_equal(a, b) -> bool:
@@ -59,7 +57,7 @@ def _reports_equal(a, b) -> bool:
         return False
     for ra, rb in zip(a, b):
         da, db = dataclasses.asdict(ra), dataclasses.asdict(rb)
-        for k in _TIMING:
+        for k in TIMING_FIELDS:
             da.pop(k), db.pop(k)
         if da != db:
             return False

@@ -38,7 +38,6 @@ benchmark drive.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import (Callable, Dict, Iterable, List, Optional, Sequence,
                     Union)
@@ -54,10 +53,12 @@ from .population import Population
 from .problem import AppRequirements, Config
 from .scenarios import (MOBILE_UPLINK_BPS, ChurnEvent, churn_trace,
                         paper_scenario)
+from .spans import span
 from .system_model import Network
 
-__all__ = ["ChurnEvent", "churn_trace", "TickReport", "ChurnStats",
-           "ChurnOrchestrator", "population_plans", "population_cohorts"]
+__all__ = ["ChurnEvent", "churn_trace", "TickReport", "TIMING_FIELDS",
+           "ChurnStats", "ChurnOrchestrator", "population_plans",
+           "population_cohorts"]
 
 
 @dataclass
@@ -98,21 +99,32 @@ class TickReport:
     n_mesh_retries: int = 0      # mesh collective dispatch retries
     n_mesh_demotions: int = 0    # mesh demotion-ladder rungs taken
     n_stragglers: int = 0        # workers flagged by the straggler detector
-    # per-phase wall-ms breakdown (zero unless every cohort was built with
-    # ``Population(..., timing=True)``; reprice is timed by the
-    # orchestrator).  Streaming ticks overlap phases, so a tick's relax
-    # time may partially attribute to the tick whose ingest it overlapped
-    # with — sums over a run are exact either way.
-    t_ingest_ms: float = 0.0     # channel ingest + requantize
-    t_relax_ms: float = 0.0      # banded relaxation launches
-    t_post_ms: float = 0.0       # exact post-pass
-    t_reprice_ms: float = 0.0    # congestion fixed point (run_tick)
-    # post-pass sub-breakdown (subsets of t_post_ms — see PopulationStats):
-    # stacked candidate scans / shared fast-table broadcasts / per-user
-    # fallbacks.  Attributes the fused-kernel wins per phase.
-    t_post_scan_ms: float = 0.0
-    t_post_fast_ms: float = 0.0
-    t_post_fallback_ms: float = 0.0
+    # per-phase wall-ms breakdown of the program spans (``core/spans.py``;
+    # zero unless every cohort was built with ``Population(...,
+    # timing=True)``).  The cohorts' fields are PopulationStats deltas; the
+    # orchestrator's (reprice, gate, account) are timed on the tick itself.
+    # Streaming ticks overlap phases, so a tick's relax time may partially
+    # attribute to the tick whose ingest it overlapped with — sums over a
+    # run are exact either way.  Span names in brackets.
+    t_ingest_ms: float = 0.0     # channel ingest + requantize [pop.ingest]
+    t_relax_ms: float = 0.0      # banded relaxation launches [pop.relax]
+    t_post_ms: float = 0.0       # exact post-pass [pop.post]
+    t_rekey_ms: float = 0.0      # state-table re-key [pop.rekey]
+    t_group_ms: float = 0.0      # solve's (state, bandwidth) grouping
+    #                              [pop.group]
+    t_reprice_ms: float = 0.0    # congestion fixed point [orch.reprice]
+    t_gate_ms: float = 0.0       # hysteresis gate [orch.gate]
+    t_account_ms: float = 0.0    # resolve accounting, migration bits and
+    #                              the energy sum [orch.account]
+
+
+#: the cohorts' PopulationStats clocks a tick reports as deltas
+_POP_TIMING_FIELDS = ("t_ingest_ms", "t_relax_ms", "t_post_ms",
+                      "t_rekey_ms", "t_group_ms")
+#: every wall-clock field of TickReport: what a bit-exactness comparison of
+#: two runs' reports must leave out
+TIMING_FIELDS = _POP_TIMING_FIELDS + ("t_reprice_ms", "t_gate_ms",
+                                      "t_account_ms")
 
 
 @dataclass
@@ -233,6 +245,9 @@ class ChurnOrchestrator:
         #: injectable per-tick worker step-time provider (tests, external
         #: schedulers): a callable ``TickReport -> (H,) times``
         self.straggler_times: Optional[Callable] = None
+        #: the orchestrator's spans (``orch.*``) follow its cohorts' one
+        #: switch: on when every cohort was built with timing=True
+        self._timing = False
         if population is not None:
             self._init_population(population)
             if shared_capacity is not None:
@@ -267,6 +282,7 @@ class ChurnOrchestrator:
         if not pops:
             raise ValueError("population= needs at least one cohort")
         self.pops = pops
+        self._timing = all(p._timing for p in pops)
         U = sum(p.U for p in pops)
         self.n_users = U
         nw = pops[0].network0
@@ -346,7 +362,8 @@ class ChurnOrchestrator:
 
     def step(self, events: Sequence[ChurnEvent]) -> TickReport:
         if self.pops is not None:
-            return self._step_population(events)
+            with span(self._timing, None, None, "orch.tick"):
+                return self._step_population(events)
         rep = TickReport(tick=self._tick, n_events=len(events))
         self._tick += 1
         U = len(self.plans)
@@ -475,6 +492,7 @@ class ChurnOrchestrator:
         semantics and bit-exact same decisions as the per-plan path, with
         the funnel / gate / re-solve running as array programs."""
         rep = TickReport(tick=self._tick, n_events=len(events))
+        snap = self._timing_snapshot()   # the events' re-keys count
         self._tick += 1
         U = self.n_users
         uplink_mask = np.zeros(U, dtype=bool)
@@ -547,7 +565,7 @@ class ChurnOrchestrator:
                 topo_event = True       # slice churn clears the state table
             else:
                 raise ValueError(f"unknown churn event kind {ev.kind!r}")
-        self._population_tick(rep, uplink_mask, dirty_mask)
+        self._population_tick(rep, uplink_mask, dirty_mask, snap)
         # background refill: after a topology change (masks moved / state
         # table cleared), a quant re-key (new packs need new contingency
         # states) or a congestion reprice (backhaul rescale cleared the
@@ -576,40 +594,44 @@ class ChurnOrchestrator:
         """
         if self.pops is None:
             raise ValueError("step_arrays requires population mode")
-        U = self.n_users
-        rep = TickReport(tick=self._tick, n_events=0)
-        self._tick += 1
-        uplink_mask = np.zeros(U, dtype=bool)
-        dirty_mask = np.zeros(U, dtype=bool)
-        if quality is not None:
-            quality = np.asarray(quality, dtype=np.float64)
-            if quality.shape != (U,):
-                raise ValueError(f"quality must be shape ({U},), got "
-                                 f"{quality.shape}")
-            self.quality[:] = quality
-            uplink_mask[:] = True
-            dirty_mask[:] = True
-            rep.n_events += U
-        if attach is not None:
-            attach = np.asarray(attach, dtype=np.int64)
-            if attach.shape != (U,):
-                raise ValueError(f"attach must be shape ({U},), got "
-                                 f"{attach.shape}")
-            slots = attach % max(1, len(self._edge_nodes))
-            moved = slots != self.attached
-            if moved.any():
-                self.attached[moved] = slots[moved]
-                self._att_ver += 1
-            uplink_mask |= moved
-            dirty_mask |= moved
-            rep.n_events += int(moved.sum())
-        self._population_tick(rep, uplink_mask, dirty_mask, requant=False)
-        return rep
+        with span(self._timing, None, None, "orch.tick"):
+            U = self.n_users
+            rep = TickReport(tick=self._tick, n_events=0)
+            snap = self._timing_snapshot()
+            self._tick += 1
+            uplink_mask = np.zeros(U, dtype=bool)
+            dirty_mask = np.zeros(U, dtype=bool)
+            if quality is not None:
+                quality = np.asarray(quality, dtype=np.float64)
+                if quality.shape != (U,):
+                    raise ValueError(f"quality must be shape ({U},), got "
+                                     f"{quality.shape}")
+                self.quality[:] = quality
+                uplink_mask[:] = True
+                dirty_mask[:] = True
+                rep.n_events += U
+            if attach is not None:
+                attach = np.asarray(attach, dtype=np.int64)
+                if attach.shape != (U,):
+                    raise ValueError(f"attach must be shape ({U},), got "
+                                     f"{attach.shape}")
+                slots = attach % max(1, len(self._edge_nodes))
+                moved = slots != self.attached
+                if moved.any():
+                    self.attached[moved] = slots[moved]
+                    self._att_ver += 1
+                uplink_mask |= moved
+                dirty_mask |= moved
+                rep.n_events += int(moved.sum())
+            self._population_tick(rep, uplink_mask, dirty_mask, snap,
+                                  requant=False)
+            return rep
 
     def _population_tick(self, rep: TickReport, uplink_mask: np.ndarray,
-                         dirty_mask: np.ndarray,
+                         dirty_mask: np.ndarray, snap,
                          requant: bool = True) -> None:
-        snap = self._timing_snapshot()
+        """The gate, re-solve and accounting of one tick; ``snap`` is the
+        timing snapshot taken at the tick's entry."""
         q0 = self._quar_counters()
         # channel + mobility funnel: one vectorized ingest per cohort.
         # Dense ticks (every user dirty — the step_arrays common case)
@@ -670,19 +692,21 @@ class ChurnOrchestrator:
                 res = np.ones(len(gl), dtype=bool)
                 n_res = len(gl)
             else:
-                no_inc, feas, energy = p.evaluate_incumbents(
-                    None if all_dirty else loc)
-                thresh = self._ref_energy[gl] * (1.0 + self.hysteresis)
-                res = no_inc | ~feas | (energy > thresh)
-                n_res = int(np.count_nonzero(res))
-                rep.n_held += len(gl) - n_res
-                if n_res == 0:
-                    # everyone held: one aligned store, no boolean gathers
-                    self._cur_energy[gl] = energy
-                    continue
-                held = ~res
-                if held.any():
-                    self._cur_energy[gl[held]] = energy[held]
+                with span(self._timing, rep, "t_gate_ms", "orch.gate"):
+                    no_inc, feas, energy = p.evaluate_incumbents(
+                        None if all_dirty else loc)
+                    thresh = self._ref_energy[gl] * (1.0 + self.hysteresis)
+                    res = no_inc | ~feas | (energy > thresh)
+                    n_res = int(np.count_nonzero(res))
+                    rep.n_held += len(gl) - n_res
+                    if n_res == 0:
+                        # everyone held: one aligned store, no boolean
+                        # gathers
+                        self._cur_energy[gl] = energy
+                    else:
+                        held = ~res
+                        if held.any():
+                            self._cur_energy[gl[held]] = energy[held]
             if n_res == 0:
                 continue
 
@@ -697,14 +721,16 @@ class ChurnOrchestrator:
                 continue
             p.solve(loc_res, build_solutions=False)
             rep.n_resolved += len(loc_res)
-            self._account_resolves(rep, p, gl_res, loc_res, old_found,
-                                   old_place, migrated, moved_bits)
+            with span(self._timing, rep, "t_account_ms", "orch.account"):
+                self._account_resolves(rep, p, gl_res, loc_res, old_found,
+                                       old_place, migrated, moved_bits)
         # per-plan parity: migration bits accumulate per user in global
         # index order (float addition order matters)
-        mb = 0.0
-        for u in np.nonzero(migrated)[0]:
-            mb += float(moved_bits[u])
-        rep.migration_bits = mb
+        with span(self._timing, rep, "t_account_ms", "orch.account"):
+            mb = 0.0
+            for u in np.nonzero(migrated)[0]:
+                mb += float(moved_bits[u])
+            rep.migration_bits = mb
 
         # shared-capacity coupling: run the congestion-priced fixed point
         # over the freshly-churned incumbents, then resync the energy
@@ -714,10 +740,8 @@ class ChurnOrchestrator:
         # state) touches nothing, keeping coupled ticks bit-exact vs the
         # uncoupled path.
         if self.congestion is not None:
-            t_rp = time.perf_counter() if snap is not None else 0.0
-            crep = self.congestion.run_tick()
-            if snap is not None:
-                rep.t_reprice_ms = (time.perf_counter() - t_rp) * 1e3
+            with span(self._timing, rep, "t_reprice_ms", "orch.reprice"):
+                crep = self.congestion.run_tick()
             rep.congestion_iters = crep.iterations
             rep.congestion_converged = crep.converged
             rep.n_repriced = crep.n_repriced
@@ -740,8 +764,9 @@ class ChurnOrchestrator:
                     mg = np.asarray(crep.moved_gids, dtype=np.int64)
                     self._ref_energy[mg] = self._cur_energy[mg]
 
-        fin = np.isfinite(self._cur_energy)
-        rep.energy = float(self._cur_energy[fin].sum())
+        with span(self._timing, rep, "t_account_ms", "orch.account"):
+            fin = np.isfinite(self._cur_energy)
+            rep.energy = float(self._cur_energy[fin].sum())
         self._tick_fill(rep, snap)
 
     # ------------------------------------------------------- streaming ticks
@@ -825,41 +850,46 @@ class ChurnOrchestrator:
         reports: List[TickReport] = []
         prev = None          # in-flight tick: (rep, pendings, snap, pos)
         for t in range(T):
-            pos = off + t
-            if prev is not None and checkpoint_dir is not None \
-                    and every > 0 and pos % every == 0:
-                # boundary: drain the in-flight tick BEFORE this tick's
-                # ingest, so the checkpoint holds exactly ticks < pos
-                self._drain_tick(reports, prev, fault_plan)
-                prev = None
-                self.checkpoint(checkpoint_dir, trace_pos=pos,
-                                keep=checkpoint_keep)
-            if fault_plan is not None:
-                fault_plan.crash_hook("ingest", pos)
-            rep = TickReport(tick=self._tick)
-            self._tick += 1
-            snap = self._timing_snapshot()
-            self.quality[:] = qualities[t]
-            rep.n_events += U
-            if attaches is not None:
-                slots = attaches[t] % max(1, len(self._edge_nodes))
-                moved = slots != self.attached
-                n_moved = int(np.count_nonzero(moved))
-                if n_moved:
-                    self.attached[moved] = slots[moved]
-                    self._att_ver += 1
-                rep.n_events += n_moved
-            # ingest(t) overlaps relax(t-1): writes only the bandwidth
-            # store + stale flags, while the in-flight post-pass reads
-            # its begin-time snapshot
-            self._stream_ingest(rep)
-            if prev is not None:
-                self._drain_tick(reports, prev, fault_plan)
-            prev = (rep, self._gate_and_begin(rep), snap, pos)
-            if fault_plan is not None:
-                fault_plan.crash_hook("relax", pos)
-        if prev is not None:
-            self._drain_tick(reports, prev, fault_plan)
+            # one orch.tick span per step of the pipeline: tick t's
+            # ingest, tick t-1's finish and tick t's gate and begin (the
+            # last step also finishes its own tick)
+            with span(self._timing, None, None, "orch.tick"):
+                pos = off + t
+                if prev is not None and checkpoint_dir is not None \
+                        and every > 0 and pos % every == 0:
+                    # boundary: drain the in-flight tick BEFORE this tick's
+                    # ingest, so the checkpoint holds exactly ticks < pos
+                    self._drain_tick(reports, prev, fault_plan)
+                    prev = None
+                    self.checkpoint(checkpoint_dir, trace_pos=pos,
+                                    keep=checkpoint_keep)
+                if fault_plan is not None:
+                    fault_plan.crash_hook("ingest", pos)
+                rep = TickReport(tick=self._tick)
+                self._tick += 1
+                snap = self._timing_snapshot()
+                self.quality[:] = qualities[t]
+                rep.n_events += U
+                if attaches is not None:
+                    slots = attaches[t] % max(1, len(self._edge_nodes))
+                    moved = slots != self.attached
+                    n_moved = int(np.count_nonzero(moved))
+                    if n_moved:
+                        self.attached[moved] = slots[moved]
+                        self._att_ver += 1
+                    rep.n_events += n_moved
+                # ingest(t) overlaps relax(t-1): writes only the bandwidth
+                # store + stale flags, while the in-flight post-pass reads
+                # its begin-time snapshot
+                self._stream_ingest(rep)
+                if prev is not None:
+                    self._drain_tick(reports, prev, fault_plan)
+                prev = (rep, self._gate_and_begin(rep), snap, pos)
+                if fault_plan is not None:
+                    fault_plan.crash_hook("relax", pos)
+                if t + 1 == T:
+                    self._drain_tick(reports, prev, fault_plan)
+                    prev = None
         if checkpoint_dir is not None and T:
             self.checkpoint(checkpoint_dir, trace_pos=off + T,
                             keep=checkpoint_keep)
@@ -1046,31 +1076,11 @@ class ChurnOrchestrator:
             if self.always_resolve:
                 gl_res, loc_res = gl, loc
             else:
-                no_inc, feas, energy = p.evaluate_incumbents(None)
-                ref = self._ref_energy[gl] if sl is None \
-                    else self._ref_energy[sl]
-                res = energy > ref * (1.0 + self.hysteresis)
-                res |= ~feas
-                res |= no_inc
-                n_res = int(np.count_nonzero(res))
-                rep.n_held += p.U - n_res
-                cur = self._cur_energy if sl is None else \
-                    self._cur_energy[sl]
-                if n_res == 0:
-                    if sl is None:
-                        self._cur_energy[gl] = energy
-                    else:
-                        cur[:] = energy
+                with span(self._timing, rep, "t_gate_ms", "orch.gate"):
+                    gl_res, loc_res = self._gate_cohort(rep, p, gl, sl, loc)
+                if gl_res is None:
                     pendings.append(None)
                     continue
-                held = ~res
-                if held.any():
-                    if sl is None:
-                        self._cur_energy[gl[held]] = energy[held]
-                    else:
-                        cur[held] = energy[held]
-                gl_res = gl[res] if n_res < p.U else gl
-                loc_res = loc[res] if n_res < p.U else loc
             old_found = p._inc_exit[loc_res] >= 0
             old_place = p._inc_place[loc_res].copy()
             pend = p.solve_begin(loc_res, build_solutions=False,
@@ -1079,6 +1089,36 @@ class ChurnOrchestrator:
             pendings.append((p, pend, gl_res, loc_res, old_found,
                              old_place))
         return pendings
+
+    def _gate_cohort(self, rep: TickReport, p: Population, gl: np.ndarray,
+                     sl: Optional[slice], loc: np.ndarray):
+        """One cohort's hysteresis gate on a dense tick: re-check every
+        incumbent, store the held users' energies and return the
+        re-placing users' (global, local) ids, or (None, None) when
+        everyone holds."""
+        no_inc, feas, energy = p.evaluate_incumbents(None)
+        ref = self._ref_energy[gl] if sl is None else self._ref_energy[sl]
+        res = energy > ref * (1.0 + self.hysteresis)
+        res |= ~feas
+        res |= no_inc
+        n_res = int(np.count_nonzero(res))
+        rep.n_held += p.U - n_res
+        cur = self._cur_energy if sl is None else self._cur_energy[sl]
+        if n_res == 0:
+            if sl is None:
+                self._cur_energy[gl] = energy
+            else:
+                cur[:] = energy
+            return None, None
+        held = ~res
+        if held.any():
+            if sl is None:
+                self._cur_energy[gl[held]] = energy[held]
+            else:
+                cur[held] = energy[held]
+        if n_res == p.U:
+            return gl, loc
+        return gl[res], loc[res]
 
     def _finish_tick(self, rep: TickReport, pendings: list, snap) -> None:
         """Join every cohort's in-flight relaxation, run the post-passes
@@ -1093,23 +1133,26 @@ class ChurnOrchestrator:
             p, pend, gl_res, loc_res, old_found, old_place = item
             p.solve_finish(pend)
             relax_s += p._last_relax_s
-            self._account_resolves(rep, p, gl_res, loc_res, old_found,
-                                   old_place, migrated, moved_bits)
+            with span(self._timing, rep, "t_account_ms", "orch.account"):
+                self._account_resolves(rep, p, gl_res, loc_res, old_found,
+                                       old_place, migrated, moved_bits)
         # the adaptive-overlap signal: what a background relax could hide
         self._overlap_relax_s += 0.3 * (relax_s - self._overlap_relax_s)
-        mb = 0.0
-        for u in np.nonzero(migrated)[0]:
-            mb += float(moved_bits[u])
-        rep.migration_bits = mb
-        # all-finite fast path: the full contiguous sum partitions exactly
-        # like the all-True gathered sum (same pairwise tree), and any
-        # inf/nan poisons the total so the guard catches the mixed case
-        s = float(self._cur_energy.sum())
-        if np.isfinite(s):
-            rep.energy = s
-        else:
-            fin = np.isfinite(self._cur_energy)
-            rep.energy = float(self._cur_energy[fin].sum())
+        with span(self._timing, rep, "t_account_ms", "orch.account"):
+            mb = 0.0
+            for u in np.nonzero(migrated)[0]:
+                mb += float(moved_bits[u])
+            rep.migration_bits = mb
+            # all-finite fast path: the full contiguous sum partitions
+            # exactly like the all-True gathered sum (same pairwise tree),
+            # and any inf/nan poisons the total so the guard catches the
+            # mixed case
+            s = float(self._cur_energy.sum())
+            if np.isfinite(s):
+                rep.energy = s
+            else:
+                fin = np.isfinite(self._cur_energy)
+                rep.energy = float(self._cur_energy[fin].sum())
         self._tick_fill(rep, snap)
 
     def _tick_fill(self, rep: TickReport, snap) -> None:
@@ -1180,7 +1223,7 @@ class ChurnOrchestrator:
         if self.straggler_times is not None:
             times = np.asarray(self.straggler_times(rep), dtype=np.float64)
         else:
-            if not all(p._timing for p in self.pops):
+            if not self._timing:
                 return          # no clock to feed the detector
             times = self._gather_relax_times(rep)
         from ..runtime.straggler import StragglerDetector
@@ -1210,22 +1253,18 @@ class ChurnOrchestrator:
                 np.asarray([t]))).reshape(-1)
         return np.asarray([t])
 
-    _TIMING_FIELDS = ("t_ingest_ms", "t_relax_ms", "t_post_ms",
-                      "t_post_scan_ms", "t_post_fast_ms",
-                      "t_post_fallback_ms")
-
     def _timing_snapshot(self):
         """Sums of the cohorts' phase clocks, or None when any cohort has
         timing disabled (keeping the breakdown zero-cost by default)."""
-        if self.pops is None or not all(p._timing for p in self.pops):
+        if not self._timing:
             return None
         return tuple(sum(getattr(p.stats, f) for p in self.pops)
-                     for f in self._TIMING_FIELDS)
+                     for f in _POP_TIMING_FIELDS)
 
     def _timing_fill(self, rep: TickReport, snap) -> None:
         if snap is None:
             return
-        for i, f in enumerate(self._TIMING_FIELDS):
+        for i, f in enumerate(_POP_TIMING_FIELDS):
             setattr(rep, f,
                     sum(getattr(p.stats, f) for p in self.pops) - snap[i])
 

@@ -68,6 +68,7 @@ from .frontier import (ParetoFrontier, eval_config_users, frontier_from_rows,
                        scan_state_users)
 from .plan import Plan, _validate_bps_values, _validate_population_bps
 from .problem import AppRequirements, Config, ConfigEval, Solution
+from .spans import span
 from .system_model import Network
 from .tolerances import dist_tol
 
@@ -137,18 +138,16 @@ class PopulationStats:
     telemetry_clamped: int = 0   # entries clamped to last known good
     quarantines: int = 0         # users entering quarantine
     recoveries: int = 0          # users leaving quarantine
-    # per-phase wall clock (accumulated only when the Population was built
-    # with timing=True — the counters stay zero-cost when disabled)
-    t_ingest_ms: float = 0.0     # channel ingest + requantize
-    t_relax_ms: float = 0.0      # banded relaxation launches
-    t_post_ms: float = 0.0       # exact post-pass (solve minus relax)
-    # post-pass sub-breakdown (subsets of t_post_ms): the general stacked
-    # candidate scans, the shared fast-table broadcasts, and the per-user
-    # Plan fallbacks.  A fallback issued from inside a scan's no-feasible
-    # branch counts in BOTH t_post_scan_ms and t_post_fallback_ms.
-    t_post_scan_ms: float = 0.0
-    t_post_fast_ms: float = 0.0
-    t_post_fallback_ms: float = 0.0
+    rekeyed_users: int = 0       # user rows passed to the state re-key
+    # per-phase wall clock of the program spans (``core/spans.py``;
+    # accumulated only when the Population was built with timing=True —
+    # zero-cost when disabled).  Span names in brackets.
+    t_ingest_ms: float = 0.0     # channel ingest + requantize [pop.ingest]
+    t_relax_ms: float = 0.0      # banded relaxation launches [pop.relax]
+    t_post_ms: float = 0.0       # exact post-pass [pop.post]
+    t_rekey_ms: float = 0.0      # state-table re-key [pop.rekey]
+    t_group_ms: float = 0.0      # solve's (state, bandwidth) grouping
+    #                              [pop.group]
 
 
 def _group_runs(keys: np.ndarray
@@ -599,32 +598,28 @@ class Population:
         quantization work without changing any decision or solution.
         Returns None in that case (the change flags are not yet known).
         """
-        t0 = time.perf_counter() if self._timing else 0.0
-        users = (np.arange(self.U) if users is None
-                 else np.asarray(users, dtype=np.int64))
-        Us = len(users)
-        self._bw_dense()      # partial write + last-known-good reads below
-        arr = _validate_population_bps(bps, Us, self.N)
-        vec = np.empty((Us, self.N))
-        vec[:] = arr if arr.ndim == 2 else \
-            (np.broadcast_to(np.asarray(arr, dtype=np.float64)
-                             .reshape(-1, 1), (Us, self.N)))
-        vec[:, self.src] = np.inf                # self-loop (Sec. II-A)
-        if not self._suspend_telemetry:
-            self._screen_rows(users, vec)
-        self._bw_vec[users] = vec
-        self.stats.ingests += 1
-        self.stats.uplink_updates += Us
-        if not requant:
-            self._stale[users] = True
-            if self._timing:
-                self.stats.t_ingest_ms += (time.perf_counter() - t0) * 1e3
-            return None
-        changed = self._requant_users(users, vec)
-        self._stale[users] = False
-        if self._timing:
-            self.stats.t_ingest_ms += (time.perf_counter() - t0) * 1e3
-        return changed
+        with span(self._timing, self.stats, "t_ingest_ms", "pop.ingest"):
+            users = (np.arange(self.U) if users is None
+                     else np.asarray(users, dtype=np.int64))
+            Us = len(users)
+            self._bw_dense()  # partial write + last-known-good reads below
+            arr = _validate_population_bps(bps, Us, self.N)
+            vec = np.empty((Us, self.N))
+            vec[:] = arr if arr.ndim == 2 else \
+                (np.broadcast_to(np.asarray(arr, dtype=np.float64)
+                                 .reshape(-1, 1), (Us, self.N)))
+            vec[:, self.src] = np.inf                # self-loop (Sec. II-A)
+            if not self._suspend_telemetry:
+                self._screen_rows(users, vec)
+            self._bw_vec[users] = vec
+            self.stats.ingests += 1
+            self.stats.uplink_updates += Us
+            if not requant:
+                self._stale[users] = True
+                return None
+            changed = self._requant_users(users, vec)
+            self._stale[users] = False
+            return changed
 
     def ingest_factors(self, scale: np.ndarray, factors: np.ndarray,
                        requant: bool = True) -> Optional[np.ndarray]:
@@ -643,40 +638,37 @@ class Population:
                 f"ingest_factors expects scale ({self.U},) and factors "
                 f"({self.U}, {self.N}); got {scale.shape} and "
                 f"{factors.shape}")
-        t0 = time.perf_counter() if self._timing else 0.0
-        if self._telemetry is None or self._telemetry.mode == "raise":
-            # loud default: a corrupt fading scale must not reach the store
-            # (factors are orchestrator-owned link patterns, not telemetry)
-            _validate_bps_values(scale, what="ingest_factors scale")
-            if not requant:
-                # defer the (U, N) product: the gate and resolve subset
-                # read through the lazy accessors (see ``_bw_lazy``)
-                self._bw_lazy = (scale, factors)
+        with span(self._timing, self.stats, "t_ingest_ms", "pop.ingest"):
+            if self._telemetry is None or self._telemetry.mode == "raise":
+                # loud default: a corrupt fading scale must not reach the
+                # store (factors are orchestrator-owned link patterns, not
+                # telemetry)
+                _validate_bps_values(scale, what="ingest_factors scale")
+                if not requant:
+                    # defer the (U, N) product: the gate and resolve subset
+                    # read through the lazy accessors (see ``_bw_lazy``)
+                    self._bw_lazy = (scale, factors)
+                else:
+                    np.multiply(scale[:, None], factors, out=self._bw_vec)
+                    self._bw_vec[:, self.src] = np.inf  # self-loop (II-A)
+                    self._bw_lazy = None
             else:
-                np.multiply(scale[:, None], factors, out=self._bw_vec)
-                self._bw_vec[:, self.src] = np.inf   # self-loop (Sec. II-A)
-                self._bw_lazy = None
-        else:
-            # screened path: stage the product so quarantined/clamped rows
-            # can be substituted before they land in the store — values are
-            # bit-identical to the fused multiply
-            self._bw_dense()       # substitution reads last-known-good rows
-            vec = scale[:, None] * factors
-            vec[:, self.src] = np.inf
-            self._screen_rows(np.arange(self.U), vec)
-            self._bw_vec[:] = vec
-        self.stats.ingests += 1
-        self.stats.uplink_updates += self.U
-        if not requant:
-            self._stale[:] = True
-            if self._timing:
-                self.stats.t_ingest_ms += (time.perf_counter() - t0) * 1e3
-            return None
-        changed = self._requant_users(np.arange(self.U), self._bw_vec)
-        self._stale[:] = False
-        if self._timing:
-            self.stats.t_ingest_ms += (time.perf_counter() - t0) * 1e3
-        return changed
+                # screened path: stage the product so quarantined/clamped
+                # rows can be substituted before they land in the store —
+                # values are bit-identical to the fused multiply
+                self._bw_dense()   # substitution reads last-known-good rows
+                vec = scale[:, None] * factors
+                vec[:, self.src] = np.inf
+                self._screen_rows(np.arange(self.U), vec)
+                self._bw_vec[:] = vec
+            self.stats.ingests += 1
+            self.stats.uplink_updates += self.U
+            if not requant:
+                self._stale[:] = True
+                return None
+            changed = self._requant_users(np.arange(self.U), self._bw_vec)
+            self._stale[:] = False
+            return changed
 
     def _screen_rows(self, users: np.ndarray, vec: np.ndarray) -> None:
         """Telemetry screening over a staging ingest batch (in place).
@@ -767,11 +759,9 @@ class Population:
         """Flush deferred requantizations (lazy ingest) for these users."""
         sel = users[self._stale[users]]
         if len(sel):
-            t0 = time.perf_counter() if self._timing else 0.0
-            self._requant_users(sel, self._bw_rows(sel))
-            self._stale[sel] = False
-            if self._timing:
-                self.stats.t_ingest_ms += (time.perf_counter() - t0) * 1e3
+            with span(self._timing, self.stats, "t_ingest_ms", "pop.ingest"):
+                self._requant_users(sel, self._bw_rows(sel))
+                self._stale[sel] = False
 
     def _quant(self) -> QuantConsts:
         """The fused requantizer's constants bundle — snapshots the proto
@@ -841,38 +831,36 @@ class Population:
         so model those as separate cohorts.
         """
         self._proto.update_slice(frac)
-        t0 = time.perf_counter() if self._timing else 0.0
-        # the proto rebuilt its packs and base tensors in place or replaced
-        # them; every cached cohort state quantized against the old compute
-        # terms is now stale (incl. fast tables), the memoized exact
-        # energies moved with the compute terms, and the fallback plan's
-        # compute base as well.  Capture the pre-slice signatures first —
-        # the quant_changed counter compares against them, and the table
-        # (their backing store) is about to clear.
-        old_enc = self._stq_enc[self._user_state]
-        self._states = []
-        self._state_ids = {}
-        self._pinned = set()
-        self._cfg_energy = {}
-        self._fallback_plan = None
-        self._quant_consts = None
-        self._tighten_cache = {}
-        self._tighten_base = {}
-        self._stq_enc = np.empty((0, self._enc_w), dtype=np.int16)
-        # requantize every user against the new compute terms in one fused
-        # launch and re-key everyone — the stored bandwidths were already
-        # screened, so this must not look like a telemetry tick
-        # (quarantine/stuck state and counters stay untouched)
-        enc = quant_signature(self._bw_dense(), self._quant(),
-                              backend=self._ingest_backend)
-        self.stats.ingests += 1
-        self.stats.uplink_updates += self.U
-        self.stats.quant_changed += \
-            int(np.count_nonzero((enc != old_enc).any(axis=1)))
-        self._assign_states(np.arange(self.U), enc=enc)
-        self._stale[:] = False
-        if self._timing:
-            self.stats.t_ingest_ms += (time.perf_counter() - t0) * 1e3
+        with span(self._timing, self.stats, "t_ingest_ms", "pop.ingest"):
+            # the proto rebuilt its packs and base tensors in place or
+            # replaced them; every cached cohort state quantized against the
+            # old compute terms is now stale (incl. fast tables), the
+            # memoized exact energies moved with the compute terms, and the
+            # fallback plan's compute base as well.  Capture the pre-slice
+            # signatures first — the quant_changed counter compares against
+            # them, and the table (their backing store) is about to clear.
+            old_enc = self._stq_enc[self._user_state]
+            self._states = []
+            self._state_ids = {}
+            self._pinned = set()
+            self._cfg_energy = {}
+            self._fallback_plan = None
+            self._quant_consts = None
+            self._tighten_cache = {}
+            self._tighten_base = {}
+            self._stq_enc = np.empty((0, self._enc_w), dtype=np.int16)
+            # requantize every user against the new compute terms in one
+            # fused launch and re-key everyone — the stored bandwidths were
+            # already screened, so this must not look like a telemetry tick
+            # (quarantine/stuck state and counters stay untouched)
+            enc = quant_signature(self._bw_dense(), self._quant(),
+                                  backend=self._ingest_backend)
+            self.stats.ingests += 1
+            self.stats.uplink_updates += self.U
+            self.stats.quant_changed += \
+                int(np.count_nonzero((enc != old_enc).any(axis=1)))
+            self._assign_states(np.arange(self.U), enc=enc)
+            self._stale[:] = False
         return self
 
     def update_backhaul(self, scale: Union[float, np.ndarray]
@@ -922,6 +910,14 @@ class Population:
         Us = len(users)
         if Us == 0:
             return
+        self.stats.rekeyed_users += Us
+        with span(self._timing, self.stats, "t_rekey_ms", "pop.rekey",
+                  users=Us):
+            self._rekey(users, enc)
+
+    def _rekey(self, users: np.ndarray, enc: Optional[np.ndarray]) -> None:
+        """The body of :meth:`_assign_states` (its ``pop.rekey`` span)."""
+        Us = len(users)
         old_sids = self._user_state[users]       # bounded-resume hints
         if enc is None:
             enc = self._stq_enc[old_sids]
@@ -1060,7 +1056,19 @@ class Population:
         states = [self._states[int(s)] for s in sids]
         if not states:
             return
+        # the overlap EWMA reads _last_relax_s, timing flag or not
         t0 = time.perf_counter()
+        with span(self._timing, self.stats, "t_relax_ms", "pop.relax"):
+            self._relax_split(states)
+        self._last_relax_s = time.perf_counter() - t0
+        if prebuilt:
+            self.stats.prebuilt_states += len(states)
+        else:
+            self.stats.dp_relaxes += len(states)
+
+    def _relax_split(self, states: List[_CohortState]) -> None:
+        """The body of :meth:`_relax_states`: bounded resumes, shared
+        grids and the full chain."""
         full: List[_CohortState] = []
         resume: Dict[int, List[Tuple[_CohortState, _CohortState]]] = {}
         if self._bounded:
@@ -1085,13 +1093,6 @@ class Population:
             self._relax_resume(l0, pairs)
             self.stats.bounded_relaxes += len(pairs)
             self.stats.layers_skipped += l0 * len(pairs)
-        if prebuilt:
-            self.stats.prebuilt_states += len(states)
-        else:
-            self.stats.dp_relaxes += len(states)
-        self._last_relax_s = time.perf_counter() - t0
-        if self._timing:
-            self.stats.t_relax_ms += self._last_relax_s * 1e3
 
     def _resume_hint(self, s: _CohortState
                      ) -> Optional[Tuple[str, _CohortState, int]]:
@@ -1331,13 +1332,10 @@ class Population:
         if len(fb_idx):
             # batched Plan.solve tighten loop (round 0 already failed via
             # the s0 scan above — bit-exact, same dp, same scan contract)
-            tF = time.perf_counter() if self._timing else 0.0
             self.stats.fallbacks += len(fb_idx)
             if not no_exit:
-                tb = self._tighten_batch(bwv[fb_idx], state)
-            if self._timing:
-                self.stats.t_post_fallback_ms += \
-                    (time.perf_counter() - tF) * 1e3
+                with span(self._timing, None, None, "pop.post.fallback"):
+                    tb = self._tighten_batch(bwv[fb_idx], state)
         s1 = None
         if self.quantize != "ceil" and (len(fb_idx) < Us or tb is not None):
             # one ceil rescue scan for everyone: the non-fallback users
@@ -1395,28 +1393,24 @@ class Population:
         plan cost microseconds where a fresh Plan build costs milliseconds
         — and users with no feasible placement hit this path every tick
         they stay dirty."""
-        t0 = time.perf_counter() if self._timing else 0.0
-        plan = self._fallback_plan
-        if plan is None:
-            plan = self._fallback_plan = Plan(
-                self.network0, self.profile, self.req, gamma=self.gamma,
-                lam=self.lam, quantize=self.quantize,
-                max_tighten=self.max_tighten,
-                tighten_factor=self.tighten_factor, n_best=1,
-                backend=self._plan_backend,
-                check_aggregate_load=self.check_aggregate_load)
-        plan.update_uplink(bw_row)
-        have = plan._masked.copy()
-        for n in np.nonzero(mask & ~have)[0]:
-            plan.mask_node(int(n))
-        for n in np.nonzero(have & ~mask)[0]:
-            plan.unmask_node(int(n))
-        self.stats.fallbacks += 1
-        sol = plan.solve()
-        if self._timing:
-            self.stats.t_post_fallback_ms += \
-                (time.perf_counter() - t0) * 1e3
-        return sol
+        with span(self._timing, None, None, "pop.post.fallback"):
+            plan = self._fallback_plan
+            if plan is None:
+                plan = self._fallback_plan = Plan(
+                    self.network0, self.profile, self.req, gamma=self.gamma,
+                    lam=self.lam, quantize=self.quantize,
+                    max_tighten=self.max_tighten,
+                    tighten_factor=self.tighten_factor, n_best=1,
+                    backend=self._plan_backend,
+                    check_aggregate_load=self.check_aggregate_load)
+            plan.update_uplink(bw_row)
+            have = plan._masked.copy()
+            for n in np.nonzero(mask & ~have)[0]:
+                plan.mask_node(int(n))
+            for n in np.nonzero(have & ~mask)[0]:
+                plan.unmask_node(int(n))
+            self.stats.fallbacks += 1
+            return plan.solve()
 
     def _tighten_consts(self, delta_eff: float) -> QuantConsts:
         """Single-mode constants bundle for one tighten round: the same
@@ -1680,12 +1674,13 @@ class Population:
         self.stats.solves += Us
 
         # unique (state, bandwidth) groups: identical inputs, one solve
-        rows = np.empty((Us, 1 + self.N), dtype=np.float64)
-        rows[:, 0] = sids
-        rows[:, 1:] = self._bw_rows(users)
-        v = np.ascontiguousarray(rows).view(
-            np.dtype((np.void, rows.shape[1] * 8))).ravel()
-        _, first, order, bounds = _group_runs(v)
+        with span(self._timing, self.stats, "t_group_ms", "pop.group"):
+            rows = np.empty((Us, 1 + self.N), dtype=np.float64)
+            rows[:, 0] = sids
+            rows[:, 1:] = self._bw_rows(users)
+            v = np.ascontiguousarray(rows).view(
+                np.dtype((np.void, rows.shape[1] * 8))).ravel()
+            _, first, order, bounds = _group_runs(v)
         pend.sids = sids
         pend.first, pend.order, pend.bounds = first, order, bounds
         pend.bw = rows[:, 1:]            # the tick's bandwidth snapshot
@@ -1702,23 +1697,22 @@ class Population:
         if pend.future is not None:
             pend.future.result()
             pend.future = None
-        t1 = time.perf_counter()
         first, order, bounds = pend.first, pend.order, pend.bounds
-        dt_share = (t1 - pend.t0) / Us
+        dt_share = (time.perf_counter() - pend.t0) / Us
 
-        if self._vector_postpass and self._proto._admissible:
-            self._solve_vectorized(users, pend.sids, first, order, bounds,
-                                   dt_share, pend.build_solutions, pend.bw)
-        else:
-            for g, j in enumerate(first):
-                state = self._states[int(pend.sids[j])]
-                cfg, ev, meta = self._solve_one(state, pend.bw[j])
-                members = users[order[bounds[g]:bounds[g + 1]]]
-                self._record_group(members, cfg, ev, meta, dt_share,
-                                   pend.build_solutions)
-        self.stats.unique_solves += len(first)
-        if self._timing:
-            self.stats.t_post_ms += (time.perf_counter() - t1) * 1e3
+        with span(self._timing, self.stats, "t_post_ms", "pop.post"):
+            if self._vector_postpass and self._proto._admissible:
+                self._solve_vectorized(users, pend.sids, first, order,
+                                       bounds, dt_share,
+                                       pend.build_solutions, pend.bw)
+            else:
+                for g, j in enumerate(first):
+                    state = self._states[int(pend.sids[j])]
+                    cfg, ev, meta = self._solve_one(state, pend.bw[j])
+                    members = users[order[bounds[g]:bounds[g + 1]]]
+                    self._record_group(members, cfg, ev, meta, dt_share,
+                                       pend.build_solutions)
+            self.stats.unique_solves += len(first)
         return self.solutions(users) if pend.build_solutions else None
 
     def _executor(self):
@@ -1822,48 +1816,48 @@ class Population:
         (``_scan_state_group``); both are bit-identical to the scalar
         per-group post-pass.
         """
-        tA = time.perf_counter() if self._timing else 0.0
-        reps = users[first]
-        rep_sids = sids[first]
-        uniq_s, _f, s_order, s_bounds = _group_runs(rep_sids)
-        states = [self._states[int(s)] for s in uniq_s]
-        tables = [st.fast if st.fast is not None else self._build_fast(st)
-                  for st in states]
-
-        # distinct scanned configs across states -> one stacked-feasibility
-        # evaluation each, over exactly the representatives of the states
-        # that reference the config (cohort states sharing a first
-        # candidate share the evaluation; disjoint states do not pay for
-        # each other's rows — unevaluated (row, rep) cells are never read)
-        key2row: Dict[Tuple, int] = {}
-        tasks: List[Config] = []
-        task_rpos: List[List[np.ndarray]] = []
-        for gi, ft in enumerate(tables):
-            rpos = s_order[s_bounds[gi]:s_bounds[gi + 1]]
-            for key, cfg in zip(ft.keys, ft.cfgs):
-                r = key2row.get(key)
-                if r is None:
-                    r = key2row[key] = len(tasks)
-                    tasks.append(cfg)
-                    task_rpos.append([])
-                task_rpos[r].append(rpos)
-        bw_reps = self._bw_rows(reps) if bw is None else bw[first]
-        nR = len(reps)
-        violM = np.ones((len(tasks), nR), dtype=bool)
-        latM = np.empty((len(tasks), nR))
-        for r, cfg in enumerate(tasks):
-            cols = (task_rpos[r][0] if len(task_rpos[r]) == 1
-                    else np.unique(np.concatenate(task_rpos[r])))
-            _e, _ec, _em, lat, viol = eval_config_users(
-                self.profile, self.req, self.network0.nodes,
-                self._proto._bw, self._proto._compute, self.src, cfg,
-                bw_reps[cols], check_aggregate_load=self.check_aggregate_load)
-            violM[r, cols] = viol
-            latM[r, cols] = lat
-        if self._timing:
+        with span(self._timing, None, None, "pop.post.fast"):
             # shared-table machinery: fast-table builds + the stacked
             # first-candidate feasibility evaluations
-            self.stats.t_post_fast_ms += (time.perf_counter() - tA) * 1e3
+            reps = users[first]
+            rep_sids = sids[first]
+            uniq_s, _f, s_order, s_bounds = _group_runs(rep_sids)
+            states = [self._states[int(s)] for s in uniq_s]
+            tables = [st.fast if st.fast is not None
+                      else self._build_fast(st) for st in states]
+
+            # distinct scanned configs across states -> one stacked-
+            # feasibility evaluation each, over exactly the representatives
+            # of the states that reference the config (cohort states sharing
+            # a first candidate share the evaluation; disjoint states do not
+            # pay for each other's rows — unevaluated (row, rep) cells are
+            # never read)
+            key2row: Dict[Tuple, int] = {}
+            tasks: List[Config] = []
+            task_rpos: List[List[np.ndarray]] = []
+            for gi, ft in enumerate(tables):
+                rpos = s_order[s_bounds[gi]:s_bounds[gi + 1]]
+                for key, cfg in zip(ft.keys, ft.cfgs):
+                    r = key2row.get(key)
+                    if r is None:
+                        r = key2row[key] = len(tasks)
+                        tasks.append(cfg)
+                        task_rpos.append([])
+                    task_rpos[r].append(rpos)
+            bw_reps = self._bw_rows(reps) if bw is None else bw[first]
+            nR = len(reps)
+            violM = np.ones((len(tasks), nR), dtype=bool)
+            latM = np.empty((len(tasks), nR))
+            for r, cfg in enumerate(tasks):
+                cols = (task_rpos[r][0] if len(task_rpos[r]) == 1
+                        else np.unique(np.concatenate(task_rpos[r])))
+                _e, _ec, _em, lat, viol = eval_config_users(
+                    self.profile, self.req, self.network0.nodes,
+                    self._proto._bw, self._proto._compute, self.src, cfg,
+                    bw_reps[cols],
+                    check_aggregate_load=self.check_aggregate_load)
+                violM[r, cols] = viol
+                latM[r, cols] = lat
 
         base_meta = {"gamma": self.gamma, "quantize": self.quantize,
                      "tighten_rounds": 0, "backend": self.backend,
@@ -1921,12 +1915,9 @@ class Population:
                                            dt_share, build_solutions)
                 continue
             # general path: full vectorized scan for this state's reps
-            tS = time.perf_counter() if self._timing else 0.0
-            cfgs, energy, lat, e_comp, e_comm, used_ceil_a, exit_, fb = \
-                self._scan_state_group(state, bw_reps[rpos])
-            if self._timing:
-                self.stats.t_post_scan_ms += \
-                    (time.perf_counter() - tS) * 1e3
+            with span(self._timing, None, None, "pop.post.scan"):
+                cfgs, energy, lat, e_comp, e_comm, used_ceil_a, exit_, fb = \
+                    self._scan_state_group(state, bw_reps[rpos])
             for pi, rp in enumerate(rpos):
                 members = users[order[bounds[rp]:bounds[rp + 1]]]
                 if fb[pi] is not None:

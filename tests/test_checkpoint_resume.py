@@ -18,13 +18,11 @@ import pytest
 
 from repro.core.capacity import SharedCapacity
 from repro.core.faults import FaultPlan, FaultSpec, InjectedCrash
-from repro.core.online import ChurnOrchestrator, population_cohorts
+from repro.core.online import (TIMING_FIELDS, ChurnOrchestrator,
+                               population_cohorts)
 from repro.runtime import checkpoint as ckpt
 
 T, U, SEED = 12, 24, 7
-
-#: wall-clock fields excluded from report comparison
-TIMING = ("t_ingest_ms", "t_relax_ms", "t_post_ms", "t_reprice_ms")
 
 
 def _trace():
@@ -52,7 +50,7 @@ def assert_reports_equal(a, b):
     assert len(a) == len(b), (len(a), len(b))
     for ra, rb in zip(a, b):
         da, db = dataclasses.asdict(ra), dataclasses.asdict(rb)
-        for k in TIMING:
+        for k in TIMING_FIELDS:
             da.pop(k), db.pop(k)
         assert da == db, (ra.tick,
                           {k: (da[k], db[k]) for k in da if da[k] != db[k]})

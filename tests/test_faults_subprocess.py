@@ -21,8 +21,9 @@ import sys
 import numpy as np
 import pytest
 
+from repro.core.online import TIMING_FIELDS
+
 REPO = pathlib.Path(__file__).resolve().parents[1]
-TIMING = ("t_ingest_ms", "t_relax_ms", "t_post_ms", "t_reprice_ms")
 
 
 def _env():
@@ -56,7 +57,7 @@ def test_sigkill_then_resume_bit_identical(tmp_path):
     assert 0 < pos <= KILL_TICK          # a pre-kill boundary checkpoint
     for ra, rb in zip(r_clean[pos:], tail):
         da, db = dataclasses.asdict(ra), dataclasses.asdict(rb)
-        for k in TIMING:
+        for k in TIMING_FIELDS:
             da.pop(k), db.pop(k)
         assert da == db, (ra.tick,
                           {k: (da[k], db[k]) for k in da if da[k] != db[k]})
