@@ -1111,11 +1111,11 @@ class ChurnOrchestrator:
                 cur[:] = energy
             return None, None
         held = ~res
-        if held.any():
-            if sl is None:
+        if sl is None:
+            if held.any():
                 self._cur_energy[gl[held]] = energy[held]
-            else:
-                cur[held] = energy[held]
+        else:
+            np.copyto(cur, energy, where=held)
         if n_res == p.U:
             return gl, loc
         return gl[res], loc[res]

@@ -176,6 +176,30 @@ def _group_runs(keys: np.ndarray
     return uniq, first, order, bounds
 
 
+#: the dense threshold gate peels at most this many incumbent
+#: configurations, and this many factor rows of one configuration, before
+#: it leaves the rest of a cohort to the exact evaluator
+_GATE_CONFIGS = 8
+_GATE_FACTOR_ROWS = 4
+#: bit pattern of the largest finite float64
+_F64_MAX_BITS = 0x7FEFFFFFFFFFFFFF
+
+
+def _modal_row(exit_all: np.ndarray, place_all: np.ndarray,
+               no_inc: np.ndarray) -> int:
+    """A row holding the modal incumbent (exit, placement; no incumbent
+    counts as one value) of a 32-row stride sample of the cohort."""
+    Us = len(exit_all)
+    samp = np.arange(0, Us, max(1, Us // 31))
+    srows = np.empty((len(samp), 1 + place_all.shape[1]), dtype=np.int32)
+    srows[:, 0] = np.where(no_inc[samp], -2, exit_all[samp])
+    srows[:, 1:] = place_all[samp]
+    sv = np.ascontiguousarray(srows).view(
+        np.dtype((np.void, srows.shape[1] * 4))).ravel()
+    uniq, counts = np.unique(sv, return_counts=True)
+    return int(samp[np.nonzero(sv == uniq[np.argmax(counts)])[0][0]])
+
+
 def _enc_int16(q: np.ndarray) -> np.ndarray:
     """Checkpoint encoding of the inf-capable integral quantization arrays
     (qpack / state stq): values are either integers in [0, gamma] or +inf
@@ -494,6 +518,10 @@ class Population:
         #: (exit, placement) -> (energy, e_comp, e_comm); cleared with the
         #: state table on compute-slice churn
         self._cfg_energy: Dict[Tuple, Tuple[float, float, float]] = {}
+        #: threshold-gate memo (``_gate_entry``): (exit, placement, factor
+        #: values it reads) -> (energy, channel-scale threshold); cleared
+        #: wherever the compute or backhaul terms move
+        self._gate_cache: Dict[Tuple, Tuple[float, float]] = {}
         self._mesh_arg = mesh
         self._mesh_relaxer = None
         self._fallback_plan: Optional[Plan] = None
@@ -846,6 +874,7 @@ class Population:
             self._cfg_energy = {}
             self._fallback_plan = None
             self._quant_consts = None
+            self._gate_cache = {}
             self._tighten_cache = {}
             self._tighten_base = {}
             self._stq_enc = np.empty((0, self._enc_w), dtype=np.int16)
@@ -888,6 +917,7 @@ class Population:
             s.cand = {}
             s.fast = None
         self._fallback_plan = None
+        self._gate_cache = {}       # repriced links move the thresholds
         # tighten states quantize the repriced non-source links too
         self._tighten_cache = {}
         self._tighten_base = {}
@@ -2125,15 +2155,231 @@ class Population:
         term by term (bit-identical doubles), with the failure-bitmap
         dead-node check of ``Plan.evaluate`` applied first.
 
-        ``users=None`` evaluates the whole cohort positionally — the dense
-        hysteresis gate's hot path: the incumbent columns are read as
-        views, the grouping key is radix-sorted int64 (one all-equal
-        compare in the steady single-config state) and a single-group
-        cohort reads the bandwidth store with zero per-user gathers.
-        When the uniform-incumbent flag is set (every user solved with one
-        configuration — the steady state at scale) even the grouping-key
-        build is skipped: one stacked evaluation against the bandwidth
-        store, results bit-identical to the single-group general path.
+        ``users=None`` evaluates the whole cohort — the dense hysteresis
+        gate's hot path.  Under the lazy bandwidth store (an array tick,
+        whose ingest defers the (U, N) product) it judges each incumbent
+        against one exact channel threshold per (configuration, factor
+        values) instead of re-evaluating it (see :meth:`_gate_plan`);
+        rows that path does not cover, and every other call, go through
+        the exact evaluator (:meth:`_incumbents_exact`).  The span ``pop.gate.threshold``
+        carries the rows judged by threshold or constant as its ``users``
+        argument, ``pop.gate.exact`` the rows re-evaluated.  Results are
+        bit-identical either way.
+        """
+        if users is None and self._bw_lazy is not None:
+            plan = self._gate_plan()
+            if plan is not None:
+                return self._gate_judge(*plan)
+        n = self.U if users is None else len(users)
+        with span(self._timing, None, None, "pop.gate.exact", users=n):
+            return self._incumbents_exact(users)
+
+    def _gate_cols(self, place: Sequence[int]) -> Tuple[int, ...]:
+        """The source-link columns ``eval_config_users`` reads for this
+        placement: the input hop off the source and every cut that enters
+        or leaves it."""
+        src = self.src
+        cols = set()
+        if place[0] != src:
+            cols.add(place[0])
+        for a, b in zip(place, place[1:]):
+            if a != b and src in (a, b):
+                cols.add(b if a == src else a)
+        return tuple(sorted(cols))
+
+    def _gate_entry(self, k: int, place: List[int], cols: Tuple[int, ...],
+                    row: np.ndarray) -> Optional[Tuple[float, float]]:
+        """(energy, threshold) of incumbent (k, place) for users whose
+        factor row agrees with ``row`` on ``cols``, memoized; None when
+        those factors are not finite and non-negative.
+
+        Energy has no bandwidth term.  Feasibility reads the bandwidth
+        ``scale * factor`` only through correctly rounded multiplies,
+        divides, adds and compares, each monotone, so with finite
+        non-negative factors it is monotone in the (finite, non-negative,
+        validated) scale: feasible exactly when ``scale >= t``.  ``t`` is
+        the least float64 at which ``eval_config_users`` itself, on a
+        one-row lazy store, finds the incumbent feasible — bisected over
+        the bit patterns, which order non-negative doubles — or inf when
+        none is.  A placement that reads no source link is feasible for
+        every scale or none: ``t`` is 0 or inf.
+        """
+        vals = row[list(cols)]
+        if not (np.isfinite(vals).all() and (vals >= 0).all()):
+            return None
+        key = (k, tuple(place), vals.tobytes())
+        ent = self._gate_cache.get(key)
+        if ent is not None:
+            return ent
+        cfg = Config(placement=list(place), final_exit=k)
+        one = row[None].copy()
+
+        def feasible(bits: int) -> Tuple[float, bool]:
+            s = np.array([bits], dtype=np.int64).view(np.float64)
+            e, _lat, viol = self._eval_config_users(
+                cfg, _LazyBwCols(s, one, self.src))
+            return e, not bool(viol[0])
+
+        e, ok = feasible(0)
+        if ok:
+            t = 0.0
+        elif not cols or not feasible(_F64_MAX_BITS)[1]:
+            t = np.inf
+        else:
+            lo, hi = 0, _F64_MAX_BITS       # infeasible, feasible
+            while hi - lo > 1:
+                mid = (lo + hi) // 2
+                if feasible(mid)[1]:
+                    hi = mid
+                else:
+                    lo = mid
+            t = float(np.array([hi], dtype=np.int64).view(np.float64)[0])
+        ent = self._gate_cache[key] = (e, t)
+        return ent
+
+    def _gate_plan(self):
+        """Group the whole cohort's incumbents for the threshold gate,
+        without sorting, or None to leave the cohort to the exact path.
+
+        The head group is the uniform incumbent, or else the modal one of
+        a 32-row sample, with the factor values its placement reads: rows
+        that differ from it in a positional compare (exit, placement
+        columns, no incumbent, those factor columns) are peeled again on
+        their gather, one (configuration, factor values) group at a time.
+        Past ``_GATE_CONFIGS`` configurations, or ``_GATE_FACTOR_ROWS``
+        factor rows of one configuration, or factor values that are not
+        finite and non-negative, the remaining rows of the peel go to the
+        exact evaluator; a cohort with no incumbent at all, or whose head
+        factors are not finite and non-negative, goes there whole.
+        Returns (no_inc, head, groups, no_inc_rows, exact) for
+        :meth:`_gate_judge`."""
+        fac = self._bw_lazy[1]
+        U, L = self.U, self.L
+        exit_all, place_all = self._inc_exit, self._inc_place
+        single = self._inc_single
+        neq = None
+        if single is not None:
+            no_inc = np.zeros(U, dtype=bool)
+            k, place, j = single[0], list(single[1]), 0
+        else:
+            no_inc = ~self._solved | (exit_all < 0)
+            j = _modal_row(exit_all, place_all, no_inc)
+            if no_inc[j]:
+                if no_inc.all():
+                    return None
+                j = int(np.argmin(no_inc))
+            k = int(exit_all[j])
+            pp = place_all[j]
+            neq = exit_all != k
+            for i in range(L):
+                neq |= place_all[:, i] != pp[i]
+            neq |= no_inc
+            place = [int(n) for n in pp[:self.profile.exits[k].block + 1]]
+        cols = self._gate_cols(place)
+        ent = self._gate_entry(k, place, cols, fac[j])
+        if ent is None:
+            return None
+        for n in cols:
+            d = fac[:, n] != fac[j, n]
+            if neq is None:
+                neq = d
+            else:
+                neq |= d
+        head = (place, cols) + ent
+        if neq is None:
+            return no_inc, head, [], np.empty(0, dtype=np.int64), []
+        rest = np.flatnonzero(neq)
+        ni = no_inc[rest]
+        left = rest[~ni]
+        # the peel runs on one gather of the remainder's incumbents
+        ex = np.take(exit_all, left)
+        pl = np.take(place_all, left, axis=0)
+        groups, exact = [], []
+        n_cfg = 1
+        while len(left):
+            if n_cfg >= _GATE_CONFIGS:
+                exact.append(left)
+                break
+            n_cfg += 1
+            k = int(ex[0])
+            pp = pl[0].copy()
+            eq = ex == k
+            for i in range(L):
+                eq &= pl[:, i] == pp[i]
+            sub = left[eq]
+            keep = ~eq
+            left = left[keep]
+            ex = ex[keep]
+            pl = np.compress(keep, pl, axis=0)
+            place = [int(n) for n in pp[:self.profile.exits[k].block + 1]]
+            cols = self._gate_cols(place)
+            for _ in range(_GATE_FACTOR_ROWS):
+                ent = self._gate_entry(k, place, cols, fac[sub[0]])
+                if ent is None:
+                    break
+                same = np.ones(len(sub), dtype=bool)
+                for n in cols:
+                    same &= fac[sub, n] == fac[sub[0], n]
+                groups.append((sub[same], place, cols) + ent)
+                sub = sub[~same]
+                if not len(sub):
+                    break
+            if len(sub):
+                exact.append(sub)
+        return no_inc, head, groups, rest[ni], exact
+
+    def _gate_judge(self, no_inc: np.ndarray, head, groups, no_inc_rows,
+                    exact: List[np.ndarray]
+                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Feasibility and energy of a :meth:`_gate_plan`: the head group
+        over the whole store (one compare against its threshold, none for
+        a placement that reads no source link), each peeled group on its
+        rows, then the dead-node check exactly as the exact path applies
+        it and the exact evaluator for the rows the plan left to it."""
+        U = self.U
+        sc = self._bw_lazy[0]
+        any_mask = self._mask_count > 0
+        n_exact = sum(len(x) for x in exact)
+        with span(self._timing, None, None, "pop.gate.threshold",
+                  users=U - n_exact):
+            place, cols, e, t = head
+            feas = sc >= t if cols else np.full(U, t == 0.0)
+            energy = np.full(U, e)
+            if any_mask:
+                dead = self._masked[:, place].any(axis=1)
+                feas[dead] = False
+                energy[dead] = np.inf
+            for rows, place, cols, e, t in groups:
+                f = sc[rows] >= t if cols else np.full(len(rows), t == 0.0)
+                en = np.full(len(rows), e)
+                if any_mask:
+                    dead = self._masked[rows][:, place].any(axis=1)
+                    f[dead] = False
+                    en[dead] = np.inf
+                feas[rows] = f
+                energy[rows] = en
+            feas[no_inc_rows] = False
+            energy[no_inc_rows] = np.inf
+        for rows in exact:
+            with span(self._timing, None, None, "pop.gate.exact",
+                      users=len(rows)):
+                _, f, en = self._incumbents_exact(rows)
+            feas[rows] = f
+            energy[rows] = en
+        return no_inc, feas, energy
+
+    def _incumbents_exact(self, users: Optional[np.ndarray]
+                          ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """:meth:`evaluate_incumbents` by re-evaluating every incumbent.
+
+        ``users=None`` reads the incumbent columns as views, the grouping
+        key is radix-sorted int64 (one all-equal compare in the steady
+        single-config state) and a single-group cohort reads the bandwidth
+        store with zero per-user gathers.  When the uniform-incumbent flag
+        is set (every user solved with one configuration) even the
+        grouping-key build is skipped: one stacked evaluation against the
+        bandwidth store, results bit-identical to the single-group general
+        path.
         """
         if users is None and self._inc_single is not None:
             k, place_t = self._inc_single
@@ -2173,14 +2419,7 @@ class Population:
         # are elementwise identical to the grouped evaluation: per-user
         # terms never depend on the grouping, only on the (config, row).
         if whole and Us >= 4096:
-            samp = np.arange(0, Us, max(1, Us // 31))
-            srows = np.empty((len(samp), 1 + self.L), dtype=np.int32)
-            srows[:, 0] = np.where(no_inc[samp], -2, exit_all[samp])
-            srows[:, 1:] = place_all[samp]
-            sv = np.ascontiguousarray(srows).view(
-                np.dtype((np.void, srows.shape[1] * 4))).ravel()
-            uniq, counts = np.unique(sv, return_counts=True)
-            pj = int(samp[np.nonzero(sv == uniq[np.argmax(counts)])[0][0]])
+            pj = _modal_row(exit_all, place_all, no_inc)
             pk = int(exit_all[pj])
             if pk >= 0 and solved[pj]:
                 pp = place_all[pj]
@@ -2202,7 +2441,7 @@ class Population:
                         feas[dead] = False
                         energy[dead] = np.inf
                     if len(idx):
-                        _, sub_f, sub_e = self.evaluate_incumbents(idx)
+                        _, sub_f, sub_e = self._incumbents_exact(idx)
                         feas[idx] = sub_f
                         energy[idx] = sub_e
                     return no_inc, feas, energy

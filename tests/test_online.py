@@ -2,8 +2,9 @@
 import numpy as np
 import pytest
 
-from repro.core import (ChurnEvent, ChurnOrchestrator, churn_trace,
-                        population_plans, solve_fin)
+from repro.core import (ChurnEvent, ChurnOrchestrator, Population,
+                        churn_trace, population_cohorts, population_plans,
+                        solve_fin)
 
 
 def _same(a, b):
@@ -147,3 +148,65 @@ def test_uplink_event_requires_user():
         with pytest.raises(ValueError, match="per-user"):
             orch.step([ChurnEvent(kind, None, 0.5)])
     np.testing.assert_array_equal(orch.quality, before)
+
+
+def test_streamed_threshold_gate_matches_exact_ticks(monkeypatch):
+    """A streamed ``run_arrays`` trace with outages, whose channel ticks
+    gate every incumbent by its channel threshold, makes the decisions,
+    energies and migrations of the same trace as event ticks, whose dense
+    store sends every incumbent through the exact evaluator."""
+    U, T = 240, 12
+    rng = np.random.default_rng(21)
+    qual = np.empty((T, U))
+    q = rng.uniform(0.3, 1.0, U)
+    for t in range(T):
+        q = np.clip(0.55 + 0.9 * (q - 0.55) + rng.normal(0, 0.15, U),
+                    0.05, 1.0)
+        qual[t] = q
+    att = np.zeros((T, U), dtype=np.int64)
+    for t in range(1, T):
+        move = rng.random(U) < 0.2
+        att[t] = np.where(move, rng.integers(0, 3, U), att[t - 1])
+    outages = {4: ("fail", 1), 8: ("recover", 1), 10: ("fail", 4)}
+    judged = []
+    judge = Population._gate_judge
+    monkeypatch.setattr(Population, "_gate_judge", lambda self, *a: (
+        judged.append(self.U), judge(self, *a))[1])
+
+    def orch():
+        return ChurnOrchestrator(
+            population=population_cohorts(U, n_extra_edge=2),
+            hysteresis=0.05, detach_frac=0.3)
+
+    streamed, exact = orch(), orch()
+    reps_s, reps_e = [], []
+    bounds = [0, 4, 8, 10, T]
+    for a, b in zip(bounds, bounds[1:]):
+        if a in outages:
+            kind, node = outages[a]
+            for o, reps in ((streamed, reps_s), (exact, reps_e)):
+                reps.append(o.step([ChurnEvent(kind, None, node)]))
+        reps_s += streamed.run_arrays(qual[a:b], att[a:b])
+        for t in range(a, b):
+            n_judged = len(judged)
+            reps_e.append(exact.step(
+                [ChurnEvent("uplink", u, float(qual[t, u]))
+                 for u in range(U)]
+                + [ChurnEvent("attach", u, int(att[t, u]))
+                   for u in range(U)]))
+            assert len(judged) == n_judged      # the exact path judged
+    # every channel tick of the stream went through the threshold gate
+    assert len(judged) == T * len(streamed.pops)
+    assert len(reps_s) == len(reps_e) == T + len(outages)
+    assert sum(r.n_resolved for r in reps_s) > 0
+    for ra, rb in zip(reps_s, reps_e):
+        for f in ("n_dirty", "n_resolved", "n_held", "n_failed",
+                  "n_migrations", "blocks_moved"):
+            assert getattr(ra, f) == getattr(rb, f), (ra.tick, f)
+        assert ra.energy == rb.energy, ra.tick
+        assert ra.migration_bits == rb.migration_bits, ra.tick
+    np.testing.assert_array_equal(streamed._cur_energy, exact._cur_energy)
+    np.testing.assert_array_equal(streamed._ref_energy, exact._ref_energy)
+    for p1, p2 in zip(streamed.pops, exact.pops):
+        np.testing.assert_array_equal(p1._inc_exit, p2._inc_exit)
+        np.testing.assert_array_equal(p1._inc_place, p2._inc_place)

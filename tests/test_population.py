@@ -426,3 +426,173 @@ def test_population_mesh_arg_requires_mesh_backend(network):
                      mesh=population_mesh(1))
     pop.solve()
     assert pop._mesh_relaxer.n_devices == 1
+
+
+# ---------------------------------------------------------------------------
+# threshold gate: evaluate_incumbents(None) over the lazy store is
+# bit-identical to re-evaluating every incumbent
+# ---------------------------------------------------------------------------
+
+def _cfg(k, place):
+    from repro.core.problem import Config
+    return Config(placement=list(place), final_exit=k)
+
+
+H1_LOCAL_CLOUD = _cfg(2, [0, 0, 0, 4, 4])   # one source link (to node 4)
+H1_CLOUD = _cfg(2, [4, 4, 4, 4, 4])
+H1_TWO_LINKS = _cfg(2, [1, 0, 2, 2, 2])     # source links to nodes 1 and 2
+H1_EDGE1 = _cfg(2, [0, 0, 1, 1, 1])
+H1_EDGE1_CLOUD = _cfg(2, [0, 0, 1, 4, 4])   # and the backhaul 1 -> 4
+H2_LOCAL = _cfg(1, [0, 0, 0])               # feasible, no source link
+H2_LOCAL_DEEP = _cfg(2, [0, 0, 0, 0, 0])    # infeasible, no source link
+H2_CLOUD = _cfg(1, [4, 4, 4])
+H2_SPLIT = _cfg(1, [0, 0, 4])
+
+
+def _attach_factors(U, N, rng, detach_frac=0.3, edge=(1, 2, 3)):
+    """``ChurnOrchestrator._fac_rows``' pattern: 1.0 on the attached edge
+    node and the non-edge targets, ``detach_frac`` on the other edges."""
+    fac = np.ones((U, N))
+    att = np.asarray(edge)[rng.integers(0, len(edge), U)]
+    for n in edge:
+        fac[att != n, n] = detach_frac
+    return fac
+
+
+def _gate_population(network, app, U, cfgs, shares, rng):
+    pop = Population(network, paper_profile(app), PAPER_MULTIAPP_REQS[app],
+                     U)
+    which = rng.choice(len(cfgs), size=U, p=shares)
+    pop.set_incumbents(np.arange(U), [cfgs[w] for w in which],
+                       [0.0] * U)
+    return pop, which
+
+
+def _threshold_scales(pop, which, cfgs, fac, rng):
+    """Log-uniform scales around each row's threshold, and in every
+    (configuration, factor row) group one row at the threshold and one at
+    each neighbouring float64."""
+    U = pop.U
+    t = np.empty(U)
+    for u in range(U):
+        c = cfgs[which[u]]
+        cols = pop._gate_cols(c.placement)
+        t[u] = pop._gate_entry(c.final_exit, c.placement, cols, fac[u])[1]
+    base = np.where(np.isfinite(t) & (t > 0), t, 1e9)
+    sc = base * 2.0 ** rng.uniform(-2, 2, U)
+    seen = {}
+    for u in range(U):
+        seen.setdefault((which[u], fac[u].tobytes()), []).append(u)
+    edges = []
+    for rows in seen.values():
+        tu = t[rows[0]]
+        if len(rows) >= 3 and np.isfinite(tu) and tu > 0:
+            sc[rows[:3]] = (tu, np.nextafter(tu, -np.inf),
+                            np.nextafter(tu, np.inf))
+            edges.append(rows[:3])
+    return sc, edges
+
+
+def _assert_gate_bitexact(pop, sc, fac):
+    pop.ingest_factors(sc, fac, requant=False)
+    assert pop._bw_lazy is not None
+    plan = pop._gate_plan()
+    assert plan is not None and not plan[4], "rows left to the exact path"
+    got = pop.evaluate_incumbents(None)
+    want = pop._incumbents_exact(None)
+    rows = pop.evaluate_incumbents(np.arange(pop.U))
+    for name, a, b, c in zip(("no_inc", "feas", "energy"), got, want, rows):
+        assert a.dtype == b.dtype and np.array_equal(a, b), name
+        assert np.array_equal(a, c), name
+    return got
+
+
+def _gate_case(case, network, rng):
+    N = network.n_nodes
+    if case == "scales":
+        cfgs = [H1_LOCAL_CLOUD, H1_CLOUD]
+        pop, which = _gate_population(network, "h1", 2000, cfgs,
+                                      [0.7, 0.3], rng)
+        return pop, which, cfgs, np.ones((2000, N))
+    if case == "all_local":
+        cfgs = [H2_LOCAL, H2_LOCAL_DEEP]
+        pop, which = _gate_population(network, "h2", 2000, cfgs,
+                                      [0.9, 0.1], rng)
+        return pop, which, cfgs, np.ones((2000, N))
+    if case == "uniform_all_local":
+        cfgs = [H2_LOCAL]
+        pop, which = _gate_population(network, "h2", 2000, cfgs, [1.0], rng)
+        pop._inc_single = pop._recompute_inc_single()
+        assert pop._inc_single is not None
+        return pop, which, cfgs, np.ones((2000, N))
+    if case == "two_source_links":
+        cfgs = [H1_TWO_LINKS, H1_LOCAL_CLOUD]
+        pop, which = _gate_population(network, "h1", 2000, cfgs,
+                                      [0.5, 0.5], rng)
+        assert len(pop._gate_cols(H1_TWO_LINKS.placement)) == 2
+        return pop, which, cfgs, np.ones((2000, N))
+    if case == "attachment_rows":
+        # the modal incumbent reads the edge link whose factor differs
+        # between attached and detached users
+        cfgs = [H1_EDGE1, H1_LOCAL_CLOUD, H1_TWO_LINKS]
+        pop, which = _gate_population(network, "h1", 2000, cfgs,
+                                      [0.8, 0.1, 0.1], rng)
+        return pop, which, cfgs, _attach_factors(2000, N, rng)
+    if case == "masked_node":
+        cfgs = [H1_LOCAL_CLOUD, H1_CLOUD, H1_EDGE1]
+        pop, which = _gate_population(network, "h1", 2000, cfgs,
+                                      [0.6, 0.2, 0.2], rng)
+        pop.mask_node(4, users=np.nonzero(rng.random(2000) < 0.3)[0])
+        pop.mask_node(1, users=np.nonzero(rng.random(2000) < 0.1)[0])
+        return pop, which, cfgs, np.ones((2000, N))
+    if case == "three_configs":
+        cfgs = [H2_LOCAL, H2_CLOUD, H2_SPLIT]
+        pop, which = _gate_population(network, "h2", 5000, cfgs,
+                                      [0.6, 0.25, 0.15], rng)
+        assert np.bincount(which).max() * 8 < 7 * 5000
+        return pop, which, cfgs, np.ones((5000, N))
+    raise AssertionError(case)
+
+
+@pytest.mark.parametrize("case", ["scales", "all_local", "uniform_all_local",
+                                  "two_source_links", "attachment_rows",
+                                  "masked_node", "three_configs"])
+def test_threshold_gate_bitexact(network, case):
+    rng = np.random.default_rng(sum(map(ord, case)))
+    pop, which, cfgs, fac = _gate_case(case, network, rng)
+    sc, edges = _threshold_scales(pop, which, cfgs, fac, rng)
+    no_inc, feas, _e = _assert_gate_bitexact(pop, sc, fac)
+    assert not no_inc.any()
+    # each threshold is tight: feasible at it and above, not one below
+    dead = np.array([pop._masked[u, cfgs[which[u]].placement].any()
+                     for u in range(pop.U)])
+    for at, below, above in edges:
+        if dead[[at, below, above]].any():
+            continue
+        assert (feas[at], feas[below], feas[above]) == (True, False, True)
+    if case not in ("all_local", "uniform_all_local"):
+        assert edges and feas.any() and not feas.all()
+    if case in ("all_local", "uniform_all_local"):
+        # no source link: one constant per configuration
+        assert np.array_equal(feas, np.asarray(cfgs)[which] == H2_LOCAL)
+
+
+@pytest.mark.parametrize("delta", ["update_slice", "update_backhaul"])
+def test_threshold_gate_cache_follows_the_proto(network, delta):
+    """A compute-slice or backhaul repricing moves the thresholds: the
+    memo is dropped, and rows at the old thresholds are judged afresh."""
+    rng = np.random.default_rng(11)
+    cfgs = [H1_LOCAL_CLOUD, H1_EDGE1_CLOUD]
+    pop, which = _gate_population(network, "h1", 2000, cfgs, [0.5, 0.5],
+                                  rng)
+    fac = np.ones((2000, network.n_nodes))
+    sc, _ = _threshold_scales(pop, which, cfgs, fac, rng)
+    _n, before, _e = _assert_gate_bitexact(pop, sc, fac)
+    assert pop._gate_cache
+    if delta == "update_slice":
+        pop.update_slice(0.5)
+    else:
+        pop.update_backhaul(0.02)
+    assert not pop._gate_cache
+    _n, after, _e = _assert_gate_bitexact(pop, sc, fac)
+    assert not np.array_equal(before, after)
