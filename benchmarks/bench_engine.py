@@ -13,7 +13,7 @@ import jax
 import numpy as np
 
 from repro.configs import get
-from repro.core import AppRequirements, paper_profile
+from repro.core import AppRequirements, profile_from_arch
 from repro.core.scenarios import paper_scenario
 from repro.kernels.ee_gate.ops import ee_gate
 from repro.models import transformer as T
@@ -23,10 +23,11 @@ from .common import Row, kv, timed
 
 
 def _engine(cfg, params, thresholds):
+    # the model's own profile; alpha 1.0 keeps its final head deployed
     return SplitServeEngine(
         cfg, params, batch_size=4, cache_len=128, thresholds=thresholds,
-        network=paper_scenario(), profile=paper_profile("h6"),
-        req=AppRequirements(alpha=0.93, delta=8e-3))
+        network=paper_scenario(), profile=profile_from_arch(cfg),
+        req=AppRequirements(alpha=1.0, delta=8e-3))
 
 
 def run() -> List[Row]:
@@ -38,13 +39,14 @@ def run() -> List[Row]:
     import jax.numpy as jnp
     caches = T.init_caches(cfg, 4, 128)
     _, _, exits = T.decode_step(params, cfg, jnp.ones((4, 1), jnp.int32),
-                                caches, jnp.int32(0))
+                                caches, jnp.zeros(4, jnp.int32))
     conf0, _ = ee_gate(exits[f"exit_{cfg.exit_layer_list[0]}"])
     thr = float(np.median(np.asarray(conf0)))
 
     stats = {}
-    for name, thresholds in (("exits_off", [1.1]), ("exits_on", [thr])):
-        eng = _engine(cfg, params, thresholds)
+    n_early = len(cfg.exit_layer_list)
+    for name, th in (("exits_off", 1.1), ("exits_on", thr)):
+        eng = _engine(cfg, params, [th] * n_early)
         for i in range(16):
             eng.submit([1 + i % 7, 2, 3], max_new_tokens=6)
         st, us = timed(lambda e=eng: e.run(max_steps=400), repeats=1)
@@ -61,7 +63,7 @@ def run() -> List[Row]:
     on = stats["exits_on"]
     ratio = ((on.energy_j / max(1, on.tokens_out))
              / (off.energy_j / max(1, off.tokens_out)))
-    seq_steps = 16 * (3 + 6)   # sequential serving of the same workload
+    seq_steps = 16 * 5    # sequential: 5 steps a request (the first admits)
     rows.append(Row(
         "engine/summary", 0.0,
         kv(energy_ratio_exits_on_over_off=ratio,

@@ -17,9 +17,10 @@ One chip (the default) runs three phases:
   incumbent placement and exit) must be identical to the reference, and
   energies must agree within ``tolerances.DIST_RTOL_F32``.
 * serving — qwen3-4b at its published widths (random bf16 weights from a
-  seed) through ``SplitServeEngine`` under a FIN placement
-  (``paper_scenario()``, ``paper_profile("h1")``): batch 8, cache 512, 8
-  requests of 16-token prompts, 16 new tokens each.  Every request must
+  seed) through ``SplitServeEngine`` under a FIN placement of its own
+  profile (``paper_scenario()``, ``profile_from_arch``): batch 8, cache
+  512, 8 requests of 16-token prompts, each admitted by one prefill, 16
+  new tokens each.  Every request must
   return 16 tokens, and on one more step the compiled ``ee_gate`` on the
   real exit logits must match ``ee_gate_ref`` exactly in argmax and within
   ``GATE_CONF_RTOL`` in confidence.
@@ -54,8 +55,9 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.configs import get  # noqa: E402
-from repro.core import (ChurnOrchestrator, Population,  # noqa: E402
-                        paper_profile, population_cohorts)
+from repro.core import (AppRequirements, ChurnOrchestrator,  # noqa: E402
+                        Population, paper_profile, population_cohorts,
+                        profile_from_arch)
 from repro.core.multiapp import PAPER_MULTIAPP_REQS  # noqa: E402
 from repro.core.scenarios import paper_scenario  # noqa: E402
 from repro.core.tolerances import DIST_RTOL_F32  # noqa: E402
@@ -197,8 +199,8 @@ def serving_phase(cfg, *, batch: int = 8, cache_len: int = 512,
         jax.random.PRNGKey(seed), cfg)
     eng = SplitServeEngine(cfg, params, batch_size=batch,
                            cache_len=cache_len, network=paper_scenario(),
-                           profile=paper_profile("h1"),
-                           req=PAPER_MULTIAPP_REQS["h1"])
+                           profile=profile_from_arch(cfg),
+                           req=AppRequirements(alpha=1.0, delta=0.05))
     rng = np.random.default_rng(seed)
     prompts = rng.integers(1, cfg.vocab_size, (n_requests, prompt_len))
     reqs = [eng.submit(p.tolist(), new_tokens) for p in prompts]
@@ -214,10 +216,11 @@ def serving_phase(cfg, *, batch: int = 8, cache_len: int = 512,
             f"request {r.rid}: {len(r.tokens)} of {new_tokens} tokens")
 
     toks = jnp.asarray(np.resize(prompts[:, 0], (batch, 1)), jnp.int32)
+    pos = jnp.asarray(eng._slot_pos)
     compiled = eng.decode_step.lower(eng.params, eng.caches, toks,
-                                     jnp.int32(eng.pos)).compile()
+                                     pos).compile()
     logits, eng.caches, exits = eng.decode_step(
-        eng.params, eng.caches, toks, jnp.int32(eng.pos))
+        eng.params, eng.caches, toks, pos)
     gate_err = {}
     for name, x in {**exits, "final": logits}.items():
         conf, arg = ee_gate(x)

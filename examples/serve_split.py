@@ -1,6 +1,7 @@
 """Split serving demo: FIN-placed early-exit LM with continuous batching.
 
-Builds a small early-exit LM, derives its Plane-2 profile, solves the FIN
+Builds a small early-exit LM, derives its Plane-2 profile from its
+architecture (``profile_from_arch``), solves the FIN
 placement over the mobile-edge-cloud system, then serves a request stream
 with exit-aware continuous batching — including a mid-run node failure that
 triggers an elastic FIN re-placement.
@@ -12,7 +13,7 @@ import sys
 import jax
 
 from repro.configs import get
-from repro.core import AppRequirements, paper_profile
+from repro.core import AppRequirements, profile_from_arch
 from repro.core.scenarios import paper_scenario
 from repro.models import transformer as T
 from repro.runtime.serve_engine import SplitServeEngine
@@ -21,14 +22,15 @@ from repro.runtime.serve_engine import SplitServeEngine
 def main() -> int:
     cfg = get("qwen3-4b", reduced=True)
     params = T.init_model(jax.random.PRNGKey(0), cfg)
-    # a degraded uplink pushes the placement off the mobile tier, so the
-    # mid-run failure below actually re-places (warm, via the plan IR)
+    # the placement lands off the mobile tier, so the mid-run failure below
+    # actually re-places (warm, via the plan IR); alpha 1.0 keeps the final
+    # head deployed (the profile claims no accuracy for the early exits)
     network = paper_scenario(uplink_bps=0.3e9)
-    profile = paper_profile("h1")
-    req = AppRequirements(alpha=0.55, delta=5e-3)
+    profile = profile_from_arch(cfg)
+    req = AppRequirements(alpha=1.0, delta=1e-3)
 
     eng = SplitServeEngine(cfg, params, batch_size=4, cache_len=128,
-                           thresholds=[0.6], network=network,
+                           thresholds=[0.6, 0.6], network=network,
                            profile=profile, req=req)
     tiers = [n.tier for n in network.nodes]
     print("FIN placement:",

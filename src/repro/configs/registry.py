@@ -37,11 +37,13 @@ def _phi3() -> ArchConfig:
 
 
 def _qwen3() -> ArchConfig:
-    # [hf:Qwen/Qwen3-8B; hf] — dense, qk_norm, GQA kv=8
+    # [hf:Qwen/Qwen3-4B; hf] — dense, qk_norm, GQA kv=8; head_dim 128 is not
+    # d_model / n_heads (80): q is projected 4096 wide; tied embeddings
     return ArchConfig(
         name="qwen3-4b", family="dense",
         n_layers=36, d_model=2560, n_heads=32, n_kv_heads=8, d_ff=9728,
-        vocab_size=151936, pattern=(A,), head_dim=80, qk_norm=True)
+        vocab_size=151936, pattern=(A,), head_dim=128, qk_norm=True,
+        tie_embeddings=True, norm_eps=1e-6, rope_theta=1e6)
 
 
 def _minitron() -> ArchConfig:
@@ -134,6 +136,14 @@ OPTIMIZED_OVERRIDES = {
     "jamba-1.5-large-398b": dict(seq_parallel=True, ssm_head_shard=True),
 }
 
+#: What a smoke variant keeps of its family beyond ``ArchConfig.reduced``:
+#: qwen3's head wider than ``d_model / n_heads`` (32 against 16) and its
+#: exits at the thirds of the depth (3 layers: exits after layers 1 and 2),
+#: so that the CPU tests exercise the shapes the chip runs.
+REDUCED_OVERRIDES = {
+    "qwen3-4b": dict(n_layers=3, head_dim=32, exit_layers=()),
+}
+
 _BUILDERS = {
     "jamba-1.5-large-398b": _jamba,
     "phi3-medium-14b": _phi3,
@@ -156,7 +166,9 @@ def get(name: str, *, reduced: bool = False,
     if optimized:
         import dataclasses
         cfg = dataclasses.replace(cfg, **OPTIMIZED_OVERRIDES.get(name, {}))
-    return cfg.reduced() if reduced else cfg
+    if not reduced:
+        return cfg
+    return cfg.reduced(**REDUCED_OVERRIDES.get(name, {}))
 
 
 def sub_quadratic(cfg: ArchConfig) -> bool:

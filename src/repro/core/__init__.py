@@ -14,6 +14,7 @@ Public API:
 from .system_model import (NodeSpec, Network, make_node, make_network,
                            PAPER_TIERS, TPU_TIERS)
 from .dnn_profile import (DNNProfile, ExitSpec, paper_profile, all_paper_apps,
+                          profile_from_arch,
                           synthetic_profile, BITS_PER_FEATURE)
 from .problem import (AppRequirements, Config, ConfigEval, Solution,
                       evaluate_config)
@@ -45,6 +46,7 @@ from .online import (ChurnOrchestrator, ChurnStats, TickReport,
 __all__ = [
     "NodeSpec", "Network", "make_node", "make_network", "PAPER_TIERS",
     "TPU_TIERS", "DNNProfile", "ExitSpec", "paper_profile", "all_paper_apps",
+    "profile_from_arch",
     "synthetic_profile", "BITS_PER_FEATURE", "AppRequirements", "Config",
     "ConfigEval", "Solution", "evaluate_config", "ExtendedGraph",
     "build_extended_graph", "build_extended_graphs", "to_networkx",

@@ -255,6 +255,44 @@ def all_paper_apps() -> Dict[str, DNNProfile]:
     return {h: paper_profile(h) for h in ("h1", "h2", "h3", "h4", "h5", "h6")}
 
 
+#: bits of one token id on a link (an int32)
+TOKEN_BITS = 32
+
+
+def profile_from_arch(cfg, *, bits: int = 16, context: int = 2048,
+                      accuracy: float = 1.0) -> DNNProfile:
+    """The Plane-2 profile of an early-exit LM (``configs.base.ArchConfig``)
+    for one generated token.
+
+    One block per segment of layers between exits (``cfg.exit_layer_list``
+    and the final head), its ops the matmul FLOPs of its layers for a token
+    attending to ``context`` positions (``launch/flops.py``); each cut
+    carries one hidden state, ``d_model * bits`` bits; the input and every
+    exit's output is one token id.  An exit's ops are its LM head's.
+
+    Random weights carry no accuracy and never clear a confidence gate, so
+    nothing is claimed for them: the early exits have accuracy 0 and
+    capture nothing (phi 0), and the final head carries the one assumed
+    ``accuracy``.  A requirement ``alpha`` at or below it then admits only
+    configurations that end at the final head, and FIN places the blocks
+    by energy and the latency bound alone."""
+    from repro.launch.flops import head_flops_per_token, layer_flops_per_token
+
+    per_period = sum(layer_flops_per_token(cfg, spec, context)
+                     for spec in cfg.pattern)
+    bounds = [0, *cfg.exit_layer_list, cfg.n_periods]
+    n = len(bounds) - 1
+    head = head_flops_per_token(cfg)
+    exits = [ExitSpec(block=k, ops=head, out_bits=TOKEN_BITS,
+                      accuracy=accuracy if k == n - 1 else 0.0,
+                      phi=1.0 if k == n - 1 else 0.0)
+             for k in range(n)]
+    return DNNProfile(
+        name=f"lm:{cfg.name}", input_bits=TOKEN_BITS,
+        block_ops=[per_period * (b - a) for a, b in zip(bounds, bounds[1:])],
+        cut_bits=[float(cfg.d_model * bits)] * n, exits=exits)
+
+
 def synthetic_profile(n_blocks: int, n_exits: int, *, seed: int = 0,
                       ops_scale: float = 10 * MOPS,
                       bits_scale: float = 1e6) -> DNNProfile:

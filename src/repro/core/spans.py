@@ -1,8 +1,9 @@
-"""Program spans: the population tick's wall-clock breakdown.
+"""Program spans: the population tick's and the serving step's wall-clock
+breakdown.
 
 ``span(on, stats, field, name)`` times one region of a tick.  ``on`` is the
 one switch, ``Population(timing=...)`` (the orchestrator's spans follow its
-cohorts).  Off, the call returns one shared null context: it costs the flag
+cohorts) or ``SplitServeEngine(timing=...)``.  Off, the call returns one shared null context: it costs the flag
 check and allocates nothing.  On, it enters
 ``jax.profiler.TraceAnnotation(name, **meta)``, which puts the region on the
 profiler's ``/host:CPU`` plane, on the same clock as the device's ``XLA
@@ -11,7 +12,8 @@ the elapsed milliseconds to ``stats.<field>``.  ``field=None`` gives an
 annotation-only span.  ``meta`` rides on the annotation as its arguments
 (the re-key passes the number of users it touched).
 
-Span names are ``orch.*`` for the orchestrator and ``pop.*`` for a cohort;
+Span names are ``orch.*`` for the orchestrator, ``pop.*`` for a cohort and
+``serve.*`` for the serving engine;
 nesting follows the call tree, so a span's self time is its duration less
 its children's.
 """

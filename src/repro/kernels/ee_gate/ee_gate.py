@@ -86,5 +86,6 @@ def ee_gate_pallas(logits: jnp.ndarray, *, bb: int = 8, bv: int = 2048,
                         pltpu.VMEM((bb, 1), jnp.float32),
                         pltpu.VMEM((bb, 1), jnp.int32)],
         interpret=interpret_mode(interpret),
+        name="ee_gate",      # the kernel's name in a device trace
     )(x)
     return conf[:B, 0], arg[:B, 0]

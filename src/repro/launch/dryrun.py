@@ -54,12 +54,13 @@ def shardings_for(cfg, mesh, shape, specs):
         out["batch"] = batch_shardings(cfg, mesh, specs["batch"])
     if "caches" in specs:
         out["caches"] = caches_shardings(cfg, mesh, specs["caches"])
+    from repro.sharding.specs import _dp_if_divisible
     if "tokens" in specs:
-        from repro.sharding.specs import _dp_if_divisible
         out["tokens"] = NamedSharding(
             mesh, P(_dp_if_divisible(mesh, specs["tokens"].shape[0]), None))
-    if "pos" in specs:
-        out["pos"] = scalar_sharding(mesh)
+    if "pos" in specs:                 # one position a batch row
+        out["pos"] = NamedSharding(
+            mesh, P(_dp_if_divisible(mesh, specs["pos"].shape[0])))
     return out
 
 

@@ -61,3 +61,21 @@ def param_count(cfg: ArchConfig, *, active: bool = False) -> int:
 
 def active_param_count(cfg: ArchConfig) -> int:
     return param_count(cfg, active=True)
+
+
+def layer_flops_per_token(cfg: ArchConfig, spec: LayerSpec,
+                          context: int) -> float:
+    """Matmul FLOPs of one layer for one token that attends to ``context``
+    earlier positions (2 per active parameter, plus the scores and the
+    weighted sum of attention: 4 * heads * head_dim per position read)."""
+    f = 2.0 * _layer_params(cfg, spec, active=True)
+    if spec.kind == "attn":
+        window = cfg.sliding_window or context
+        f += 4.0 * cfg.n_heads * cfg.head_dim_ * min(context, window)
+    return f
+
+
+def head_flops_per_token(cfg: ArchConfig) -> float:
+    """FLOPs of one LM head (an exit's or the final one) for one token,
+    over the unpadded vocabulary."""
+    return 2.0 * cfg.d_model * cfg.vocab_size

@@ -15,7 +15,7 @@ import argparse
 import jax
 
 from repro.configs import ARCH_NAMES, get
-from repro.core import AppRequirements, paper_profile
+from repro.core import AppRequirements, profile_from_arch
 from repro.core.scenarios import paper_scenario
 from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
@@ -42,9 +42,10 @@ def main() -> None:
         jax.random.PRNGKey(0), cfg)
     eng = SplitServeEngine(
         cfg, params, batch_size=args.batch, cache_len=args.cache_len,
-        thresholds=[args.threshold] * (len(cfg.exit_layer_list)),
-        network=paper_scenario(), profile=paper_profile("h2"),
-        req=AppRequirements(alpha=0.55, delta=8e-3))
+        thresholds=[args.threshold] * len(cfg.exit_layer_list),
+        network=paper_scenario(),
+        profile=profile_from_arch(cfg),
+        req=AppRequirements(alpha=1.0, delta=0.05))
     for i in range(args.requests):
         eng.submit([1 + i % 7, 2, 3], max_new_tokens=args.max_new)
     stats = eng.run()

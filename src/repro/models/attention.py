@@ -136,6 +136,10 @@ def attn_apply(params, cfg: ArchConfig, x, positions) -> jnp.ndarray:
 class KVCacheSpec:
     """Cache geometry for one attention layer (ring buffer if SWA).
 
+    Every batch row (serving slot) has its own position row ``pos`` [B, T]:
+    the absolute position held in each cache entry, -1 where empty, so
+    rows at different depths share one cache.
+
     ``quantized=True`` stores K/V as int8 with a per-(slot, kv-head) f32
     scale — 2x less HBM traffic on the decode hot path (the memory-bound
     roofline term of every decode cell; EXPERIMENTS §Perf/granite)."""
@@ -148,28 +152,21 @@ class KVCacheSpec:
     def _kv_dtype(self, dtype):
         return jnp.int8 if self.quantized else dtype
 
-    def init(self, dtype):
-        shape = (self.batch, self.max_len, self.n_kv, self.head_dim)
-        out = {"k": jnp.zeros(shape, self._kv_dtype(dtype)),
-               "v": jnp.zeros(shape, self._kv_dtype(dtype)),
-               "pos": jnp.full((self.max_len,), -1, jnp.int32)}
-        if self.quantized:
-            sshape = (self.batch, self.max_len, self.n_kv)
-            out["k_scale"] = jnp.zeros(sshape, F32)
-            out["v_scale"] = jnp.zeros(sshape, F32)
-        return out
-
     def shape_dtype(self, dtype):
-        import jax
         shape = (self.batch, self.max_len, self.n_kv, self.head_dim)
         out = {"k": jax.ShapeDtypeStruct(shape, self._kv_dtype(dtype)),
                "v": jax.ShapeDtypeStruct(shape, self._kv_dtype(dtype)),
-               "pos": jax.ShapeDtypeStruct((self.max_len,), jnp.int32)}
+               "pos": jax.ShapeDtypeStruct((self.batch, self.max_len),
+                                           jnp.int32)}
         if self.quantized:
             sshape = (self.batch, self.max_len, self.n_kv)
             out["k_scale"] = jax.ShapeDtypeStruct(sshape, F32)
             out["v_scale"] = jax.ShapeDtypeStruct(sshape, F32)
         return out
+
+    def init(self, dtype):
+        return {k: jnp.full(s.shape, -1 if k == "pos" else 0, s.dtype)
+                for k, s in self.shape_dtype(dtype).items()}
 
 
 def cache_spec(cfg: ArchConfig, batch: int, seq_len: int) -> KVCacheSpec:
@@ -190,73 +187,78 @@ def _dequantize_kv(q, scale, dtype):
     return (q.astype(F32) * scale[..., None]).astype(dtype)
 
 
+def kv_entries(cache: dict, k, v) -> dict:
+    """The cache entries that store ``k``/``v`` ([..., KV, D]): int8 values
+    and their scales for a quantized cache, else the values in the cache's
+    own dtype."""
+    if "k_scale" in cache:
+        kq, ks = _quantize_kv(k)
+        vq, vs = _quantize_kv(v)
+        return {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+    return {"k": k.astype(cache["k"].dtype), "v": v.astype(cache["v"].dtype)}
+
+
 def decode_attention(q, k_cache, v_cache, cache_pos, pos, *, window: int
                      ) -> jnp.ndarray:
     """One-token attention over the cache.
 
-    q: [B,1,H,D]; caches: [B,T,KV,D]; cache_pos: [T] absolute positions of
-    each slot (-1 = empty); pos: scalar current position.  Reference
-    implementation for the Pallas ``decode_attn`` kernel.
+    q: [B,1,H,D]; caches: [B,T,KV,D]; cache_pos: [B,T] (or [T], shared)
+    absolute position of each entry (-1 = empty); pos: [B] (or scalar) the
+    position each row is decoding.  Reference implementation for the
+    Pallas ``decode_attn`` kernel.
     """
     B, _, H, D = q.shape
-    KV = k_cache.shape[2]
+    T, KV = k_cache.shape[1], k_cache.shape[2]
     G = H // KV
     scale = D ** -0.5
+    cache_pos = jnp.broadcast_to(cache_pos, (B, T))
+    pos = jnp.broadcast_to(pos, (B,))[:, None]
     qg = q.reshape(B, KV, G, D)
     s = jnp.einsum("bkgd,btkd->bkgt", qg, k_cache,
                    preferred_element_type=F32) * scale
     ok = (cache_pos >= 0) & (cache_pos <= pos)
     if window > 0:
         ok &= cache_pos > pos - window
-    s = jnp.where(ok[None, None, None, :], s, NEG_INF)
+    s = jnp.where(ok[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgt,btkd->bkgd", p.astype(q.dtype), v_cache,
                      preferred_element_type=F32)
     return out.reshape(B, 1, H, D).astype(q.dtype)
 
 
-def attn_decode_step(params, cfg: ArchConfig, x, cache: dict, pos
-                     ) -> Tuple[jnp.ndarray, dict]:
-    """x: [B,1,d]; cache: {"k","v","pos"[,"k_scale","v_scale"]}; pos: scalar
-    int32 (current index).
+def attn_decode_step(params, cfg: ArchConfig, x, cache: dict, pos,
+                     layer) -> Tuple[jnp.ndarray, dict]:
+    """x: [B,1,d]; cache: the whole stack {"k","v","pos"[,"k_scale",
+    "v_scale"]}, each [L, B, T, ...], of which this is layer ``layer``;
+    pos: [B] int32, each row's current position (a scalar is shared by
+    every row).
 
-    Returns (out [B,1,d], updated cache).  SWA uses a ring buffer: slot =
-    pos % window.  int8 caches quantize the new K/V and dequantize on read.
+    Returns (out [B,1,d], updated stack).  Row b's new K/V lands at entry
+    ``pos[b] % T`` (a ring buffer under SWA); int8 caches quantize it and
+    dequantize on read.  Only the new entries are written, so a donated
+    stack is updated in place.
     """
-    positions = pos[None] if pos.ndim == 0 else pos
-    q, k, v = _project_qkv(params, cfg, x, jnp.broadcast_to(
-        positions, (x.shape[0], 1)))
-    T = cache["k"].shape[1]
-    slot = pos % T
-    quantized = "k_scale" in cache
-    new_cache = {}
-    if quantized:
-        kq, ks = _quantize_kv(k)
-        vq, vs = _quantize_kv(v)
-        k_store, v_store = kq, vq
-        new_cache["k_scale"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["k_scale"], ks, slot, axis=1)
-        new_cache["v_scale"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["v_scale"], vs, slot, axis=1)
+    B = x.shape[0]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    q, k, v = _project_qkv(params, cfg, x, pos[:, None])
+    T = cache["k"].shape[2]
+    at = (layer, jnp.arange(B), pos % T)
+    new = kv_entries(cache, k[:, 0], v[:, 0])
+    new["pos"] = pos
+    cache = {name: cache[name].at[at].set(val) for name, val in new.items()}
+    mine = jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        cache)
+    if "k_scale" in mine:
+        k_read = _dequantize_kv(mine["k"], mine["k_scale"], x.dtype)
+        v_read = _dequantize_kv(mine["v"], mine["v_scale"], x.dtype)
     else:
-        k_store, v_store = k, v
-    k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_store,
-                                                  slot, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_store,
-                                                  slot, axis=1)
-    cache_pos = jax.lax.dynamic_update_slice_in_dim(
-        cache["pos"], pos[None].astype(jnp.int32), slot, axis=0)
-    if quantized:
-        k_read = _dequantize_kv(k_cache, new_cache["k_scale"], x.dtype)
-        v_read = _dequantize_kv(v_cache, new_cache["v_scale"], x.dtype)
-    else:
-        k_read, v_read = k_cache, v_cache
-    out = decode_attention(q, k_read, v_read, cache_pos, pos,
+        k_read, v_read = mine["k"], mine["v"]
+    out = decode_attention(q, k_read, v_read, mine["pos"], pos,
                            window=cfg.sliding_window)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"],
                    preferred_element_type=F32).astype(x.dtype)
-    new_cache.update({"k": k_cache, "v": v_cache, "pos": cache_pos})
-    return y, new_cache
+    return y, cache
 
 
 def attn_flops_per_token(cfg: ArchConfig, kv_len: int) -> float:

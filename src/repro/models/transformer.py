@@ -18,6 +18,8 @@ Entry points:
   loss_fn(params, cfg, batch)        -> scalar (BranchyNet joint CE)
   prefill(params, cfg, batch)        -> (logits_last, caches)
   decode_step(params, cfg, tokens, caches, pos) -> (logits, caches, exits)
+  prefill_into_slot(params, cfg, caches, slot, tokens, length)
+                                     -> (logits, caches, exits)
   encode(params, cfg, batch)         -> final logits (encoder-only archs)
 """
 from __future__ import annotations
@@ -331,51 +333,73 @@ def cache_shape_dtypes(cfg: ArchConfig, batch: int, seq_len: int) -> dict:
 # Decode
 # ---------------------------------------------------------------------------
 
-def _period_decode(cfg: ArchConfig, pp: dict, h, cache: dict, pos):
-    new_cache = {}
+def _period_decode(cfg: ArchConfig, pp: dict, h, caches: dict, period, pos):
+    """One period at decode.  ``caches`` is the whole stack (the scan's
+    carry) and ``period`` this period's index in it: attention writes its
+    new entries in place, an SSM layer replaces its own state."""
     for i, spec in enumerate(cfg.pattern):
-        p = pp[f"l{i}"]
+        p, name = pp[f"l{i}"], f"l{i}"
         hn = rmsnorm(p["norm1"], h, cfg.norm_eps)
         if spec.kind == "attn":
-            y, new_cache[f"l{i}"] = ATT.attn_decode_step(
-                p["mix"], cfg, hn, cache[f"l{i}"], pos)
+            y, c = ATT.attn_decode_step(p["mix"], cfg, hn, caches[name], pos,
+                                        layer=period)
         else:
-            y, new_cache[f"l{i}"] = SSM.ssm_decode_step(
-                p["mix"], cfg, hn, cache[f"l{i}"])
+            mine = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, period, 0, keepdims=False), caches[name])
+            y, new = SSM.ssm_decode_step(p["mix"], cfg, hn, mine)
+            c = _put_period(caches[name], new, period)
+        caches = {**caches, name: c}
         h = h + y
         if spec.mlp != "none":
             hn = rmsnorm(p["norm2"], h, cfg.norm_eps)
             h = h + (mlp_apply(p["mlp"], hn) if spec.mlp == "dense"
                      else MOE.moe_apply(p["mlp"], cfg, hn))
-    return h, new_cache
+    return h, caches
+
+
+def _put_period(stack: dict, new: dict, period) -> dict:
+    """Write one period's cache entries ``new`` into the stacked ``stack``."""
+    return jax.tree.map(lambda a, n: jax.lax.dynamic_update_index_in_dim(
+        a, n.astype(a.dtype), period, 0), stack, new)
+
+
+def _heads(params, cfg: ArchConfig, hs, h) -> Tuple[jnp.ndarray,
+                                                   Dict[str, jnp.ndarray]]:
+    """Final logits from the last hidden state ``h`` [..., d] and each
+    exit's from the per-period hidden states ``hs`` [n_periods, ..., d]."""
+    head = _lm_head_params(params, cfg)
+    exits = {f"exit_{b}": exit_head_apply(params["exits"][f"exit_{b}"], cfg,
+                                          hs[b - 1], head)
+             for b in cfg.exit_layer_list}
+    hn = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    return lm_head_apply(head, hn, cfg.vocab_size), exits
 
 
 def decode_step(params, cfg: ArchConfig, tokens, caches: dict, pos
                 ) -> Tuple[jnp.ndarray, dict, Dict[str, jnp.ndarray]]:
-    """One decode step.  tokens: [B,1] int32; pos: scalar int32 (0-based
-    index of the position being generated); caches from init_caches/prefill.
+    """One decode step.  tokens: [B,1] int32; pos: [B] int32, the 0-based
+    position each row generates (a scalar is shared by every row); caches
+    from init_caches/prefill/prefill_into_slot.
 
     Returns (final logits [B,V_pad], new caches, exit logits {name: [B,V]}).
     """
     assert cfg.has_decoder, f"{cfg.name} is encoder-only"
+    B = tokens.shape[0]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     h = embed_apply(params["embed"], tokens)
-    head = _lm_head_params(params, cfg)
 
     def body(carry, xs):
-        pp, cache = xs
-        hh, new_cache = _period_decode(cfg, pp, carry, cache, pos)
-        return hh, (new_cache, hh)
+        hh, cc = carry
+        pp, period = xs
+        hh, cc = _period_decode(cfg, pp, hh, cc, period, pos)
+        return (hh, cc), hh[:, 0]
 
-    # one scan over the whole stack: the exits read the per-period hidden
-    # states it emits, so no segment slices of the weights or caches (each
-    # a full copy inside the step) are ever materialized
-    h, (new_caches, hs) = jax.lax.scan(body, h, (params["layers"], caches))
-    exits: Dict[str, jnp.ndarray] = {
-        f"exit_{b}": exit_head_apply(params["exits"][f"exit_{b}"], cfg,
-                                     hs[b - 1], head)[:, 0]
-        for b in cfg.exit_layer_list}
-    hn = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    logits = lm_head_apply(head, hn, cfg.vocab_size)[:, 0]
+    # the caches ride in the carry and each layer writes only its new
+    # entries, so a donated cache is updated in place: no second copy of
+    # the stack (scan outputs) and no segment slices of it
+    (h, new_caches), hs = jax.lax.scan(
+        body, (h, caches), (params["layers"], jnp.arange(cfg.n_periods)))
+    logits, exits = _heads(params, cfg, hs, h[:, 0])
     return logits, new_caches, exits
 
 
@@ -427,7 +451,7 @@ def prefill(params, cfg: ArchConfig, batch: dict, cache_len: int
                         k_tail.astype(dtype))
                     cache_i["v"] = cache_i["v"].at[:, slots].set(
                         v_tail.astype(dtype))
-                cache_i["pos"] = cpos.at[slots].set(src_pos)
+                cache_i["pos"] = cpos.at[:, slots].set(src_pos)
                 new_cache[f"l{i}"] = cache_i
             else:
                 y_full, state = SSM.ssm_apply_with_state(p["mix"], cfg, hn)
@@ -444,3 +468,70 @@ def prefill(params, cfg: ArchConfig, batch: dict, cache_len: int
     hn = rmsnorm(params["final_norm"], h, cfg.norm_eps)
     logits = lm_head_apply(head, hn[:, -1:], cfg.vocab_size)[:, 0]
     return logits, caches
+
+
+def prefill_into_slot(params, cfg: ArchConfig, caches: dict, slot, tokens,
+                      length) -> Tuple[jnp.ndarray, dict,
+                                       Dict[str, jnp.ndarray]]:
+    """Admit one sequence into batch row ``slot`` of the decode caches in
+    one pass: positions ``[0, length)`` of ``tokens`` ([S] int32, padded to
+    S >= length; S at most the cache length) are written in every layer,
+    and every other entry of the row is marked empty, so nothing of the
+    row's previous occupant stays visible.  ``slot`` and ``length`` may be
+    traced: one compile per padded length S.
+
+    Padding is causal-safe for attention (row t never reads t' > t).  An
+    SSM layer's state is the state after all S tokens, so patterns with
+    SSM layers need S == length.
+
+    Returns (final logits [1,V_pad], caches, exit logits {name: [1,V_pad]})
+    at position ``length - 1``: the sequence's first generated token.
+    """
+    S = tokens.shape[-1]
+    h = embed_apply(params["embed"], tokens.reshape(1, S))
+    positions = jnp.arange(S, dtype=jnp.int32)[None]
+    last = jnp.asarray(length, jnp.int32) - 1
+    slot = jnp.asarray(slot, jnp.int32)
+
+    def body(carry, xs):
+        hh, cc = carry
+        pp, period = xs
+        for i, spec in enumerate(cfg.pattern):
+            p, name = pp[f"l{i}"], f"l{i}"
+            hn = rmsnorm(p["norm1"], hh, cfg.norm_eps)
+            c = cc[name]
+            if spec.kind == "attn":
+                q, k, v = ATT._project_qkv(p["mix"], cfg, hn, positions)
+                o = ATT.chunked_attention(
+                    q, k, v, positions[0], positions[0], causal=cfg.causal,
+                    window=cfg.sliding_window, chunk=cfg.attn_chunk)
+                y = jnp.einsum("bshk,hkd->bsd", o, p["mix"]["wo"],
+                               preferred_element_type=F32).astype(hh.dtype)
+                T = c["pos"].shape[2]
+                assert S <= T, f"prompt bucket {S} > cache length {T}"
+                ent = ATT.kv_entries(c, k[0], v[0])
+                t = jnp.arange(T, dtype=jnp.int32)
+                ent["pos"] = jnp.where(t <= last, t, -1)
+                c = {n: jax.lax.dynamic_update_slice(
+                        c[n], ent[n][None, None].astype(c[n].dtype),
+                        (period, slot) + (0,) * (c[n].ndim - 2))
+                     if n in ent else c[n] for n in c}
+            else:
+                y, state = SSM.ssm_apply_with_state(p["mix"], cfg, hn)
+                c = jax.tree.map(lambda a, n: jax.lax.dynamic_update_slice(
+                    a, n[None].astype(a.dtype),
+                    (period, slot) + (0,) * (a.ndim - 2)), c, state)
+            cc = {**cc, name: c}
+            hh = hh + y
+            if spec.mlp != "none":
+                hn = rmsnorm(p["norm2"], hh, cfg.norm_eps)
+                hh = hh + (mlp_apply(p["mlp"], hn) if spec.mlp == "dense"
+                           else MOE.moe_apply(p["mlp"], cfg, hn))
+        at = jax.lax.dynamic_index_in_dim(hh[0], last, 0, keepdims=True)
+        return (hh, cc), at
+
+    (h, caches), hs = jax.lax.scan(
+        body, (h, caches), (params["layers"], jnp.arange(cfg.n_periods)))
+    at = jax.lax.dynamic_index_in_dim(h[0], last, 0, keepdims=True)
+    logits, exits = _heads(params, cfg, hs, at)
+    return logits, caches, exits

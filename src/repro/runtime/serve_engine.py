@@ -4,6 +4,10 @@ This is the TPU-native adaptation of the paper's execution model
 (DESIGN.md Sec. 3): SPMD cannot stop computing individual batch lanes, so
 per-sample early exits are realized as *scheduling*:
 
+  * a request is admitted into a free slot with one prefill of its
+    prompt (``transformer.prefill_into_slot``, prompt lengths padded to
+    power-of-two buckets), which also gives its first token; every slot
+    then decodes at its own position;
   * every decode step runs the full stack once for the active batch;
   * the fused gate (kernels/ee_gate) scores each exit's logits; a sequence
     whose confidence clears its threshold takes THAT exit's token — deeper
@@ -13,7 +17,10 @@ per-sample early exits are realized as *scheduling*:
     becomes throughput;
   * per-token *tier accounting*: with a FIN placement (blocks -> tiers),
     the engine charges each token only the blocks up to its exit, yielding
-    the measured energy the paper's objective (3a) predicts;
+    the measured energy the paper's objective (3a) predicts.  The
+    profile describes the served model (one exit per model exit,
+    ``core.profile_from_arch``), and a token exits no deeper than the
+    placement's final exit;
   * fault tolerance: the placement lives in a persistent ``core.Plan`` —
     ``fail_node`` masks the dead node and issues a *warm* re-solve (no
     graph reconstruction; bit-exact vs a cold solve on the reduced
@@ -47,7 +54,7 @@ per-sample early exits are realized as *scheduling*:
 """
 from __future__ import annotations
 
-import time
+import itertools
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -63,7 +70,9 @@ from repro.core.contingency import (ContingencyEntry, ContingencyLibrary,
                                     NoFeasiblePlacement)
 from repro.core.frontier import frontier_pick
 from repro.core.scenarios import MOBILE_UPLINK_BPS, ChurnEvent
+from repro.core.spans import span
 from repro.kernels.ee_gate.ops import ee_gate
+from repro.models import attention as ATT
 from repro.models import transformer as T
 
 
@@ -92,6 +101,15 @@ class EngineStats:
     contingency_misses: int = 0       # failovers that warm re-solved
     paused_events: int = 0            # infeasible -> serving parked
     degrades: int = 0                 # infeasible -> degraded frontier row
+    admissions: int = 0               # requests prefilled into a slot
+    prompt_tokens_prefilled: int = 0  # their prompt tokens (unpadded)
+    live_depth_sum: int = 0           # KV entries read: over decode steps,
+    #                                   each live slot's position + 1
+    # program spans (``timing=True``), ms
+    t_admit_ms: float = 0.0           # serve.admit: prefills + 1st tokens
+    t_decode_ms: float = 0.0          # serve.decode: the decode program
+    t_gate_ms: float = 0.0            # serve.gate: ee_gate + host reads
+    t_account_ms: float = 0.0         # serve.account: the slot loop
 
     @property
     def measured_phi(self) -> Dict[int, float]:
@@ -102,11 +120,14 @@ class EngineStats:
 class SplitServeEngine:
     """Decode engine with exit-aware continuous batching.
 
-    Prompts are consumed token-by-token through the decode path (prefill-as-
-    decode keeps slot cache surgery trivial); generation then proceeds with
-    gated exits.  ``placement``/``profile``/``network`` wire the engine to
-    the paper's placement problem for energy accounting; they are optional —
-    without them the engine is a plain continuous-batching server.
+    A request is admitted with one prefill of its prompt into a free slot,
+    which also yields its first token; generation then proceeds with gated
+    exits, each slot at its own position.  ``profile``/``network``/``req``
+    wire the engine to the paper's placement problem for energy
+    accounting; they are optional — without them the engine is a plain
+    continuous-batching server.  ``thresholds``: one per early exit.
+    ``timing=True`` turns on the program
+    spans ``serve.*`` (``core/spans.py``) and their ``EngineStats`` fields.
     """
 
     def __init__(self, cfg: ArchConfig, params, *, batch_size: int,
@@ -117,27 +138,64 @@ class SplitServeEngine:
                  gamma: int = 10, seed: int = 0,
                  migration_weight: float = 0.0, frontier_k: int = 4,
                  on_infeasible: str = "raise", contingency: bool = True,
-                 hysteresis: float = 0.05):
+                 hysteresis: float = 0.05, timing: bool = False):
         assert cfg.has_decoder
         self.cfg = cfg
         self.params = params
         self.B = batch_size
         self.cache_len = cache_len
         self.n_exits = len(cfg.exit_layer_list) + 1
-        self.thresholds = list(thresholds) if thresholds is not None else \
-            [0.9] * (self.n_exits - 1)
+        self.thresholds = ([0.9] * (self.n_exits - 1) if thresholds is None
+                           else list(thresholds))
+        if len(self.thresholds) != self.n_exits - 1:
+            raise ValueError(f"{len(self.thresholds)} thresholds for "
+                             f"{self.n_exits - 1} early exits")
+        if profile is not None and profile.n_exits != self.n_exits:
+            raise ValueError(
+                f"profile {profile.name!r} has {profile.n_exits} exits, the "
+                f"model {self.n_exits}: place the served model's own "
+                f"profile (core.profile_from_arch)")
+        self.timing = bool(timing)
         self.caches = T.init_caches(cfg, batch_size, cache_len)
-        #: the jitted step ``(params, caches, tokens [B, 1], pos) ->
+
+        def decode_step(p, c, t, pos):
+            return T.decode_step(p, cfg, t, c, pos)
+
+        def prefill_into_slot(p, c, slot, t, n):
+            return T.prefill_into_slot(p, cfg, c, slot, t, n)
+        #: the jitted step ``(params, caches, tokens [B, 1], pos [B]) ->
         #: (logits, caches, exits)``; it donates the caches, so the step
-        #: updates them in place instead of keeping a second copy live
-        self.decode_step = jax.jit(
-            lambda p, c, t, pos: T.decode_step(p, cfg, t, c, pos),
-            donate_argnums=(1,))
+        #: updates them in place instead of keeping a second copy live.
+        #: Its device program is named ``jit_decode_step``.
+        self.decode_step = jax.jit(decode_step, donate_argnums=(1,))
+        #: the jitted admission ``(params, caches, slot, tokens [S],
+        #: length) -> (logits, caches, exits)``, caches donated; program
+        #: ``jit_prefill_into_slot``
+        self.prefill_into_slot = jax.jit(prefill_into_slot,
+                                         donate_argnums=(1,))
+        #: prompt lengths are padded to these: powers of two from 64, and
+        #: the cache's length; an SSM state needs the exact length (None)
+        self.buckets: Optional[List[int]] = None
+        if all(s.kind == "attn" for s in cfg.pattern):
+            T_len = ATT.cache_spec(cfg, batch_size, cache_len).max_len
+            self.buckets = [b for b in (64 << i for i in range(16))
+                            if b < T_len] + [T_len]
+        self._ring = cfg.sliding_window > 0
         self.slots: List[Optional[Request]] = [None] * batch_size
         self.queue: List[Request] = []
         self.stats = EngineStats()
-        self.pos = 0
-        self._slot_len = np.zeros(batch_size, np.int32)
+        self._rid = itertools.count(10_000)
+        #: the position each slot decodes next (its context length)
+        self._slot_pos = np.zeros(batch_size, np.int32)
+        #: the last step's logits on the device, ``{"final": [B, V_pad],
+        #: "exit_<l>": ...}``; ``last_decoded[i]`` is ``(request, position)``
+        #: of row i in them (None: an empty slot); ``last_admissions`` the
+        #: step's admissions, ``(request, position, logits)`` each
+        self.last_logits: Optional[Dict[str, jnp.ndarray]] = None
+        self.last_decoded: List[Optional[Tuple[Request, int]]] = \
+            [None] * batch_size
+        self.last_admissions: List[Tuple[Request, int,
+                                         Dict[str, jnp.ndarray]]] = []
         # placement integration: a persistent Plan owns the built pipeline
         # state, so failure/recovery re-solves are warm deltas
         self.profile = profile
@@ -193,7 +251,16 @@ class SplitServeEngine:
 
     # ------------------------------------------------------------------ API
     def submit(self, prompt: Sequence[int], max_new_tokens: int) -> Request:
-        r = Request(rid=len(self.queue) + 10_000, prompt=list(prompt),
+        """Queue a request.  Its prompt must fit the cache; without a
+        sliding window so must every position it decodes."""
+        n = len(prompt)
+        if not 1 <= n <= self.cache_len or max_new_tokens < 1:
+            raise ValueError(f"prompt of {n} tokens, {max_new_tokens} new "
+                             f"tokens, cache of {self.cache_len}")
+        if not self._ring and n + max_new_tokens - 1 > self.cache_len:
+            raise ValueError(f"{n} prompt + {max_new_tokens} new tokens "
+                             f"overflow the {self.cache_len}-entry cache")
+        r = Request(rid=next(self._rid), prompt=list(prompt),
                     max_new_tokens=max_new_tokens)
         self.queue.append(r)
         return r
@@ -453,11 +520,93 @@ class SplitServeEngine:
         return self.stats
 
     # ----------------------------------------------------------------- step
+    def bucket(self, n: int) -> int:
+        """The padded length a prompt of ``n`` tokens is prefilled at."""
+        if self.buckets is None:
+            return n
+        return next(b for b in self.buckets if b >= n)
+
+    def warm(self) -> None:
+        """Compile every program serving can reach: each prompt bucket's
+        admission, the decode step and the gates at both row counts.  The
+        slots' cache rows are left in an undefined state, which admission
+        overwrites."""
+        for b in self.buckets or ():
+            _, self.caches, _ = self.prefill_into_slot(
+                self.params, self.caches, jnp.int32(0),
+                jnp.zeros(b, jnp.int32), jnp.int32(b))
+        logits, self.caches, exits = self.decode_step(
+            self.params, self.caches, jnp.zeros((self.B, 1), jnp.int32),
+            jnp.asarray(self._slot_pos))
+        for x in (logits, logits[:1]):
+            jax.block_until_ready(ee_gate(x))
+
+    def _deployed(self) -> int:
+        """Index of the deepest exit a token may take: the placement's
+        final exit (deeper blocks are not deployed), else the last."""
+        return (self.n_exits - 1 if self.placement is None
+                else self.placement.final_exit)
+
+    def _gate(self, heads: List[jnp.ndarray]) -> Tuple[np.ndarray,
+                                                      np.ndarray]:
+        """First-exit-wins over the deployed heads ``heads`` (exits in
+        order, the last one taken by every row that clears no earlier
+        threshold).  Returns (token, exit index) per row."""
+        confs, args = [], []
+        for x in heads:
+            c, a = ee_gate(x)
+            confs.append(np.asarray(c))
+            args.append(np.asarray(a))
+        k = len(heads) - 1
+        exit_idx = np.full(len(args[0]), k)
+        for j in reversed(range(k)):
+            exit_idx = np.where(confs[j] >= self.thresholds[j], j, exit_idx)
+        return np.choose(exit_idx, args), exit_idx
+
+    def _heads(self, logits, exits) -> List[jnp.ndarray]:
+        """The deployed heads' logits, shallowest first."""
+        heads = [exits[f"exit_{p}"] for p in self.cfg.exit_layer_list]
+        return (heads + [logits])[:self._deployed() + 1]
+
+    def _emit(self, i: int, r: Request, token: int, exit_idx: int) -> None:
+        r.tokens.append(token)
+        r.exits_taken.append(exit_idx)
+        self.stats.tokens_out += 1
+        self._charge(exit_idx)
+        if len(r.tokens) >= r.max_new_tokens:
+            r.done = True
+            self.slots[i] = None   # continuous batching: free the slot
+
+    def _admit(self, i: int, r: Request) -> None:
+        """Prefill ``r``'s prompt into slot ``i``; its first token comes
+        from the prefill's logits at the prompt's last position."""
+        n = len(r.prompt)
+        toks = np.zeros(self.bucket(n), np.int32)
+        toks[:n] = r.prompt
+        logits, self.caches, exits = self.prefill_into_slot(
+            self.params, self.caches, jnp.int32(i), jnp.asarray(toks),
+            jnp.int32(n))
+        self._slot_pos[i] = n
+        self.slots[i] = r
+        self.stats.admissions += 1
+        self.stats.prompt_tokens_prefilled += n
+        self.last_admissions.append((r, n - 1, {"final": logits, **exits}))
+        token, exit_idx = self._gate(self._heads(logits, exits))
+        self._emit(i, r, int(token[0]), int(exit_idx[0]))
+
     def _fill_slots(self) -> None:
-        for i in range(self.B):
-            if self.slots[i] is None and self.queue:
-                self.slots[i] = self.queue.pop(0)
-                self._slot_len[i] = 0
+        """Admit queued requests into the free slots, in queue order (again
+        while a request admitted with a one-token budget frees its slot)."""
+        while True:
+            free = [i for i in range(self.B) if self.slots[i] is None]
+            take = self.queue[:len(free)]
+            if not take:
+                return
+            del self.queue[:len(take)]
+            with span(self.timing, self.stats, "t_admit_ms", "serve.admit",
+                      tokens=sum(len(r.prompt) for r in take)):
+                for i, r in zip(free, take):
+                    self._admit(i, r)
 
     def _charge(self, exit_idx: int) -> None:
         """Tier accounting for one emitted token at the given exit."""
@@ -465,77 +614,58 @@ class SplitServeEngine:
         st.exit_histogram[exit_idx] = st.exit_histogram.get(exit_idx, 0) + 1
         if self.profile is None or self.placement is None:
             return
-        prof, place = self.profile, self.placement
-        last_block = prof.exits[min(exit_idx, prof.n_exits - 1)].block
-        nw = self.network
+        prof, place, nw = self.profile, self.placement, self.network
+        last_block = prof.exits[exit_idx].block
         for b in range(prof.n_blocks):
-            if b <= last_block:
-                st.blocks_executed += 1
-                n = place.placement[min(b, len(place.placement) - 1)]
-                t_comp = prof.block_ops_with_exit(b, prof.n_exits - 1) \
-                    / nw.compute[n]
-                st.energy_j += nw.power_active[n] * t_comp
-                if b < last_block:
-                    n2 = place.placement[min(b + 1, len(place.placement) - 1)]
-                    if n2 != n:
-                        st.energy_j += (nw.e_tx[n] + nw.e_rx[n2]) \
-                            * prof.cut_bits[b]
-            else:
+            if b > last_block:
                 st.blocks_saved += 1
+                continue
+            st.blocks_executed += 1
+            n = place.placement[b]
+            st.energy_j += nw.power_active[n] * prof.block_ops_with_exit(
+                b, place.final_exit) / nw.compute[n]
+            if b < last_block and place.placement[b + 1] != n:
+                n2 = place.placement[b + 1]
+                st.energy_j += (nw.e_tx[n] + nw.e_rx[n2]) * prof.cut_bits[b]
 
     def step(self) -> None:
         if self.paused:
             return                # parked until feasibility is restored
         self._maybe_refill()      # background contingency refill (off the
         #                           failover critical path)
-        self._fill_slots()
-        if not any(self.slots):
-            return
-        toks = np.zeros((self.B, 1), np.int32)
-        for i, r in enumerate(self.slots):
-            if r is None:
-                continue
-            consumed = int(self._slot_len[i])
-            if consumed < len(r.prompt):
-                toks[i, 0] = r.prompt[consumed]
-            else:
-                toks[i, 0] = r.tokens[-1] if r.tokens else r.prompt[-1]
-
-        logits, self.caches, exits = self.decode_step(
-            self.params, self.caches, jnp.asarray(toks),
-            jnp.int32(self.pos))
-        self.pos += 1
-        self.stats.steps += 1
-
-        # gate every exit with the fused kernel; first-exit-wins
-        confs, args = [], []
-        for j, p_idx in enumerate(self.cfg.exit_layer_list):
-            c, a = ee_gate(exits[f"exit_{p_idx}"])
-            confs.append(np.asarray(c))
-            args.append(np.asarray(a))
-        c_f, a_f = ee_gate(logits)
-        confs.append(np.asarray(c_f))
-        args.append(np.asarray(a_f))
-
-        for i, r in enumerate(self.slots):
-            if r is None:
-                continue
-            self._slot_len[i] += 1
-            if self._slot_len[i] < len(r.prompt):
-                continue  # still consuming the prompt
-            exit_idx = self.n_exits - 1
-            for j in range(self.n_exits - 1):
-                if confs[j][i] >= self.thresholds[j]:
-                    exit_idx = j
-                    break
-            token = int(args[exit_idx][i])
-            r.tokens.append(token)
-            r.exits_taken.append(exit_idx)
-            self.stats.tokens_out += 1
-            self._charge(exit_idx)
-            if len(r.tokens) >= r.max_new_tokens:
-                r.done = True
-                self.slots[i] = None   # continuous batching: free the slot
+        with span(self.timing, self.stats, None, "serve.step"):
+            self.last_admissions = []
+            self._fill_slots()
+            live = [i for i, r in enumerate(self.slots) if r is not None]
+            if not live:
+                return
+            toks = np.zeros((self.B, 1), np.int32)
+            for i in live:
+                toks[i, 0] = self.slots[i].tokens[-1]
+            pos = self._slot_pos.copy()
+            with span(self.timing, self.stats, "t_decode_ms",
+                      "serve.decode"):
+                logits, self.caches, exits = self.decode_step(
+                    self.params, self.caches, jnp.asarray(toks),
+                    jnp.asarray(pos))
+                if self.timing:
+                    # keeps the decode program's time out of serve.gate;
+                    # untimed, the gate's launches queue behind the program
+                    jax.block_until_ready(logits)
+            self.last_logits = {"final": logits, **exits}
+            self.last_decoded = [(self.slots[i], int(pos[i]))
+                                 if i in live else None
+                                 for i in range(self.B)]
+            self._slot_pos[live] += 1
+            self.stats.steps += 1
+            self.stats.live_depth_sum += int(pos[live].sum()) + len(live)
+            with span(self.timing, self.stats, "t_gate_ms", "serve.gate"):
+                token, exit_idx = self._gate(self._heads(logits, exits))
+            with span(self.timing, self.stats, "t_account_ms",
+                      "serve.account"):
+                for i in live:
+                    self._emit(i, self.slots[i], int(token[i]),
+                               int(exit_idx[i]))
 
 
 def serve_with_churn(engine: SplitServeEngine,

@@ -1,8 +1,8 @@
 """Train / serve step builders + ``input_specs`` (the dry-run contract).
 
 ``build_train_step(cfg)``  -> step(state, batch) -> (state, metrics)
-``build_serve_step(cfg)``  -> step(params, caches, tokens, pos) -> (logits,
-                              caches, exit_logits)
+``build_serve_step(cfg)``  -> step(params, caches, tokens, pos [B]) ->
+                              (logits, caches, exit_logits)
 ``build_encode_step(cfg)`` -> step(params, batch) -> logits   (encoder-only)
 
 ``input_specs(cfg, shape)`` returns ShapeDtypeStruct stand-ins for every
@@ -121,7 +121,7 @@ def input_specs(cfg: ArchConfig, shape: ShapeSpec) -> dict:
             "params": params_shapes(cfg),
             "caches": T.cache_shape_dtypes(cfg, B, S),
             "tokens": jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            "pos": jax.ShapeDtypeStruct((), jnp.int32),
+            "pos": jax.ShapeDtypeStruct((B,), jnp.int32),
         }
     raise ValueError(shape.kind)
 

@@ -218,8 +218,8 @@ def cache_spec(cfg: ArchConfig, mesh: Mesh, path: str,
         if msize > 1 and mode == "sequence" and shape[2] % msize == 0:
             return P(None, dp, "model", None)
         return P(None, dp, None, None)
-    if path.endswith("/pos"):
-        return P(None, None)
+    if path.endswith("/pos"):         # [n, B, T]: one position row a slot
+        return P(None, _dp_if_divisible(mesh, shape[1], all_axes=pure), None)
     if path.endswith("/state"):        # [n, B, H, P, N]
         dp = _dp_if_divisible(mesh, shape[1], all_axes=pure)
         s = [None, dp, None, None, None]

@@ -70,12 +70,13 @@ def test_swa_ring_buffer_decode():
     B, S = 1, 20
     xs = jax.random.normal(key, (B, S, cfg.d_model), F32)
     # sequential ring-buffer decode
-    ring = ATT.cache_spec(cfg, B, S).init(F32)
-    assert ring["k"].shape[1] == 8  # ring = window
+    ring = jax.tree.map(lambda a: a[None],
+                        ATT.cache_spec(cfg, B, S).init(F32))  # one layer
+    assert ring["k"].shape[2] == 8  # ring = window
     outs = []
     for t in range(S):
         y, ring = ATT.attn_decode_step(params, cfg, xs[:, t:t + 1],
-                                       ring, jnp.int32(t))
+                                       ring, jnp.int32(t), layer=0)
         outs.append(y)
     got = jnp.concatenate(outs, axis=1)
     # full-sequence chunked attention with the same window

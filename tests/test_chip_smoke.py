@@ -73,11 +73,12 @@ def test_compile_counter_counts_backend_compiles(smoke):
 
 
 def test_serving_phase_reduced_model(smoke):
-    out = smoke.serving_phase(get("qwen3-4b", reduced=True), batch=4,
-                              cache_len=64, n_requests=6, prompt_len=4,
-                              new_tokens=4)
+    cfg = get("qwen3-4b", reduced=True)
+    out = smoke.serving_phase(cfg, batch=4, cache_len=64, n_requests=6,
+                              prompt_len=4, new_tokens=4)
     assert out["tokens"] > 0 and out["tokens_per_s"] > 0
-    assert set(out["gate_conf_max_rel_err"]) == {"exit_1", "final"}
+    assert set(out["gate_conf_max_rel_err"]) == {
+        *(f"exit_{p}" for p in cfg.exit_layer_list), "final"}
     assert max(out["gate_conf_max_rel_err"].values()) \
         <= smoke.GATE_CONF_RTOL
 

@@ -46,13 +46,13 @@ def test_exit_thresholds_control_depth(setup):
     cfg, params = setup
     # threshold 0: everything exits at the first exit
     eng = SplitServeEngine(cfg, params, batch_size=2, cache_len=32,
-                           thresholds=[0.0])
+                           thresholds=[0.0, 0.0])
     eng.submit([1, 2], max_new_tokens=4)
     stats = eng.run(max_steps=50)
     assert set(stats.exit_histogram) == {0}
     # threshold > 1: nothing exits early
     eng2 = SplitServeEngine(cfg, params, batch_size=2, cache_len=32,
-                            thresholds=[1.1])
+                            thresholds=[1.1, 1.1])
     eng2.submit([1, 2], max_new_tokens=4)
     stats2 = eng2.run(max_steps=50)
     assert set(stats2.exit_histogram) == {eng2.n_exits - 1}
@@ -64,7 +64,7 @@ def test_fin_placement_energy_accounting(setup):
     prof = paper_profile("h2")
     req = AppRequirements(alpha=0.5, delta=8e-3)
     eng = SplitServeEngine(cfg, params, batch_size=2, cache_len=64,
-                           thresholds=[0.0], network=nw, profile=prof,
+                           thresholds=[0.0, 0.0], network=nw, profile=prof,
                            req=req)
     assert eng.placement is not None
     eng.submit([1, 2], max_new_tokens=6)
@@ -108,7 +108,7 @@ def test_fail_node_avoids_dead_node_and_matches_cold_solve(setup):
     prof = paper_profile("h2")
     req = AppRequirements(alpha=0.5, delta=8e-3)
     eng = SplitServeEngine(cfg, params, batch_size=2, cache_len=64,
-                           thresholds=[0.0], network=nw, profile=prof,
+                           thresholds=[0.0, 0.0], network=nw, profile=prof,
                            req=req)
     eng.submit([1, 2], max_new_tokens=3)
     pre = eng.run(max_steps=40)
@@ -200,7 +200,7 @@ def test_measured_phi_feeds_placement(setup):
     """measured_phi from the gates is a valid phi vector for core.DNNProfile."""
     cfg, params = setup
     eng = SplitServeEngine(cfg, params, batch_size=2, cache_len=64,
-                           thresholds=[0.5])
+                           thresholds=[0.5, 0.5])
     eng.submit(list(range(1, 5)), max_new_tokens=8)
     stats = eng.run(max_steps=100)
     phi = stats.measured_phi

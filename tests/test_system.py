@@ -5,6 +5,8 @@ extended / feasible graphs -> solve with FIN -> execute the placement in the
 split-serving engine -> verify the engine's measured energy accounting is
 consistent with the placement evaluator's prediction.
 """
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -32,8 +34,9 @@ def test_end_to_end_profile_place_serve():
     ev = evaluate_config(network, profile, req, sol.config)
     assert ev.feasible and ev.energy == pytest.approx(sol.energy)
 
-    # 3. serve an LM under the same placement machinery
-    cfg = get("qwen3-4b", reduced=True)
+    # 3. serve an LM under the same placement machinery: one early exit,
+    # as the profile has (the engine charges model exit k as profile exit k)
+    cfg = dataclasses.replace(get("qwen3-4b", reduced=True), exit_layers=(1,))
     params = T.init_model(jax.random.PRNGKey(0), cfg)
     eng = SplitServeEngine(cfg, params, batch_size=2, cache_len=64,
                            thresholds=[0.0], network=network,
