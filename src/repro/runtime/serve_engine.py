@@ -11,7 +11,9 @@ per-sample early exits are realized as *scheduling*:
   * every decode step runs the full stack once for the active batch;
   * the fused gate (kernels/ee_gate) scores each exit's logits; a sequence
     whose confidence clears its threshold takes THAT exit's token — deeper
-    blocks' output for it is discarded;
+    blocks' output for it is discarded.  One device program judges every
+    deployed head (``gate_heads``), and the host reads its tokens and exit
+    indices at once;
   * finished sequences free their slot immediately and the next queued
     request takes it (continuous batching) — phi-fraction compute saving
     becomes throughput;
@@ -54,6 +56,7 @@ per-sample early exits are realized as *scheduling*:
 """
 from __future__ import annotations
 
+import functools
 import itertools
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -71,9 +74,33 @@ from repro.core.contingency import (ContingencyEntry, ContingencyLibrary,
 from repro.core.frontier import frontier_pick
 from repro.core.scenarios import MOBILE_UPLINK_BPS, ChurnEvent
 from repro.core.spans import span
-from repro.kernels.ee_gate.ops import ee_gate
+from repro.kernels.ee_gate.ee_gate import ee_gate_pallas
 from repro.models import attention as ATT
 from repro.models import transformer as T
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gate_heads(heads: Tuple[jnp.ndarray, ...], thresholds: jnp.ndarray, *,
+               interpret: Optional[bool] = None) -> jnp.ndarray:
+    """First-exit-wins over the deployed heads, in one device program.
+
+    ``heads``: the deployed heads' logits, shallowest first, each [rows,
+    V]; ``thresholds``: float32, one per early exit (at least ``len(heads)
+    - 1``).  The ``ee_gate`` kernel judges each head; a row takes the first
+    exit whose confidence clears its threshold, compared in float32, and
+    the last head takes every row that clears none.  The number of heads
+    and the rows are the program's shapes, so each pair compiles once.
+    Returns int32 [2, rows]: the token and the exit index of each row."""
+    gated = [ee_gate_pallas(x, interpret=interpret) for x in heads]
+    k = len(heads) - 1
+    token = gated[k][1]
+    exit_idx = jnp.full(token.shape, k, jnp.int32)
+    for j in reversed(range(k)):
+        conf, arg = gated[j]
+        hit = conf >= thresholds[j]
+        token = jnp.where(hit, arg, token)
+        exit_idx = jnp.where(hit, j, exit_idx)
+    return jnp.stack([token, exit_idx])
 
 
 @dataclass
@@ -105,10 +132,13 @@ class EngineStats:
     prompt_tokens_prefilled: int = 0  # their prompt tokens (unpadded)
     live_depth_sum: int = 0           # KV entries read: over decode steps,
     #                                   each live slot's position + 1
+    gate_reads: int = 0               # the gate's device-to-host reads,
+    #                                   one a decode step and an admission
     # program spans (``timing=True``), ms
     t_admit_ms: float = 0.0           # serve.admit: prefills + 1st tokens
     t_decode_ms: float = 0.0          # serve.decode: the decode program
-    t_gate_ms: float = 0.0            # serve.gate: ee_gate + host reads
+    t_gate_ms: float = 0.0            # serve.gate: the gate program on
+    #                                   every deployed head + its one read
     t_account_ms: float = 0.0         # serve.account: the slot loop
 
     @property
@@ -150,6 +180,8 @@ class SplitServeEngine:
         if len(self.thresholds) != self.n_exits - 1:
             raise ValueError(f"{len(self.thresholds)} thresholds for "
                              f"{self.n_exits - 1} early exits")
+        #: the thresholds on the device, for the gate program
+        self._thresholds_dev = jnp.asarray(self.thresholds, jnp.float32)
         if profile is not None and profile.n_exits != self.n_exits:
             raise ValueError(
                 f"profile {profile.name!r} has {profile.n_exits} exits, the "
@@ -538,8 +570,10 @@ class SplitServeEngine:
         logits, self.caches, exits = self.decode_step(
             self.params, self.caches, jnp.zeros((self.B, 1), jnp.int32),
             jnp.asarray(self._slot_pos))
-        for x in (logits, logits[:1]):
-            jax.block_until_ready(ee_gate(x))
+        heads = self._heads(logits, exits)
+        for rows in (self.B, 1):
+            jax.block_until_ready(gate_heads(
+                tuple(x[:rows] for x in heads), self._thresholds_dev))
 
     def _deployed(self) -> int:
         """Index of the deepest exit a token may take: the placement's
@@ -547,21 +581,14 @@ class SplitServeEngine:
         return (self.n_exits - 1 if self.placement is None
                 else self.placement.final_exit)
 
-    def _gate(self, heads: List[jnp.ndarray]) -> Tuple[np.ndarray,
-                                                      np.ndarray]:
+    def _gate(self, heads: List[jnp.ndarray]) -> np.ndarray:
         """First-exit-wins over the deployed heads ``heads`` (exits in
         order, the last one taken by every row that clears no earlier
-        threshold).  Returns (token, exit index) per row."""
-        confs, args = [], []
-        for x in heads:
-            c, a = ee_gate(x)
-            confs.append(np.asarray(c))
-            args.append(np.asarray(a))
-        k = len(heads) - 1
-        exit_idx = np.full(len(args[0]), k)
-        for j in reversed(range(k)):
-            exit_idx = np.where(confs[j] >= self.thresholds[j], j, exit_idx)
-        return np.choose(exit_idx, args), exit_idx
+        threshold): the gate program (``gate_heads``) and one host read of
+        its result.  Returns int32 [2, rows]: token and exit index."""
+        out = gate_heads(tuple(heads), self._thresholds_dev)
+        self.stats.gate_reads += 1
+        return np.asarray(out)
 
     def _heads(self, logits, exits) -> List[jnp.ndarray]:
         """The deployed heads' logits, shallowest first."""
@@ -591,8 +618,8 @@ class SplitServeEngine:
         self.stats.admissions += 1
         self.stats.prompt_tokens_prefilled += n
         self.last_admissions.append((r, n - 1, {"final": logits, **exits}))
-        token, exit_idx = self._gate(self._heads(logits, exits))
-        self._emit(i, r, int(token[0]), int(exit_idx[0]))
+        token, exit_idx = self._gate(self._heads(logits, exits))[:, 0]
+        self._emit(i, r, int(token), int(exit_idx))
 
     def _fill_slots(self) -> None:
         """Admit queued requests into the free slots, in queue order (again
@@ -650,7 +677,7 @@ class SplitServeEngine:
                     jnp.asarray(pos))
                 if self.timing:
                     # keeps the decode program's time out of serve.gate;
-                    # untimed, the gate's launches queue behind the program
+                    # untimed, the gate program queues behind it
                     jax.block_until_ready(logits)
             self.last_logits = {"final": logits, **exits}
             self.last_decoded = [(self.slots[i], int(pos[i]))
@@ -659,7 +686,8 @@ class SplitServeEngine:
             self._slot_pos[live] += 1
             self.stats.steps += 1
             self.stats.live_depth_sum += int(pos[live].sum()) + len(live)
-            with span(self.timing, self.stats, "t_gate_ms", "serve.gate"):
+            with span(self.timing, self.stats, "t_gate_ms", "serve.gate",
+                      reads=1):          # the gate's one host read
                 token, exit_idx = self._gate(self._heads(logits, exits))
             with span(self.timing, self.stats, "t_account_ms",
                       "serve.account"):

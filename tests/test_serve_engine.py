@@ -2,6 +2,7 @@
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -9,8 +10,10 @@ from repro.configs import get
 from repro.core import AppRequirements, paper_profile
 from repro.core.contingency import NoFeasiblePlacement
 from repro.core.scenarios import churn_trace, paper_scenario
+from repro.kernels.ee_gate.ops import ee_gate
 from repro.models import transformer as T
-from repro.runtime.serve_engine import SplitServeEngine, serve_with_churn
+from repro.runtime.serve_engine import (SplitServeEngine, gate_heads,
+                                        serve_with_churn)
 
 
 @pytest.fixture(scope="module")
@@ -56,6 +59,66 @@ def test_exit_thresholds_control_depth(setup):
     eng2.submit([1, 2], max_new_tokens=4)
     stats2 = eng2.run(max_steps=50)
     assert set(stats2.exit_histogram) == {eng2.n_exits - 1}
+
+
+def _host_gate(heads, thresholds):
+    """The engine's former gate: ``ee_gate`` on each head, both results
+    read to the host, first-exit-wins in numpy (the oracle)."""
+    confs, args = [], []
+    for x in heads:
+        c, a = ee_gate(x)
+        confs.append(np.asarray(c))
+        args.append(np.asarray(a))
+    k = len(heads) - 1
+    exit_idx = np.full(len(args[0]), k)
+    for j in reversed(range(k)):
+        exit_idx = np.where(confs[j] >= thresholds[j], j, exit_idx)
+    return np.choose(exit_idx, args), exit_idx
+
+
+def _gate_logits(n_heads, rows, V=3000, seed=0):
+    """Heads whose rows run from flat to sharply peaked, so that their
+    confidences spread over (0, 1]."""
+    rng = np.random.default_rng(seed)
+    temp = np.geomspace(0.05, 40.0, rows)
+    return [jnp.asarray(rng.standard_normal((rows, V))
+                        * rng.permutation(temp)[:, None], jnp.float32)
+            for _ in range(n_heads)]
+
+
+@pytest.mark.parametrize("rows", [1, 8])
+@pytest.mark.parametrize("n_heads", [1, 2, 3])
+@pytest.mark.parametrize("kind", ["zero", "above_one", "mixed"])
+def test_device_gate_equals_host_gate(n_heads, rows, kind):
+    """``gate_heads`` gives exactly the host gate's token and exit index.
+    The mixed thresholds sit on row confidences: one equal to a float32
+    confidence, one a Python float that rounds to one, so that some rows
+    clear an early exit, some tie it exactly in float32 and the rest fall
+    through to the last head."""
+    heads = _gate_logits(n_heads, rows, seed=10 * n_heads + rows)
+    if kind == "zero":
+        thr = [0.0, 0.0]
+    elif kind == "above_one":
+        thr = [1.1, 1.1]
+    else:
+        thr = []
+        for j, x in enumerate(heads[:2]):
+            c = np.sort(np.asarray(ee_gate(x)[0]))
+            t = float(c[len(c) // 2])
+            if j == 1:      # above the float32 value, within half an ulp
+                t += float(np.spacing(np.float32(t))) / 4
+                assert t > float(c[len(c) // 2])
+            thr.append(t)
+    tok, idx = _host_gate(heads, thr)
+    got = np.asarray(gate_heads(tuple(heads), jnp.asarray(thr, jnp.float32)))
+    assert got.dtype == np.int32 and got.shape == (2, rows)
+    np.testing.assert_array_equal(got[0], tok)
+    np.testing.assert_array_equal(got[1], idx)
+    if kind == "mixed" and rows > 1 and n_heads > 1:
+        c0 = np.asarray(ee_gate(heads[0])[0])
+        tie = c0 == np.float32(thr[0])
+        assert (c0 > thr[0]).any() and tie.any() and (idx[tie] == 0).all()
+        assert set(idx.tolist()) == set(range(n_heads))
 
 
 def test_fin_placement_energy_accounting(setup):

@@ -6,11 +6,14 @@ The reduced variant keeps what the chip runs: a head wider than
 ``d_model / n_heads``, QK-norm, tied embeddings, eps 1e-6, theta 1e6 and
 exits at the thirds of the depth."""
 import dataclasses
+import glob
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro.configs import get
 from repro.core import AppRequirements, Plan, paper_profile, profile_from_arch
@@ -280,20 +283,31 @@ def test_warm_leaves_nothing_to_compile(model):
     assert n[0] == 0
 
 
-def test_spans_and_counters(model):
+def test_spans_and_counters(model, tmp_path):
     cfg, params = model
     eng = SplitServeEngine(cfg, params, batch_size=2, cache_len=64,
                            timing=True)
     eng.submit(list(range(1, 11)), 3)
     eng.submit(list(range(1, 6)), 4)
     eng.submit(list(range(1, 4)), 2)
-    st = eng.run()
+    with jax.profiler.trace(str(tmp_path)):
+        st = eng.run()
     assert st.admissions == 3 and st.prompt_tokens_prefilled == 18
     assert st.tokens_out == 9
     # decode steps: 2, 3 and 1 per request (the first token is admission's)
     # at depths 10..11, 5..7 and 3 (KV entries read = position + 1)
     assert st.live_depth_sum == (11 + 12) + (6 + 7 + 8) + 4
     assert st.t_admit_ms > 0 and st.t_gate_ms > 0 and st.t_account_ms > 0
+    # one host read of the gate a decode step and an admission, each
+    # decode step's carried by its serve.gate span
+    assert st.gate_reads == st.steps + st.admissions
+    (f,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)
+    reads = [dict(e.stats).get("reads")
+             for p in ProfileData.from_file(f).planes
+             if p.name == "/host:CPU" for line in p.lines
+             for e in line.events if e.name == "serve.gate"]
+    assert reads == [1] * st.steps
 
 
 def test_submit_rejects_what_the_cache_cannot_hold(model):

@@ -8,6 +8,7 @@ this file never loads the TPU compiler, and the tests skip where the
 topology cannot be described.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,7 @@ from repro.kernels.minplus.minplus import (banded_minplus_chain_kbest_pallas,
                                            banded_minplus_chain_pallas,
                                            banded_minplus_pallas,
                                            minplus_argmin_pallas)
+from repro.runtime.serve_engine import gate_heads
 
 # the population's shapes: paper_scenario(n_extra_edge=2) has 5 nodes,
 # gamma=10 gives 11 depth cells, and the deepest paper profiles (h1-h4,
@@ -97,3 +99,19 @@ def test_minplus_argmin_compiles(one_chip):
 def test_ee_gate_compiles(one_chip, batch, dtype):
     _compile(lambda x: ee_gate_pallas(x, interpret=False), one_chip,
              ((batch, QWEN3_VOCAB_PAD), dtype))
+
+
+@pytest.mark.parametrize("rows", [8, 1])
+def test_gate_heads_compiles(one_chip, rows):
+    """The serving gate's one program over three deployed heads holds the
+    kernel three times, each a custom call named ``ee_gate`` (the name the
+    benchmark's trace reduction finds the kernel by)."""
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    heads = tuple(arg((rows, QWEN3_VOCAB_PAD), jnp.float32)
+                  for _ in range(3))
+    text = gate_heads.lower(heads, arg((2,), jnp.float32),
+                            interpret=False).compile().as_text()
+    calls = re.findall(r"^\s*(?:ROOT )?%?([\w.-]+) = .*custom-call\(", text,
+                       re.M)
+    assert [re.sub(r"(\.\d+)+$", "", c) for c in calls] == ["ee_gate"] * 3
