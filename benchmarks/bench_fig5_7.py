@@ -6,22 +6,19 @@ Also validates the paper's headline claims:
   * FIN(gamma=3) still never loses to MCP;
   * tighter latency targets force split deployments with higher energy.
 
-The ``sweep-batched`` rows time the whole Fig. 5-7 grid (apps x deltas x
-uplink settings) as ONE ``solve_many`` batched (min,+) relaxation against
-the equivalent loop of legacy ``backend="python"`` ``solve_fin`` calls, and
-record the wall-clock speedup plus a per-scenario agreement count.
+The competitive-ratio claim is also a tier-1 test
+(``tests/test_fin.py::test_fin_matches_opt_on_paper_apps``, one case per
+point of ``SWEEPS``).
 """
 from __future__ import annotations
 
 from typing import List
 
-import numpy as np
-
 from repro.core import (AppRequirements, paper_profile, solve_fin, solve_mcp,
                         solve_opt)
-from repro.core.scenarios import paper_scenario, sweep_scenarios
+from repro.core.scenarios import paper_scenario
 
-from .common import Row, batched_solver_row, kv, timed
+from .common import Row, kv, timed
 
 #: (figure, app, accuracy targets, latency targets ms)
 SWEEPS = [
@@ -62,33 +59,6 @@ def run() -> List[Row]:
                 # competitive-ratio check recorded inline
                 if opt.feasible and fin10.feasible:
                     assert fin10.energy <= opt.energy * 1.1 + 1e-15
-    rows.extend(run_batched_sweep())
-    return rows
-
-
-def run_batched_sweep() -> List[Row]:
-    """Batched solve_many over scenario sweeps vs the legacy solve() loop.
-
-    Two grids: the dense-edge reference scenario (15 candidate hosts — where
-    the legacy triple-loop DP spends its O(N^2 * gamma) inner iterations in
-    Python while the batched solver amortizes one vectorized relaxation
-    across all scenarios; >= 10x expected), and the paper's 3-node network
-    (placement search so small that shared exact-evaluation work bounds the
-    gain).  Both record per-scenario agreement with the legacy results.
-    """
-    rows: List[Row] = []
-    # dense edge tier: apps x deltas = 48 scenarios (>= 20 required by the
-    # acceptance gate for the recorded speedup), 15 nodes
-    ps, ns, rs = sweep_scenarios(deltas_ms=(1.0, 2.0, 3.0, 5.0, 6.5, 8.0,
-                                            10.0, 12.0),
-                                 n_extra_edge=12)
-    rows.append(batched_solver_row("fig5_7/sweep-batched", ps, ns, rs,
-                                   repeats=7, n_nodes=ns[0].n_nodes))
-    # the paper's 3-node network: apps x deltas x uplinks = 48 scenarios
-    ps, ns, rs = sweep_scenarios(deltas_ms=(2.0, 5.0, 8.0, 12.0),
-                                 uplinks_bps=(1e9, 0.5e9))
-    rows.append(batched_solver_row("fig5_7/sweep-batched-3node", ps, ns, rs,
-                                   repeats=5, n_nodes=ns[0].n_nodes))
     return rows
 
 

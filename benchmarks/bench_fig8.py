@@ -10,7 +10,7 @@ import numpy as np
 
 from repro.core import run_multiapp
 
-from .common import Row, kv, smoke, timed
+from .common import Row, kv, timed
 
 APPS = ("h1", "h2", "h3", "h4", "h5", "h6")
 
@@ -52,7 +52,7 @@ def run(user_counts=(10, 25, 50), seed: int = 1) -> List[Row]:
     # serves repeats from its per-bucket solution cache and the batched FIN
     # path dedups extended graphs per bucket; continuous-draw run of the
     # same size timed alongside for the speedup
-    n_pop = 50 if smoke() else 200
+    n_pop = 200
     res_c, us_c = timed(run_multiapp, n_pop, seed=seed, repeats=2)
     res_b, us_b = timed(run_multiapp, n_pop, seed=seed, repeats=2,
                         uplink_buckets=16)

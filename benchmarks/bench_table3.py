@@ -1,7 +1,7 @@
 """Table III: per-block feature counts and complexity of the paper DNNs,
 extracted from our JAX models.
 
-Feature counts must match the paper exactly (they do — asserted).  For the
+Feature counts match the paper exactly (``features_match=1`` on every row).  For the
 "complexity" column the paper counts k^2 * H_out * W_out * C_out (the input
 channel factor is missing: B-LeNet block-2 is listed as 0.040 MOPs where the
 true conv cost is 5*5*6*16*10*10 = 0.240 M MACs).  We report both our true
@@ -42,24 +42,8 @@ def _paper_convention_macs(seq: Sequential, in_shape) -> float:
     return total
 
 
-def _batched_placement_rows(profiles) -> List[Row]:
-    """Batched-solver mode: place every extracted model profile in one
-    ``solve_many`` call and report solver wall-clock vs the legacy loop —
-    ties the Table III model extraction to the deployment pipeline."""
-    from repro.core import AppRequirements
-    from repro.core.scenarios import paper_scenario
-
-    from .common import batched_solver_row
-
-    return [batched_solver_row("table3/solver-batched", profiles,
-                               paper_scenario(),
-                               AppRequirements(alpha=0.0, delta=8e-3),
-                               n_models=len(profiles))]
-
-
 def run() -> List[Row]:
     rows: List[Row] = []
-    extracted = []
     for name, ctor in PAPER_MODELS.items():
         model = ctor()
 
@@ -67,7 +51,6 @@ def run() -> List[Row]:
             return model.extract_profile()
 
         prof, us = timed(build_profile)
-        extracted.append(prof)
         shape = model.input_shape
         for i, blk in enumerate(model.blocks):
             out_shape = blk.out_shape(shape)
@@ -83,7 +66,6 @@ def run() -> List[Row]:
                    paper_convention_MOPs=conv_macs / 1e6,
                    paper_MOPs=TABLE_III_MOPS[name][i])))
             shape = out_shape
-    rows.extend(_batched_placement_rows(extracted))
     return rows
 
 

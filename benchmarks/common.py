@@ -1,24 +1,15 @@
-"""Shared helpers for the benchmark harness.
+"""Shared helpers for the paper-figure reproductions.
 
 Every bench function yields ``Row(name, us_per_call, derived)`` records; the
-``derived`` field carries the paper-facing metric (energy, latency, ratio...)
-as a compact ``key=value;...`` string so ``run.py`` can emit a uniform CSV
-(or, with ``--json``, machine-readable records with the key-values parsed).
-
-``run.py --smoke`` sets REPRO_BENCH_SMOKE=1; benches consult ``smoke()`` to
-shrink instance sizes / repeat counts for the CI perf-regression smoke job.
+``derived`` field carries the paper-facing metric (energy, placement,
+feature count...) as a compact ``key=value;...`` string so ``run.py`` can
+emit a uniform CSV.
 """
 from __future__ import annotations
 
-import os
 import time
 from dataclasses import dataclass
-from typing import Callable, Dict, Iterable, List
-
-
-def smoke() -> bool:
-    """True when running as the reduced-size CI smoke pass."""
-    return os.environ.get("REPRO_BENCH_SMOKE", "") not in ("", "0")
+from typing import Callable
 
 
 @dataclass
@@ -29,22 +20,6 @@ class Row:
 
     def csv(self) -> str:
         return f"{self.name},{self.us_per_call:.3f},{self.derived}"
-
-    def to_dict(self) -> Dict[str, object]:
-        """JSON-friendly record: the ``key=value;...`` payload is parsed and
-        numeric values converted, so downstream tooling (BENCH_PR2.json,
-        regression checks) can compare fields without re-parsing CSV."""
-        out: Dict[str, object] = {"name": self.name,
-                                  "us_per_call": round(self.us_per_call, 3)}
-        for part in self.derived.split(";"):
-            if "=" not in part:
-                continue
-            k, v = part.split("=", 1)
-            try:
-                out[k] = int(v) if v.lstrip("+-").isdigit() else float(v)
-            except ValueError:
-                out[k] = v
-        return out
 
 
 def timed(fn: Callable, *args, repeats: int = 3, **kwargs):
@@ -66,50 +41,3 @@ def kv(**kwargs) -> str:
         else:
             parts.append(f"{k}={v}")
     return ";".join(parts)
-
-
-def batched_solver_row(name: str, profiles, networks, reqs, *,
-                       gamma: int = 10, repeats: int = 1, **extra) -> Row:
-    """Time one ``solve_many`` batched relaxation against the equivalent loop
-    of legacy ``backend="python"`` ``solve_fin`` calls.
-
-    Shared by every batched-solver benchmark mode so the timing protocol
-    (full-size warmup, interleaved best-of-N so scheduler noise hits both
-    paths alike) and the agreement check (placement AND energy per scenario)
-    cannot drift between benches.  ``networks``/``reqs`` broadcast like
-    ``solve_many``'s arguments.  Extra keyword args land in the kv payload.
-    """
-    from repro.core import solve_fin, solve_many
-
-    B = max(len(x) if isinstance(x, (list, tuple)) else 1
-            for x in (profiles, networks, reqs))
-
-    def aslist(x):
-        xs = list(x) if isinstance(x, (list, tuple)) else [x]
-        return xs * B if len(xs) == 1 else xs
-
-    ps, ns, rs = aslist(profiles), aslist(networks), aslist(reqs)
-
-    # full-size warmup (allocator pages, profile caches)
-    batched = solve_many(ps, ns, rs, gamma=gamma)
-    legacy = [solve_fin(nw, pf, rq, gamma=gamma, backend="python")
-              for pf, nw, rq in zip(ps, ns, rs)]
-    t_legacy = t_batched = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        legacy = [solve_fin(nw, pf, rq, gamma=gamma, backend="python")
-                  for pf, nw, rq in zip(ps, ns, rs)]
-        t_legacy = min(t_legacy, time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        batched = solve_many(ps, ns, rs, gamma=gamma)
-        t_batched = min(t_batched, time.perf_counter() - t0)
-
-    agree = sum(
-        1 for a, b in zip(legacy, batched)
-        if a.found == b.found and (not a.found or
-                                   (a.config.placement == b.config.placement
-                                    and a.energy == b.energy)))
-    return Row(name, t_batched / len(ps) * 1e6,
-               kv(n_scenarios=len(ps), legacy_ms=t_legacy * 1e3,
-                  batched_ms=t_batched * 1e3, speedup=t_legacy / t_batched,
-                  agree=agree, **extra))

@@ -22,7 +22,7 @@ from .extended_graph import (ExtendedGraph, build_extended_graph,
                              build_extended_graphs, to_networkx)
 from .feasible_graph import (FeasibleGraph, build_feasible_graph,
                              build_feasible_graphs)
-from .fin import solve_fin, solve_many, fin_all_exit_costs
+from .fin import solve_fin, solve_many
 from .frontier import (FrontierRow, ParetoFrontier, brute_force_frontier,
                        frontier_from_rows, pareto_mask)
 from .plan import (Plan, PlanStats, solve_plans, update_uplinks,
@@ -51,7 +51,7 @@ __all__ = [
     "ConfigEval", "Solution", "evaluate_config", "ExtendedGraph",
     "build_extended_graph", "build_extended_graphs", "to_networkx",
     "FeasibleGraph", "build_feasible_graph", "build_feasible_graphs",
-    "solve_fin", "solve_many", "fin_all_exit_costs",
+    "solve_fin", "solve_many",
     "FrontierRow", "ParetoFrontier", "brute_force_frontier",
     "frontier_from_rows", "pareto_mask",
     "Plan", "PlanStats", "solve_plans", "update_uplinks", "migration_delta",

@@ -233,6 +233,9 @@ class CongestionReport:
     n_priced_links: int = 0      # links with price > 1 after the tick
     max_node_util: float = 0.0   # peak load/cap seen (finite caps)
     max_link_util: float = 0.0
+    #: global ids without an incumbent after the pass, filled only once the
+    #: controller has left the read-only regime (a bump this tick or warm
+    #: prices); a read-only pass reports none, like the uncoupled tick
     unplaced_ids: List[int] = field(default_factory=list)
     #: global ids whose incumbent (found flag, config or energy) actually
     #: changed during the pass — the orchestrator re-arms its hysteresis

@@ -296,7 +296,7 @@ def profile_from_arch(cfg, *, bits: int = 16, context: int = 2048,
 def synthetic_profile(n_blocks: int, n_exits: int, *, seed: int = 0,
                       ops_scale: float = 10 * MOPS,
                       bits_scale: float = 1e6) -> DNNProfile:
-    """Random chain profile for property-based tests and scaling benchmarks."""
+    """Random chain profile for property-based and randomized tests."""
     rng = np.random.default_rng(seed)
     assert 1 <= n_exits <= n_blocks
     block_ops = (rng.uniform(0.05, 1.0, n_blocks) * ops_scale).tolist()

@@ -48,7 +48,7 @@ from .bellman_ford import (_RELAX_CHUNK_BYTES_DEFAULT,
                            batched_banded_relax_min,
                            batched_layered_relax_kbest,
                            batched_layered_relax_min, banded_parent_np,
-                           layered_relax, relax_chunk_bytes, relax_chunk_rows)
+                           relax_chunk_bytes, relax_chunk_rows)
 from .dnn_profile import DNNProfile
 from .extended_graph import (ExtendedGraph, build_extended_graph,
                              build_extended_graphs)
@@ -463,8 +463,8 @@ def _configs_at_exit(dp: "_DPState", profile: DNNProfile, k: int
     """Seed-faithful eager scan: ALL DP end-states at exit k's block, sorted
     by energy, every path backtracked up front.  Only the ``python`` oracle
     backend uses this — it preserves the original solver pipeline that the
-    batched-sweep benchmarks compare against (and that the vectorized lazy
-    post-pass is validated to reproduce)."""
+    vectorized lazy post-pass is validated to reproduce
+    (``tests/test_fin_backends.py``)."""
     block = profile.exits[k].block
     d = dp.dist[block]                      # (N, G+1, K)
     flat = np.argsort(d, axis=None)
@@ -783,29 +783,4 @@ def solve_many(profiles: Union[DNNProfile, Sequence[DNNProfile]],
         meta["n_feasible_states"] = int(np.isfinite(ev.energy))
         out.append(Solution(config=cfg, eval=ev, solve_time=dt / B,
                             solver="fin", meta=meta))
-    return out
-
-
-def fin_all_exit_costs(network: Network, profile: DNNProfile,
-                       req: AppRequirements, *, gamma: int = 10,
-                       lam: Optional[int] = None, quantize: str = "floor",
-                       backend: str = "numpy") -> np.ndarray:
-    """Graph-cost (not exact-eval) per exit — used by scaling benchmarks to
-    exercise the relaxation backends on large instances.  ``banded`` relaxes
-    the compact (N, G+1) grid directly; ``numpy`` / ``jnp`` / ``pallas``
-    scatter the dense (L-1, S, S) matrices first (the PR-1 path, kept for
-    the banded-vs-dense comparison)."""
-    ext = build_extended_graph(network, profile, req)
-    fg = build_feasible_graph(ext, gamma, lam=lam, quantize=quantize)
-    if backend == "banded":
-        E, steep = fg.banded_tensors()
-        hist = batched_banded_relax_min(fg.init_grid()[None], E[None],
-                                        steep[None], fg.depth_window_lo)
-        dist = hist[0].reshape(hist.shape[1], -1)        # (L, N*(G+1))
-    else:
-        Ws = fg.layer_matrices()
-        dist = layered_relax(fg.init_vector(), Ws, backend=backend)
-    out = np.full(profile.n_exits, np.inf)
-    for k, e in enumerate(profile.exits):
-        out[k] = dist[e.block].min()
     return out

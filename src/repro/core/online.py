@@ -33,8 +33,7 @@ through such churn:
 
 ``hysteresis=0`` with ``always_resolve=True`` degenerates to per-tick
 optimal re-planning whose configurations are bit-exact vs cold per-user
-``solve_fin`` calls — the mode the equivalence tests and the warm-vs-cold
-benchmark drive.
+``solve_fin`` calls — the mode the equivalence tests drive.
 """
 from __future__ import annotations
 
@@ -87,7 +86,7 @@ class TickReport:
     n_degraded: int = 0          # evictions resolved via a frontier row
     n_rejected: int = 0          # evictions that cleared the incumbent
     n_readmitted: int = 0        # unplaced users re-admitted on a row
-    n_unplaced: int = 0          # users without an incumbent after the tick
+    n_unplaced: int = 0          # users without an incumbent (0 if read-only)
     # contingency-library accounting (zero when contingency= is off)
     contingency_hits: int = 0    # affected states whose mask was prebuilt
     contingency_misses: int = 0  # affected states that had to relax

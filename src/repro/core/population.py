@@ -1637,8 +1637,8 @@ class Population:
         the default vectorized post-pass the unique groups of each cohort
         state are scored together as stacked arrays (``frontier.
         scan_state_users``) — per-user selections are bit-identical to the
-        scalar per-group path (``vector_postpass=False``), which the
-        ``always_resolve`` benchmarks keep as the same-machine oracle.
+        scalar per-group path (``vector_postpass=False``), the oracle of
+        ``tests/test_frontier.py::test_vector_postpass_bitexact_vs_scalar_seeded``.
         Updates the incumbents in place; returns the per-user Solutions
         when ``build_solutions`` (pass False on million-user ticks to skip
         materializing U Python objects — the incumbent arrays carry the

@@ -43,8 +43,8 @@ def paper_scenario(*, uplink_bps: float = MOBILE_UPLINK_BPS,
 
     ``n_extra_edge > 0`` densifies the edge tier with that many additional
     edge nodes (same per-app slice) — the multi-helper infrastructure flavour
-    of Sec. V, used by the batched scenario-sweep benchmarks where placement
-    search spans many candidate hosts."""
+    of Sec. V, where placement search spans many candidate hosts (the
+    population cohorts and their tests run on it)."""
     tiers = ("mobile", "edge") + ("edge",) * n_extra_edge + ("cloud",)
     fracs = (mobile_frac, edge_frac) + (edge_frac,) * n_extra_edge + (cloud_frac,)
     nw = make_network(tiers, compute_frac=fracs)

@@ -1,8 +1,9 @@
 """Where JAX's persistent compilation cache lives for this checkout.
 
-Entry points (``chip_smoke.py``, ``launch/serve.py``, ``benchmarks/run.py``)
-call :func:`use_compile_cache` once before compiling anything.  When the
-environment sets ``JAX_COMPILATION_CACHE_DIR``, JAX already reads it as its
+Entry points (``chip_smoke.py``, ``launch/serve.py``, ``bench/run.py``,
+``benchmarks/run.py``) call :func:`use_compile_cache` once before
+compiling anything.  When the environment sets
+``JAX_COMPILATION_CACHE_DIR``, JAX already reads it as its
 ``jax_compilation_cache_dir`` option and nothing is set here.  Otherwise the
 cache goes to ``.jax_cache/`` at the checkout root: a fixed path (the path
 is part of the cache key, so a per-run temporary directory would never hit),

@@ -938,9 +938,11 @@ def _random_capacity_run(seed: int) -> None:
     assert rep.iterations <= ctrl.capacity.max_iters
     _assert_caps_hold(ctrl, tol=1e-12)
     _no_fitting_row(ctrl, k_per_exit=ctrl.frontier_k)
-    # rejected set is consistent with the report
-    assert rep.unplaced_ids == sorted(
-        int(g) for g in pop.user_ids[~pop.inc_found])
+    # rejected set is consistent with the report: a pass that never left
+    # the read-only regime (no bump, no warm prices) reports nobody, even
+    # where the uncoupled solve left users without a placement
+    unplaced = sorted(int(g) for g in pop.user_ids[~pop.inc_found])
+    assert rep.unplaced_ids == (unplaced if ctrl._active else [])
 
 
 @pytest.mark.parametrize("seed", range(6))
@@ -949,12 +951,15 @@ def test_random_capacity_fixed_points(seed):
 
 
 try:
-    from hypothesis import HealthCheck, given, settings
+    from hypothesis import HealthCheck, example, given, settings
     from hypothesis import strategies as st
 
     @given(seed=st.integers(0, 10_000))
     @settings(max_examples=15, deadline=None,
               suppress_health_check=[HealthCheck.too_slow])
+    # finite caps that never bind, with users the uncoupled solve left
+    # unplaced: the pass stays read-only and reports nobody unplaced
+    @example(seed=9437)
     def test_property_capacity_fixed_points(seed):
         """Property form (AC): random small populations — the congestion
         fixed point never leaves a capacity violated among admitted

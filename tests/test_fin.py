@@ -14,12 +14,32 @@ def scenario():
     return paper_scenario()
 
 
-@pytest.mark.parametrize("app", ["h1", "h2", "h3", "h4", "h5", "h6"])
-def test_fin_matches_opt_on_paper_apps(scenario, app):
-    """Sec. V: 'FIN virtually always matches the optimum' (gamma=10)."""
+#: (figure, app, accuracy targets, latency targets ms): Fig. 5 sweeps
+#: B-AlexNet (h2), Fig. 7 sweeps B-LeNet (h6)
+FIG5_7_SWEEPS = [
+    ("fig5", "h2", (0.55, 0.80), (2.0, 5.0, 8.0, 12.0)),
+    ("fig7", "h6", (0.93, 0.99), (0.05, 0.1, 0.5, 1.0)),
+]
+
+#: each app at its loosest accuracy and 8 ms (alpha None), then every
+#: (alpha, delta) point of the Fig. 5 and Fig. 7 sweeps
+OPT_CASES = (
+    [pytest.param(app, None, 8.0, id=app)
+     for app in ("h1", "h2", "h3", "h4", "h5", "h6")]
+    + [pytest.param(app, alpha, delta_ms,
+                    id=f"{fig}-{app}-a{alpha}-d{delta_ms}ms")
+       for fig, app, alphas, deltas in FIG5_7_SWEEPS
+       for alpha in alphas for delta_ms in deltas])
+
+
+@pytest.mark.parametrize("app,alpha,delta_ms", OPT_CASES)
+def test_fin_matches_opt_on_paper_apps(scenario, app, alpha, delta_ms):
+    """Sec. V: 'FIN virtually always matches the optimum' (gamma=10):
+    within the 1 + 1/gamma competitive ratio of Opt."""
     prof = paper_profile(app)
-    alpha = min(e.accuracy for e in prof.exits)  # always satisfiable
-    req = AppRequirements(alpha=alpha, delta=8e-3, sigma=1.0)
+    if alpha is None:
+        alpha = min(e.accuracy for e in prof.exits)  # always satisfiable
+    req = AppRequirements(alpha=alpha, delta=delta_ms * 1e-3)
     fin = solve_fin(scenario, prof, req, gamma=10)
     opt = solve_opt(scenario, prof, req)
     assert opt.feasible

@@ -82,6 +82,30 @@ def test_zero_layer_chain_short_circuits():
     assert np.array_equal(h[:, 0], init) and p.shape == (3, 0, 5, 11)
 
 
+def test_population_mesh_backend_matches_minplus():
+    """A population orchestrator on the mesh backend, its relaxations
+    sharded over every visible device, keeps exactly the incumbents of the
+    float64 ``minplus`` engine under AR(1) fading with hysteresis."""
+    from repro.core import ChurnOrchestrator, population_cohorts
+    users = 48
+    ref, mesh = (ChurnOrchestrator(
+        population=population_cohorts(users, n_extra_edge=2, backend=b),
+        hysteresis=0.05) for b in ("minplus", "mesh"))
+    rng = np.random.default_rng(5)
+    q = np.full(users, 0.5)
+    for _ in range(3):
+        q = np.clip(0.5 + 0.95 * (q - 0.5) + rng.normal(0, 0.15, users),
+                    0.3, 1.0)
+        ref.step_arrays(quality=q)
+        mesh.step_arrays(quality=q)
+    for pa, pb in zip(ref.pops, mesh.pops):
+        assert pb._mesh().n_devices == jax.device_count()
+        assert np.array_equal(pa.inc_found, pb.inc_found)
+        f = pa.inc_found
+        assert np.array_equal(pa._inc_place[f], pb._inc_place[f])
+        assert np.array_equal(pa._inc_exit[f], pb._inc_exit[f])
+
+
 def test_population_mesh_device_trim_validation():
     with pytest.raises(ValueError, match="visible"):
         population_mesh(jax.device_count() + 1)

@@ -322,7 +322,8 @@ def test_population_f32_backends_agree(network, backend):
 
 def test_population_mesh_backend_single_device(network):
     """Mesh backend must work on whatever devices exist (1 on plain CPU);
-    the 4-device path is exercised by the CI multi-device smoke job."""
+    ``tests/test_mesh_relaxer.py::test_population_mesh_backend_matches_minplus``
+    runs the orchestrator on 4 host devices."""
     prof = paper_profile("h1")
     req = PAPER_MULTIAPP_REQS["h1"]
     ref = Population(network, prof, req, 3)

@@ -81,7 +81,7 @@ def test_banded_chains_compile_on_largest_network(one_chip):
 
 
 def test_banded_layer_compiles_at_bench_size(one_chip):
-    # the largest single-layer case of benchmarks/bench_kernels.py
+    # a 64-node, 48-step single layer: the largest banded layer shape
     n, g = 64, 48
     _compile(lambda d, e, s: banded_minplus_pallas(d, e, s, interpret=False),
              one_chip, ((n, g + 1), jnp.float32), ((n, n), jnp.float32),
