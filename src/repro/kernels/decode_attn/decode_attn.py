@@ -1,20 +1,39 @@
-"""Pallas TPU kernel: flash-decode GQA attention (split-K over the cache).
+"""Pallas TPU kernel: flash-decode GQA attention, read in place from the
+stacked decode cache.
 
-One new query token per sequence attends over a long KV cache:
+One new query token per batch row attends over that row's KV cache, read
+straight from the stack that rides in the decode scan's carry:
 
-  q: [B, H, D]; k/v cache: [B, T, KV, D]; cache_pos: [T] (absolute position
-  per slot, -1 = empty); pos: current position (masking/SWA).
+  q: [B, H, D]; k/v: [L, B, T, KV, D]; cache_pos: [L, B, T] (absolute
+  position held in each entry, -1 = empty); layer: which of the L slabs;
+  pos: [B], the position each row decodes.
 
-Tiling: grid (B, KV, T/bt) with the T axis minor — the classic
-FlashDecoding split-K schedule.  Each step loads a [bt, D] K/V tile plus the
-[G, D] query group into VMEM, computes [G, bt] scores on the MXU, and
-maintains running (max, sum, weighted-V accumulator) in VMEM scratch.  This
-is the hot loop of the serving path: at 32k context the cache read is the
-roofline term, and the fused single pass reads K/V exactly once.
+Nothing of K/V is sliced out of the stack: ``layer`` and ``pos`` arrive by
+scalar prefetch and the index maps pick block ``(layer, b, t)`` directly.
+Grid (B, T/bt) with the T axis minor; each step one block of ``bt``
+positions with all KV heads, in the ``[.., T, KV, D]`` layout one
+contiguous run, seen as ``[bt * KV, D]`` (row ``i * KV + h``: position i,
+head h; a free reshape).  One score matmul ``q [H, D] @ block^T`` covers
+every query head against every row; a query head keeps the rows of its own
+KV head (the rest masked), so one AV matmul ``p @ block`` yields every
+head's output.  The MXU reads each block once either way; per-head slices
+of a block would cost a relayout of every row.  Running (max, sum,
+weighted-V accumulator) per query head are float32 VMEM scratch; the
+matmuls take the cache's own dtype and accumulate in float32.
+
+Depth bound.  The cache is a ring: entry i holds a position congruent to
+i modulo T (or -1).  So while ``pos[b] < T`` no entry past ``pos[b]`` can
+be valid, and row b reads only blocks ``t <= last[b] = min(pos[b], T-1)
+// bt``: the index maps clamp the block index there (a repeated index
+fetches nothing) and ``pl.when`` skips the compute.  Once a ring has
+wrapped, every block is read.  The mask is exactly ``decode_attention``'s:
+entry valid iff ``0 <= cache_pos <= pos[b]``, and ``> pos[b] - window``
+under a sliding window; the layer's positions enter repeated once per KV
+head (``[B, T * KV]``, small beside K/V), in the block's row order.
 
 The pure-jnp oracle is models/attention.decode_attention (re-exported in
-ref.py) — the same function the serving engine uses, so kernel == engine
-semantics by construction.
+ref.py), which the decode step keeps for quantized caches and partitioned
+programs.
 """
 from __future__ import annotations
 
@@ -28,82 +47,118 @@ from jax.experimental.pallas import tpu as pltpu
 from ..platform import interpret_mode
 
 NEG = -3.0e38         # python float: kernels must not capture traced constants
+#: positions per K/V block of a cache longer than one block
+BLOCK_T = 512
 
 
-def _decode_attn_kernel(window, q_ref, k_ref, v_ref, cpos_ref, pos_ref,
-                        o_ref, m_ref, s_ref, acc_ref):
-    t = pl.program_id(2)
-    nt = pl.num_programs(2)
+def block_len(T: int, block_t: int = BLOCK_T) -> int:
+    """Positions per block over a cache of ``T`` entries: the whole cache
+    when it fits one block, else ``block_t`` (the last block ragged when it
+    does not divide ``T``)."""
+    return T if T <= block_t else block_t
+
+
+def blocks_read(pos, T: int, bt: int):
+    """Blocks of ``bt`` positions the kernel reads for rows at ``pos``
+    (numpy or jnp, any shape): ``min(pos, T-1) // bt + 1`` each."""
+    return (pos.clip(0, T - 1) // bt) + 1
+
+
+def _decode_attn_kernel(window, bt, T, KV, layer_ref, pos_ref, q_ref, k_ref,
+                        v_ref, cpos_ref, o_ref, m_ref, l_ref, acc_ref):
+    del layer_ref                             # used by the index maps only
+    b, t = pl.program_id(0), pl.program_id(1)
+    H, D = q_ref.shape[1:]
+    G, n = H // KV, bt * KV
+    pos = pos_ref[b]
 
     @pl.when(t == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG)
-        s_ref[...] = jnp.zeros_like(s_ref)
+        l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)       # [G, D]
-    k = k_ref[0, :, 0].astype(jnp.float32)    # [bt, D]
-    v = v_ref[0, :, 0].astype(jnp.float32)    # [bt, D]
-    cpos = cpos_ref[...]                      # [bt]
-    pos = pos_ref[0]
+    @pl.when(t <= jnp.clip(pos, 0, T - 1) // bt)
+    def _block():
+        cpos = cpos_ref[pl.ds(b, 1), :]               # [1, n]
+        ok = (cpos >= 0) & (cpos <= pos)
+        if window > 0:
+            ok &= cpos > pos - window
+        # query head r reads the rows of KV head r // G
+        ok &= (jax.lax.broadcasted_iota(jnp.int32, (H, n), 1) % KV ==
+               jax.lax.broadcasted_iota(jnp.int32, (H, n), 0) // G)
+        k, v = k_ref[0, 0], v_ref[0, 0]               # [n, D]
+        if T % bt:        # a ragged last block: its rows past T are garbage
+            left = (T - t * bt) * KV
+            ok &= jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) < left
+            v = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0) <
+                          left, v, jnp.zeros_like(v))
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * D ** -0.5   # [H, n]
+        s = jnp.where(ok, s, NEG)
+        m_old = m_ref[...]                            # [H, 1]
+        m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_old - m_new)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
-    scale = q.shape[-1] ** -0.5
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # [G, bt]
-    ok = (cpos >= 0) & (cpos <= pos)
-    if window > 0:
-        ok &= cpos > pos - window
-    s = jnp.where(ok[None, :], s, NEG)
-
-    m_old = m_ref[...]                        # [G]
-    m_new = jnp.maximum(m_old, s.max(axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_old - m_new)
-    s_ref[...] = s_ref[...] * corr + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-    @pl.when(t == nt - 1)
+    @pl.when(t == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(s_ref[...], 1e-30)[:, None]
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("window", "bt", "interpret"))
-def decode_attn_pallas(q, k_cache, v_cache, cache_pos, pos, *,
-                       window: int = 0, bt: int = 512,
-                       interpret=None) -> jnp.ndarray:
-    """q: [B, H, D]; k/v: [B, T, KV, D]; cache_pos: [T] i32; pos scalar i32.
-    Returns [B, H, D] (same dtype as q)."""
+def decode_attn_pallas(q, k, v, cache_pos, layer, pos, *, window: int = 0,
+                       bt: int = BLOCK_T, interpret=None) -> jnp.ndarray:
+    """q: [B, H, D]; k/v: [L, B, T, KV, D]; cache_pos: [L, B, T] i32; layer:
+    scalar i32; pos: [B] i32.  Returns [B, H, D] (q's dtype): attention of
+    each row's query over slab ``layer`` of the stack, read in place."""
     B, H, D = q.shape
-    T, KV = k_cache.shape[1], k_cache.shape[2]
+    L, _, T, KV, _ = k.shape
     assert H % KV == 0
-    G = H // KV
-    if T % bt:
-        pad = bt - T % bt
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        cache_pos = jnp.pad(cache_pos, (0, pad), constant_values=-1)
-        T += pad
-    qg = q.reshape(B, KV, G, D)
-    pos_arr = jnp.broadcast_to(pos, (1,)).astype(jnp.int32)
+    bt = block_len(T, bt)
+    n = bt * KV
+    # one row per (position, KV head): the cache's own bytes
+    k, v = (a.reshape(L, B, T * KV, D) for a in (k, v))
+    cpos = jnp.repeat(jax.lax.dynamic_index_in_dim(cache_pos, layer, 0,
+                                                   keepdims=False), KV, -1)
 
-    out = pl.pallas_call(
-        functools.partial(_decode_attn_kernel, window),
-        grid=(B, KV, T // bt),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, t: (b, h, 0, 0)),
-            pl.BlockSpec((1, bt, 1, D), lambda b, h, t: (b, t, h, 0)),
-            pl.BlockSpec((1, bt, 1, D), lambda b, h, t: (b, t, h, 0)),
-            pl.BlockSpec((bt,), lambda b, h, t: (t,)),
-            pl.BlockSpec((1,), lambda b, h, t: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, t: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((G,), jnp.float32),
-                        pltpu.VMEM((G,), jnp.float32),
-                        pltpu.VMEM((G, D), jnp.float32)],
+    def block(b, t, pos_ref):          # clamped to row b's last block
+        return jnp.minimum(t, jnp.clip(pos_ref[b], 0, T - 1) // bt)
+
+    def kv_block(b, t, layer_ref, pos_ref):
+        return layer_ref[0], b, block(b, t, pos_ref), 0
+
+    def pos_block(b, t, layer_ref, pos_ref):
+        return 0, block(b, t, pos_ref)
+
+    def row(b, t, layer_ref, pos_ref):
+        return b, 0, 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, pl.cdiv(T, bt)),
+        in_specs=[pl.BlockSpec((1, H, D), row),
+                  pl.BlockSpec((1, 1, n, D), kv_block),
+                  pl.BlockSpec((1, 1, n, D), kv_block),
+                  # every row's positions of the block: a row alone is no
+                  # tile; the kernel picks row b
+                  pl.BlockSpec((B, n), pos_block)],
+        out_specs=pl.BlockSpec((1, H, D), row),
+        scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, D), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_decode_attn_kernel, window, bt, T, KV),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret_mode(interpret),
-    )(qg, k_cache, v_cache, cache_pos, pos_arr)
-    return out.reshape(B, H, D)
+        name="decode_attn",      # the kernel's name in a device trace
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      jnp.broadcast_to(pos, (B,)).astype(jnp.int32), q, k, v, cpos)

@@ -1,12 +1,13 @@
-"""Oracle for the decode_attn kernel: the serving engine's own jnp path."""
+"""Oracle for the decode_attn kernel: the decode step's own jnp path."""
 import jax
-import jax.numpy as jnp
 
 from repro.models.attention import decode_attention
 
 
-def decode_attn_ref(q, k_cache, v_cache, cache_pos, pos, *, window: int = 0):
-    """q: [B, H, D] -> [B, H, D] via models.attention.decode_attention."""
-    out = decode_attention(q[:, None], k_cache, v_cache, cache_pos, pos,
-                           window=window)
-    return out[:, 0]
+def decode_attn_ref(q, k_cache, v_cache, cache_pos, layer, pos, *,
+                    window: int = 0):
+    """q: [B, H, D] -> [B, H, D] via models.attention.decode_attention on
+    slab ``layer`` of the [L, B, T, ...] stacks."""
+    mine = [jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            for a in (k_cache, v_cache, cache_pos)]
+    return decode_attention(q[:, None], *mine, pos, window=window)[:, 0]

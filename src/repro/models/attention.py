@@ -2,9 +2,11 @@
 bidirectional masks; chunked online-softmax for train/prefill (O(S*chunk)
 memory instead of O(S^2)) and a KV-cache decode step (ring buffer for SWA).
 
-The chunked formulation is the pure-JAX (lax.scan) flash-attention analogue —
-the Pallas `decode_attn` kernel (kernels/decode_attn) is the TPU-optimized
-version of the decode path and is validated against `decode_attention` here.
+The chunked formulation is the pure-JAX (lax.scan) flash-attention analogue.
+At decode, attention runs the Pallas `decode_attn` kernel (kernels/decode_attn):
+it reads the stacked [L, B, T, KV, D] cache in place, each row only to its
+own depth, and is validated against `decode_attention` here, which int8
+caches and partitioned programs keep (`reads_in_place`).
 """
 from __future__ import annotations
 
@@ -15,6 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.kernels.decode_attn.ops import decode_attn
+from repro.sharding import context as shard_ctx
 
 from .layers import F32, apply_rope, dense_init, rmsnorm, rmsnorm_init
 
@@ -226,6 +230,16 @@ def decode_attention(q, k_cache, v_cache, cache_pos, pos, *, window: int
     return out.reshape(B, 1, H, D).astype(q.dtype)
 
 
+def reads_in_place(cache: dict) -> bool:
+    """Whether decode attention over ``cache`` (a stack of ``KVCacheSpec``
+    entries) runs the Pallas ``decode_attn`` kernel, which reads each row's
+    live blocks in place from the stack: a cache in the compute dtype in an
+    unpartitioned program.  An int8 cache (dequantized on read) and a
+    program under an activation-sharding mesh (GSPMD does not partition a
+    ``pallas_call``) take ``decode_attention`` on the layer's slab."""
+    return "k_scale" not in cache and shard_ctx.current() is None
+
+
 def attn_decode_step(params, cfg: ArchConfig, x, cache: dict, pos,
                      layer) -> Tuple[jnp.ndarray, dict]:
     """x: [B,1,d]; cache: the whole stack {"k","v","pos"[,"k_scale",
@@ -236,7 +250,8 @@ def attn_decode_step(params, cfg: ArchConfig, x, cache: dict, pos,
     Returns (out [B,1,d], updated stack).  Row b's new K/V lands at entry
     ``pos[b] % T`` (a ring buffer under SWA); int8 caches quantize it and
     dequantize on read.  Only the new entries are written, so a donated
-    stack is updated in place.
+    stack is updated in place; attention then reads the stack in place
+    too (``reads_in_place``), each row to its own depth.
     """
     B = x.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
@@ -246,16 +261,20 @@ def attn_decode_step(params, cfg: ArchConfig, x, cache: dict, pos,
     new = kv_entries(cache, k[:, 0], v[:, 0])
     new["pos"] = pos
     cache = {name: cache[name].at[at].set(val) for name, val in new.items()}
-    mine = jax.tree.map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
-        cache)
-    if "k_scale" in mine:
-        k_read = _dequantize_kv(mine["k"], mine["k_scale"], x.dtype)
-        v_read = _dequantize_kv(mine["v"], mine["v_scale"], x.dtype)
+    if reads_in_place(cache):
+        out = decode_attn(q[:, 0], cache["k"], cache["v"], cache["pos"],
+                          layer, pos, window=cfg.sliding_window)[:, None]
     else:
-        k_read, v_read = mine["k"], mine["v"]
-    out = decode_attention(q, k_read, v_read, mine["pos"], pos,
-                           window=cfg.sliding_window)
+        mine = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0,
+                                                   keepdims=False), cache)
+        if "k_scale" in mine:
+            k_read = _dequantize_kv(mine["k"], mine["k_scale"], x.dtype)
+            v_read = _dequantize_kv(mine["v"], mine["v_scale"], x.dtype)
+        else:
+            k_read, v_read = mine["k"], mine["v"]
+        out = decode_attention(q, k_read, v_read, mine["pos"], pos,
+                               window=cfg.sliding_window)
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"],
                    preferred_element_type=F32).astype(x.dtype)
     return y, cache

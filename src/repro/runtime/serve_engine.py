@@ -74,6 +74,7 @@ from repro.core.contingency import (ContingencyEntry, ContingencyLibrary,
 from repro.core.frontier import frontier_pick
 from repro.core.scenarios import MOBILE_UPLINK_BPS, ChurnEvent
 from repro.core.spans import span
+from repro.kernels.decode_attn.decode_attn import block_len, blocks_read
 from repro.kernels.ee_gate.ee_gate import ee_gate_pallas
 from repro.models import attention as ATT
 from repro.models import transformer as T
@@ -132,11 +133,17 @@ class EngineStats:
     prompt_tokens_prefilled: int = 0  # their prompt tokens (unpadded)
     live_depth_sum: int = 0           # KV entries read: over decode steps,
     #                                   each live slot's position + 1
+    kv_blocks_read: int = 0           # K/V blocks the decode_attn kernel
+    #                                   reads per attention layer: over
+    #                                   decode steps, each live slot's
+    #                                   min(pos, T-1) // bt + 1
     gate_reads: int = 0               # the gate's device-to-host reads,
     #                                   one a decode step and an admission
     # program spans (``timing=True``), ms
     t_admit_ms: float = 0.0           # serve.admit: prefills + 1st tokens
     t_decode_ms: float = 0.0          # serve.decode: the decode program
+    #                                   (the step's kv_blocks_read rides
+    #                                   on it as its ``blocks`` argument)
     t_gate_ms: float = 0.0            # serve.gate: the gate program on
     #                                   every deployed head + its one read
     t_account_ms: float = 0.0         # serve.account: the slot loop
@@ -208,10 +215,17 @@ class SplitServeEngine:
         #: prompt lengths are padded to these: powers of two from 64, and
         #: the cache's length; an SSM state needs the exact length (None)
         self.buckets: Optional[List[int]] = None
+        T_len = ATT.cache_spec(cfg, batch_size, cache_len).max_len
         if all(s.kind == "attn" for s in cfg.pattern):
-            T_len = ATT.cache_spec(cfg, batch_size, cache_len).max_len
             self.buckets = [b for b in (64 << i for i in range(16))
                             if b < T_len] + [T_len]
+        #: the decode_attn kernel's (cache length, positions per block),
+        #: for the ``kv_blocks_read`` count; None where decode attention
+        #: takes the jnp path (no attention layer, or an int8 cache)
+        attn = next((f"l{i}" for i, s in enumerate(cfg.pattern)
+                     if s.kind == "attn"), None)
+        self._kv_blocks = ((T_len, block_len(T_len)) if attn and
+                           ATT.reads_in_place(self.caches[attn]) else None)
         self._ring = cfg.sliding_window > 0
         self.slots: List[Optional[Request]] = [None] * batch_size
         self.queue: List[Request] = []
@@ -670,8 +684,10 @@ class SplitServeEngine:
             for i in live:
                 toks[i, 0] = self.slots[i].tokens[-1]
             pos = self._slot_pos.copy()
+            blocks = (0 if self._kv_blocks is None else
+                      int(blocks_read(pos[live], *self._kv_blocks).sum()))
             with span(self.timing, self.stats, "t_decode_ms",
-                      "serve.decode"):
+                      "serve.decode", blocks=blocks):
                 logits, self.caches, exits = self.decode_step(
                     self.params, self.caches, jnp.asarray(toks),
                     jnp.asarray(pos))
@@ -686,6 +702,7 @@ class SplitServeEngine:
             self._slot_pos[live] += 1
             self.stats.steps += 1
             self.stats.live_depth_sum += int(pos[live].sum()) + len(live)
+            self.stats.kv_blocks_read += blocks
             with span(self.timing, self.stats, "t_gate_ms", "serve.gate",
                       reads=1):          # the gate's one host read
                 token, exit_idx = self._gate(self._heads(logits, exits))

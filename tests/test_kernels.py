@@ -233,6 +233,11 @@ def test_ee_gate_peaked_distribution():
 # decode_attn
 # ---------------------------------------------------------------------------
 
+def _rows(x, B):
+    """A position shared by every row, as the kernel takes it: per row."""
+    return jnp.broadcast_to(jnp.asarray(x, jnp.int32), (B,) + jnp.shape(x))
+
+
 @pytest.mark.parametrize("B,H,KV,D,T,bt", [
     (1, 4, 4, 32, 128, 64),       # MHA
     (2, 8, 2, 64, 256, 128),      # GQA 4:1
@@ -244,12 +249,12 @@ def test_decode_attn_sweep(B, H, KV, D, T, bt, dtype):
     key = jax.random.PRNGKey(B + H + T)
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, H, D), dtype)
-    k = jax.random.normal(ks[1], (B, T, KV, D), dtype)
-    v = jax.random.normal(ks[2], (B, T, KV, D), dtype)
-    cache_pos = jnp.arange(T, dtype=jnp.int32)
-    pos = jnp.int32(T - 3)   # last slots masked (future)
-    got = decode_attn(q, k, v, cache_pos, pos, block_t=bt)
-    want = decode_attn_ref(q, k, v, cache_pos, pos)
+    k = jax.random.normal(ks[1], (1, B, T, KV, D), dtype)
+    v = jax.random.normal(ks[2], (1, B, T, KV, D), dtype)
+    cache_pos = _rows(jnp.arange(T, dtype=jnp.int32), B)[None]
+    pos = _rows(T - 3, B)    # last slots masked (future)
+    got = decode_attn(q, k, v, cache_pos, 0, pos, block_t=bt)
+    want = decode_attn_ref(q, k, v, cache_pos, 0, pos)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -261,13 +266,13 @@ def test_decode_attn_sliding_window():
     key = jax.random.PRNGKey(9)
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
-    k = jax.random.normal(ks[1], (B, T, KV, D), jnp.float32)
-    v = jax.random.normal(ks[2], (B, T, KV, D), jnp.float32)
-    cache_pos = jnp.arange(T, dtype=jnp.int32)
-    pos = jnp.int32(T - 1)
+    k = jax.random.normal(ks[1], (1, B, T, KV, D), jnp.float32)
+    v = jax.random.normal(ks[2], (1, B, T, KV, D), jnp.float32)
+    cache_pos = _rows(jnp.arange(T, dtype=jnp.int32), B)[None]
+    pos = _rows(T - 1, B)
     for w in (16, 64):
-        got = decode_attn(q, k, v, cache_pos, pos, window=w, block_t=64)
-        want = decode_attn_ref(q, k, v, cache_pos, pos, window=w)
+        got = decode_attn(q, k, v, cache_pos, 0, pos, window=w, block_t=64)
+        want = decode_attn_ref(q, k, v, cache_pos, 0, pos, window=w)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 
@@ -278,12 +283,65 @@ def test_decode_attn_empty_slots_masked():
     key = jax.random.PRNGKey(5)
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
-    k = jax.random.normal(ks[1], (B, T, KV, D), jnp.float32)
-    v = jax.random.normal(ks[2], (B, T, KV, D), jnp.float32)
-    cache_pos = jnp.where(jnp.arange(T) < 10, jnp.arange(T), -1).astype(
-        jnp.int32)
-    got = decode_attn(q, k, v, cache_pos, jnp.int32(9), block_t=32)
-    want = decode_attn_ref(q, k[:, :10], v[:, :10],
-                           cache_pos[:10], jnp.int32(9))
+    k = jax.random.normal(ks[1], (1, B, T, KV, D), jnp.float32)
+    v = jax.random.normal(ks[2], (1, B, T, KV, D), jnp.float32)
+    cache_pos = _rows(jnp.where(jnp.arange(T) < 10, jnp.arange(T), -1), B
+                      )[None]
+    pos = _rows(9, B)
+    got = decode_attn(q, k, v, cache_pos, 0, pos, block_t=32)
+    want = decode_attn_ref(q, k[:, :, :10], v[:, :, :10],
+                           cache_pos[:, :, :10], 0, pos)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+#: (rows, layers, layer, T, window): each row's position, cache length
+#: and window; block length 64 throughout
+_STACKS = {
+    # one row at each edge of a block, and one at the cache's end
+    "depths": ([0, 63, 64, 65, 255], 2, 1, 256, 0),
+    # a re-admitted slot: stale K/V past its depth, marked -1
+    "stale": ([40, 130, 7], 2, 0, 256, 0),
+    # a sliding-window ring that has wrapped (pos >= T) beside one that
+    # has not
+    "wrapped_ring": ([200, 127, 30, 64], 1, 0, 128, 128),
+    # the layer index picks its own slab of the stack
+    "layer": ([100, 5], 3, 2, 128, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STACKS))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attn_reads_the_stack_in_place(case, dtype):
+    """Per-row depths over a stacked [L, B, T, KV, D] cache equal
+    ``decode_attention`` on the layer's slab, whatever lies past each
+    row's depth in the blocks the kernel skips."""
+    rows, L, layer, T, window = _STACKS[case]
+    B, H, KV, D = len(rows), 8, 2, 32
+    pos = jnp.asarray(rows, jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    q = jax.random.normal(ks[0], (B, H, D), dtype)
+    k = jax.random.normal(ks[1], (L, B, T, KV, D), dtype)
+    v = jax.random.normal(ks[2], (L, B, T, KV, D), dtype)
+    t = jnp.arange(T, dtype=jnp.int32)
+    # the ring's layout: entry i holds the latest position congruent to i
+    # that the row has reached, -1 where none has been written
+    held = pos[:, None] - (pos[:, None] - t[None]) % T
+    cache_pos = jnp.broadcast_to(jnp.where(held >= 0, held, -1), (L, B, T))
+    if case == "stale":
+        gone = t[None] > pos[:, None]
+        cache_pos = jnp.where(gone, -1, cache_pos)
+        k = jnp.where(gone[None, :, :, None, None], 1e3, k).astype(dtype)
+        v = jnp.where(gone[None, :, :, None, None], -1e3, v).astype(dtype)
+    got = decode_attn(q, k, v, cache_pos, jnp.int32(layer), pos,
+                      window=window, block_t=64)
+    want = decode_attn_ref(q, k, v, cache_pos, jnp.int32(layer), pos,
+                           window=window)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+    if case == "layer":
+        other = decode_attn_ref(q, k, v, cache_pos, 0, pos)
+        assert not np.allclose(np.asarray(got, np.float32),
+                               np.asarray(other, np.float32), atol=tol)

@@ -18,6 +18,7 @@ from jax.profiler import ProfileData
 from repro.configs import get
 from repro.core import AppRequirements, Plan, paper_profile, profile_from_arch
 from repro.core.scenarios import paper_scenario
+from repro.kernels.decode_attn.decode_attn import block_len
 from repro.launch.flops import param_count
 from repro.models import dense_ref
 from repro.models import transformer as T
@@ -130,22 +131,28 @@ def test_profile_must_describe_the_served_model(model):
 @pytest.mark.parametrize("prompt_len", [5, 64, 71])
 def test_admission_and_decode_match_dense_ref(model, prompt_len):
     """Admission prefill (one bucket below, at and above 64) and the decode
-    steps after it give the reference's logits at every head."""
+    steps after it give the reference's logits at every head, beside a
+    deeper slot: the two rows' live K/V end in different blocks of the
+    decode_attn kernel, which reads each to its own depth."""
     cfg, params = model
-    eng = SplitServeEngine(cfg, params, batch_size=2, cache_len=128,
+    eng = SplitServeEngine(cfg, params, batch_size=2, cache_len=1024,
                            thresholds=[1.1, 1.1])
+    assert block_len(1024) < 600        # the deep slot's own block
     rng = np.random.default_rng(prompt_len)
-    prompt = rng.integers(0, cfg.vocab_size, prompt_len).tolist()
-    r = eng.submit(prompt, max_new_tokens=6)
-    seen = _serve(eng)[r.rid]
-    assert sorted(seen) == list(range(prompt_len - 1, prompt_len + 5))
-    seq = prompt + r.tokens[:-1]
-    ref = dense_ref.forward(params, dense_ref.dims_of(cfg), seq,
-                            at=sorted(seen))
-    for head in ref:
-        got = np.stack([seen[p][head] for p in sorted(seen)])
-        assert _rel_rms(got, ref[head]) < REL_RMS, head
-        assert (got.argmax(-1) == ref[head].argmax(-1)).all(), head
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
+               for n in (prompt_len, 600)]
+    reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+    served = _serve(eng)
+    for prompt, r in zip(prompts, reqs):
+        seen = served[r.rid]
+        n = len(prompt)
+        assert sorted(seen) == list(range(n - 1, n + 5))
+        ref = dense_ref.forward(params, dense_ref.dims_of(cfg),
+                                prompt + r.tokens[:-1], at=sorted(seen))
+        for head in ref:
+            got = np.stack([seen[p][head] for p in sorted(seen)])
+            assert _rel_rms(got, ref[head]) < REL_RMS, (n, head)
+            assert (got.argmax(-1) == ref[head].argmax(-1)).all(), (n, head)
 
 
 def _swap_qk_norm(params, cfg):
@@ -297,6 +304,9 @@ def test_spans_and_counters(model, tmp_path):
     # decode steps: 2, 3 and 1 per request (the first token is admission's)
     # at depths 10..11, 5..7 and 3 (KV entries read = position + 1)
     assert st.live_depth_sum == (11 + 12) + (6 + 7 + 8) + 4
+    # a 64-entry cache is one block of the decode_attn kernel: one block
+    # per live slot a step, carried by the step's serve.decode span
+    assert st.kv_blocks_read == 2 + 3 + 1
     assert st.t_admit_ms > 0 and st.t_gate_ms > 0 and st.t_account_ms > 0
     # one host read of the gate a decode step and an admission, each
     # decode step's carried by its serve.gate span
@@ -308,6 +318,32 @@ def test_spans_and_counters(model, tmp_path):
              if p.name == "/host:CPU" for line in p.lines
              for e in line.events if e.name == "serve.gate"]
     assert reads == [1] * st.steps
+    blocks = [dict(e.stats).get("blocks")
+              for p in ProfileData.from_file(f).planes
+              if p.name == "/host:CPU" for line in p.lines
+              for e in line.events if e.name == "serve.decode"]
+    assert len(blocks) == st.steps and sum(blocks) == st.kv_blocks_read
+
+
+def test_kv_blocks_read_counts_each_live_slots_blocks(model):
+    """Over decode steps, ``kv_blocks_read`` adds ``min(pos, T-1) // bt + 1``
+    for each live slot: the blocks of one layer's cache the decode_attn
+    kernel reads for it, here on both sides of a block edge."""
+    cfg, params = model
+    T_len = 1024
+    bt = block_len(T_len)
+    eng = SplitServeEngine(cfg, params, batch_size=3, cache_len=T_len,
+                           thresholds=[1.1, 1.1])
+    # (prompt length, tokens): decode steps at positions n .. n + m - 2,
+    # the first token being admission's
+    reqs = [(bt - 2, 5), (bt + 188, 3), (3, 2)]
+    for n, m in reqs:
+        eng.submit(list(range(1, n + 1)), m)
+    st = eng.run()
+    want = sum(min(p, T_len - 1) // bt + 1
+               for n, m in reqs for p in range(n, n + m - 1))
+    assert want == (1 + 1 + 2 + 2) + (2 + 2) + 1
+    assert st.kv_blocks_read == want
 
 
 def test_submit_rejects_what_the_cache_cannot_hold(model):

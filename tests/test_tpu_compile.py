@@ -7,6 +7,8 @@ topology is described inside a fixture, so a worker that is not given
 this file never loads the TPU compiler, and the tests skip where the
 topology cannot be described.
 """
+import dataclasses
+import functools
 import os
 import re
 
@@ -14,11 +16,14 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.configs import get
+from repro.kernels.decode_attn import decode_attn as decode_attn_kernel
 from repro.kernels.ee_gate.ee_gate import ee_gate_pallas
 from repro.kernels.minplus.minplus import (banded_minplus_chain_kbest_pallas,
                                            banded_minplus_chain_pallas,
                                            banded_minplus_pallas,
                                            minplus_argmin_pallas)
+from repro.models import transformer as T
 from repro.runtime.serve_engine import gate_heads
 
 # the population's shapes: paper_scenario(n_extra_edge=2) has 5 nodes,
@@ -115,3 +120,32 @@ def test_gate_heads_compiles(one_chip, rows):
     calls = re.findall(r"^\s*(?:ROOT )?%?([\w.-]+) = .*custom-call\(", text,
                        re.M)
     assert [re.sub(r"(\.\d+)+$", "", c) for c in calls] == ["ee_gate"] * 3
+
+
+def test_decode_step_reads_the_cache_in_place(one_chip, monkeypatch):
+    """qwen3-4b's decode step at its published widths, batch 8, cache
+    4096 (two layers: the scan body is the same) runs attention as the
+    ``decode_attn`` kernel on the carried stack: a custom call of that name
+    (the name the benchmark's trace finds it by), and no dynamic-slice that
+    copies one layer's K/V slab out of the stack."""
+    # the kernel resolves interpret mode from the default backend (this
+    # process's CPU); the described chip compiles it
+    monkeypatch.setattr(decode_attn_kernel, "interpret_mode",
+                        lambda interpret=None: False)
+    cfg = dataclasses.replace(get("qwen3-4b"), n_layers=2, exit_layers=())
+    on_chip = functools.partial(jax.tree.map, lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip))
+    params = on_chip(jax.eval_shape(
+        lambda: T.init_model(jax.random.PRNGKey(0), cfg)))
+    caches = on_chip(T.cache_shape_dtypes(cfg, 8, 4096))
+    toks, pos = on_chip((jax.ShapeDtypeStruct((8, 1), jnp.int32),
+                         jax.ShapeDtypeStruct((8,), jnp.int32)))
+    text = jax.jit(lambda p, c, t, q: T.decode_step(p, cfg, t, c, q),
+                   donate_argnums=(1,)).lower(
+        params, caches, toks, pos).compile().as_text()
+    calls = re.findall(r"^\s*(?:ROOT )?%?([\w.-]+) = .*custom-call\(", text,
+                       re.M)
+    assert "decode_attn" in [re.sub(r"(\.\d+)+$", "", c) for c in calls]
+    slab = [line for line in text.splitlines()
+            if "dynamic-slice" in line and "bf16[1,8,4096,8,128]" in line]
+    assert not slab, slab[:2]
